@@ -207,7 +207,7 @@ SCHED_TMP=$(mktemp -d)
 sched_flags="--num-samples 64 --references 1:0:400000 --mesh-shape 1,4 \
   --similarity-strategy sharded --block-size 64 --ingest packed"
 for mode in flat hier; do
-  env JAX_PLATFORMS=cpu SPARK_EXAMPLES_TPU_PLATFORM=cpu \
+  env JAX_PLATFORMS=cpu \
       SPARK_EXAMPLES_TPU_NO_CACHE=1 SPARK_EXAMPLES_TPU_HIER_HOSTS=2 \
       XLA_FLAGS="--xla_force_host_platform_device_count=4" \
     python -m spark_examples_tpu variants-pca $sched_flags \
@@ -479,7 +479,7 @@ RING_TMP=$(mktemp -d)
 ring_flags="--num-samples 64 --references 1:0:400000 --mesh-shape 1,4 \
   --similarity-strategy sharded --block-size 64"
 for mode in on off; do
-  env JAX_PLATFORMS=cpu SPARK_EXAMPLES_TPU_PLATFORM=cpu \
+  env JAX_PLATFORMS=cpu \
       SPARK_EXAMPLES_TPU_NO_CACHE=1 \
       XLA_FLAGS="--xla_force_host_platform_device_count=4" \
     python -m spark_examples_tpu variants-pca $ring_flags \

@@ -12,7 +12,7 @@ Gramian actually rests on live one layer down, in the *traced IR*:
   serialized loop paid one extra, returning each tile to its owner
   (GI006).
 - **donation/aliasing** — the accumulator's donation contract is read off
-  the traced ``pjit`` eqn's ``donated_invars`` and cross-checked against
+  the traced ``jit`` eqn's ``donated_invars`` and cross-checked against
   the AST layer's justified ``# graftcheck: disable=GC005`` escape
   hatches, so the two layers cannot drift (GI002): a non-donated update
   needs the justification, a justified disable needs the non-donation.
@@ -60,21 +60,8 @@ import numpy as np
 from spark_examples_tpu.check.rules import Finding, parse_disables
 
 # --------------------------------------------------------------------------
-# jaxpr plumbing (version-tolerant: jax.core moved to jax.extend.core).
+# jaxpr plumbing
 # --------------------------------------------------------------------------
-
-
-def _core() -> Any:
-    try:
-        from jax.extend import core as jcore  # type: ignore[attr-defined]
-
-        if hasattr(jcore, "Var"):
-            return jcore
-    except ImportError:
-        pass
-    from jax import core as jcore2  # type: ignore[no-redef]
-
-    return jcore2
 
 
 def _is_var(v: Any) -> bool:
@@ -82,7 +69,7 @@ def _is_var(v: Any) -> bool:
 
 
 def _sub_jaxprs(eqn: Any) -> List[Any]:
-    """The inner Jaxpr objects of one eqn's params (pjit/scan/shard_map
+    """The inner Jaxpr objects of one eqn's params (jit/scan/shard_map
     jaxpr=, cond branches=, while cond/body_jaxpr=...)."""
     out: List[Any] = []
 
@@ -219,7 +206,7 @@ _PACKED_VIOLATION = {
 
 def _map_into_sub(eqn: Any, sub: Any, packed_in: Set[Any]) -> Set[Any]:
     """Positionally map packed eqn operands onto a sub-jaxpr's invars
-    (pjit/shard_map/scan all bind operands to inner invars in order)."""
+    (jit/shard_map/scan all bind operands to inner invars in order)."""
     seeds: Set[Any] = set()
     for outer, inner in zip(eqn.invars, sub.invars):
         if _is_var(outer) and outer in packed_in:
@@ -457,9 +444,9 @@ def _emit(audit: KernelAudit, rule_id: str, detail: str) -> None:
     audit.findings.append(Finding(rule_id, audit.name, 0, 0, detail))
 
 
-def _find_top_pjit(jaxpr: Any) -> Optional[Any]:
+def _find_top_jit(jaxpr: Any) -> Optional[Any]:
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             return eqn
     return None
 
@@ -467,12 +454,12 @@ def _find_top_pjit(jaxpr: Any) -> Optional[Any]:
 def _audit_donation(spec: KernelSpec, jaxpr: Any, audit: KernelAudit) -> None:
     if spec.acc_invar is None:
         return
-    eqn = _find_top_pjit(jaxpr)
+    eqn = _find_top_jit(jaxpr)
     if eqn is None:
         _emit(
             audit,
             "GI002",
-            "kernel has no jitted (pjit) entry point; the accumulator "
+            "kernel has no jitted (jit) entry point; the accumulator "
             "donation contract cannot be audited",
         )
         return
@@ -858,7 +845,7 @@ def ring_kernel_spec(
             SAMPLES_AXIS,
         )
 
-        mesh = AbstractMesh(((DATA_AXIS, data), (SAMPLES_AXIS, samples)))
+        mesh = AbstractMesh((data, samples), (DATA_AXIS, SAMPLES_AXIS))
         operand = np.int8 if exact_int else np.float32
         accum = jnp.int32 if exact_int else jnp.float32
         update = build_sharded_update(mesh, operand, pack)
@@ -928,11 +915,8 @@ def hier_kernel_spec(
         )
 
         mesh = AbstractMesh(
-            (
-                (DATA_AXIS, data),
-                (HOST_AXIS, hosts),
-                (SAMPLES_AXIS, devices_per_host),
-            )
+            (data, hosts, devices_per_host),
+            (DATA_AXIS, HOST_AXIS, SAMPLES_AXIS),
         )
         operand = np.int8 if exact_int else np.float32
         accum = jnp.int32 if exact_int else jnp.float32
@@ -991,7 +975,7 @@ def devicegen_ring_spec(
         from spark_examples_tpu.ops.devicegen import _ring_update
         from spark_examples_tpu.parallel.mesh import DATA_AXIS, SAMPLES_AXIS
 
-        mesh = AbstractMesh(((DATA_AXIS, data), (SAMPLES_AXIS, samples)))
+        mesh = AbstractMesh((data, samples), (DATA_AXIS, SAMPLES_AXIS))
         pops = np.zeros(padded, dtype=np.int32)
         update = _ring_update.__wrapped__(
             (0x5EED,),
@@ -1074,11 +1058,8 @@ def devicegen_hier_spec(
         )
 
         mesh = AbstractMesh(
-            (
-                (DATA_AXIS, data),
-                (HOST_AXIS, hosts),
-                (SAMPLES_AXIS, devices_per_host),
-            )
+            (data, hosts, devices_per_host),
+            (DATA_AXIS, HOST_AXIS, SAMPLES_AXIS),
         )
         pops = np.zeros(padded, dtype=np.int32)
         update = _ring_update.__wrapped__(

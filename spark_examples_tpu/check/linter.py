@@ -53,8 +53,7 @@ _AUG_OPS = {"Add": "+", "Sub": "-", "Mult": "*", "BitOr": "|"}
 #: where a host numpy call is just as wrong as under jit).
 _SHARD_MAP_NAMES = (
     "shard_map",
-    "jax.experimental.shard_map.shard_map",
-    "spark_examples_tpu.utils.compat.shard_map",
+    "jax.shard_map",
 )
 
 #: GC011: cast targets narrow enough that the Gramian dtype ladder's

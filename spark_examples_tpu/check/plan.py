@@ -489,17 +489,6 @@ def _eval_sharded_update(
             "samples axis",
         )
 
-    try:
-        # Capability probe only — the IR audit below constructs the mesh.
-        from jax.sharding import AbstractMesh  # noqa: F401
-    except ImportError:
-        report.warn(
-            "no-abstract-mesh",
-            "this jax has no AbstractMesh; sharded-update shape check "
-            "skipped (geometry checks above still hold)",
-        )
-        return
-
     accum = jnp.int32 if conf.exact_similarity else jnp.float32
     x_width = padded // RING_PACK_MULTIPLE if pack else padded
 
@@ -989,24 +978,15 @@ def _eval_analysis_kernels(
         mesh = None
         mesh_note = "single-device"
         if samples >= 2:
-            try:
-                from jax.sharding import AbstractMesh
-            except ImportError:
-                report.warn(
-                    "no-abstract-mesh",
-                    "this jax has no AbstractMesh; the LD window kernel "
-                    "is shape-checked single-device only",
-                )
-            else:
-                from spark_examples_tpu.parallel.mesh import (
-                    DATA_AXIS,
-                    SAMPLES_AXIS,
-                )
+            from jax.sharding import AbstractMesh
 
-                mesh = AbstractMesh(
-                    ((DATA_AXIS, data), (SAMPLES_AXIS, samples))
-                )
-                mesh_note = f"abstract {data}x{samples} mesh"
+            from spark_examples_tpu.parallel.mesh import (
+                DATA_AXIS,
+                SAMPLES_AXIS,
+            )
+
+            mesh = AbstractMesh((data, samples), (DATA_AXIS, SAMPLES_AXIS))
+            mesh_note = f"abstract {data}x{samples} mesh"
         try:
             stats_fn = build_ld_window_stats(mesh)
             C, k = jax.eval_shape(

@@ -245,6 +245,7 @@ _PASSTHROUGH = {
     "copy",
     "optimization_barrier",
     "pbroadcast",
+    "pvary",
     "ppermute",
     "dynamic_slice",
     "stop_gradient",
@@ -363,7 +364,7 @@ class Interpreter:
             a, b = frame.read(eqn.invars[0]), frame.read(eqn.invars[1])
             frame.write(eqn.outvars[0], self._compare(name, a, b))
             return
-        if name in ("pjit", "closed_call", "custom_jvp_call", "custom_vjp_call",
+        if name in ("jit", "closed_call", "custom_jvp_call", "custom_vjp_call",
                     "remat", "checkpoint"):
             self._descend(frame, eqn, trips, collect)
             return
@@ -887,7 +888,7 @@ class Interpreter:
             if eqn is None:
                 return frame, var
             name = eqn.primitive.name
-            if name in ("pbroadcast", "convert_element_type", "copy",
+            if name in ("pbroadcast", "pvary", "convert_element_type", "copy",
                         "optimization_barrier", "broadcast_in_dim", "squeeze"):
                 var = eqn.invars[0]
                 continue
@@ -958,7 +959,7 @@ class Interpreter:
             )
             if div.point is not None:
                 modulus, dividend = int(div.point), eqn.invars[0]
-        elif eqn.primitive.name == "pjit" and eqn.params.get("name") in (
+        elif eqn.primitive.name == "jit" and eqn.params.get("name") in (
             "remainder",
             "mod",
             "floormod",

@@ -506,8 +506,7 @@ LOCK_RULES: Dict[str, Rule] = {
             "GL002",
             "device-sync-under-lock",
             "A lock is held across block_until_ready: every thread "
-            "needing the lock stalls behind a device round-trip (seconds "
-            "on remote-attached backends). Sync first, then take the "
+            "needing the lock stalls behind a device round-trip. Sync first, then take the "
             "lock.",
         ),
         Rule(

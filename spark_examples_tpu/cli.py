@@ -254,11 +254,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         # propagate.
         return int(COMMANDS[command](rest))
     # After the help/unknown early-outs: only real commands pay (and benefit
-    # from) the process-global platform/cache configuration.
-    from spark_examples_tpu.parallel.mesh import apply_platform_override
+    # from) the process-global compile cache configuration.
     from spark_examples_tpu.utils.cache import enable_persistent_compile_cache
 
-    apply_platform_override()
     enable_persistent_compile_cache()
     if command == "serve":
         # The daemon's exit code IS the drain verdict (ci.sh gates on it).

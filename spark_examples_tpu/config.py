@@ -474,8 +474,7 @@ def build_pca_parser(
         default=None,
         help=(
             "Device-ingest blocks fused per dispatch (lax.scan length); "
-            "higher amortizes per-dispatch overhead on remote-attached "
-            "backends. Default: auto — constant device work per "
+            "higher amortizes per-dispatch host overhead. Default: auto — constant device work per "
             "dispatch, so small cohorts get longer scans "
             "(ops/devicegen.py:auto_blocks_per_dispatch)."
         ),

@@ -9,9 +9,8 @@ Gramian accumulators their flush aggregate (``dispatch``) and finalize
 so one manifest shows where a run's wall-clock went, layer by layer.
 
 Honest-timing semantics carried over from ``StageTimes.stage(sync=)``
-(``utils/tracing.py``): dispatch is asynchronous and ``block_until_ready``
-can ACK before execution completes on remote-attached backends, so a
-span's wall time is only meaningful when it ends in a synchronous fetch.
+(``utils/tracing.py``): dispatch is asynchronous, so a span's wall time
+includes its device work only when it ends in a synchronous fetch.
 ``span(..., sync=fn)`` calls ``fn`` before closing the measurement and the
 span records ``synced: true`` — manifest consumers can tell honest
 wall-clock from dispatch-time-only numbers.

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from spark_examples_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from spark_examples_tpu.parallel.mesh import SAMPLES_AXIS
 

@@ -74,7 +74,7 @@ def build_ld_window_stats(mesh=None):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from spark_examples_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     if mesh is None or mesh.shape.get(SAMPLES_AXIS, 1) < 2:
 

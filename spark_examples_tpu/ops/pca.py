@@ -64,8 +64,7 @@ def principal_components_subspace(
     The TPU-first eigensolver for the driver path: ``num_pc`` is tiny (the
     reference defaults to 2, ``GenomicsConf.scala:76``), so the full O(N³)
     ``eigh`` is the wrong tool — XLA's TPU eigh at N=2,504 compiles for
-    minutes, runs in tens of seconds, and degrades subsequent dispatch
-    throughput ~20× on remote-attached backends (measured), while subspace
+    minutes and runs in tens of seconds (measured before PR 1), while subspace
     iteration is a few hundred skinny (N×N)@(N×k) MXU matmuls: ~20 ms warm.
     Subspace iteration converges to the largest-|λ| eigenpairs — exactly the
     MLlib covariance ordering (see :func:`principal_components`). It also
@@ -125,7 +124,7 @@ def principal_components_subspace_sharded(
     (all-zero after :func:`gower_center_sharded` with ``n_true``) contribute
     nothing and the returned components simply carry zero rows for padding.
     """
-    from spark_examples_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from spark_examples_tpu.parallel.mesh import SAMPLES_AXIS

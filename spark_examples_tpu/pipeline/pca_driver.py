@@ -678,8 +678,8 @@ class VariantsPcaDriver:
         )
         self._finish_checkpointing()
         # Stay on device either way: centering/PCA consume this directly;
-        # fetching the N×N matrix to host is pointless and degrades
-        # remote-attached backends (see ops/gramian.py). The sharded result
+        # fetching the N×N matrix to host would only copy it back. The
+        # sharded result
         # remains row-tile-sharded (padded) for the sharded PCA stage.
         if isinstance(acc, GramianAccumulator):
             return self._merge_host_partials(acc.finalize_device())
@@ -1367,8 +1367,7 @@ def run_pipeline(
                 )
             else:
                 # compute_pca ends in the synchronous components fetch, so
-                # its stage time is honest even on asynchronous
-                # remote-attached backends.
+                # its stage time includes the device work it dispatched.
                 if recorder is not None:
                     recorder.begin("center+pca", tid="pipeline")
                 with times.stage("center+pca"):
@@ -1573,8 +1572,8 @@ def _summarize_similarity(similarity, n: int) -> Dict:
 
 def _sync_scalar(similarity) -> None:
     """Force outstanding device work to completion with a one-scalar fetch
-    that depends on the full accumulation chain (``block_until_ready`` can
-    ACK early on remote-attached backends; a host array is a no-op)."""
+    that depends on the full accumulation chain (a host array is a
+    no-op)."""
     import jax
     import jax.numpy as jnp
 

@@ -28,8 +28,9 @@ is a thin dispatch onto it, so every behavior is testable in-process):
   execution, only the scheduling changes;
 - **survives restarts**: every acknowledged admission is journaled
   (``serve/journal.py``) before its 202 leaves the socket, the
-  warm-geometry ledger and the XLA persistent compilation cache are
-  keyed under the run directory, so a restarted daemon replays
+  warm-geometry ledger is kept under the run directory and the XLA
+  persistent compilation cache in the shared cache directory
+  (``utils/cache.py:compile_cache_dir``), so a restarted daemon replays
   accepted-but-unfinished jobs (requeue-once semantics preserved via
   the journaled ``device_began`` flag) and serves its first
   repeat-geometry job warm instead of paying the whole-genome recompile;
@@ -639,13 +640,11 @@ class PcaService:
         )
 
         if self.persistent_cache:
-            # Warm state half 1: XLA compile artifacts keyed under the
-            # run dir — a restarted daemon reloads them from disk instead
-            # of recompiling (the ~9.5 s whole-genome recompile of
-            # BENCH_r05 becomes a cache read).
-            enable_persistent_compile_cache(
-                os.path.join(self.run_dir, "jax-cache")
-            )
+            # Warm state half 1: XLA compile artifacts in the shared
+            # compile cache (utils/cache.py:compile_cache_dir) — a
+            # restarted daemon reloads them from disk instead of
+            # recompiling.
+            enable_persistent_compile_cache(persist_all=True)
         import jax
 
         # The warm-mesh moment: devices enumerate here, once; every

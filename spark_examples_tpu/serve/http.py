@@ -454,8 +454,8 @@ def serve_main(argv: Optional[Sequence[str]] = None) -> int:
         "--no-persistent-cache",
         action="store_true",
         help=(
-            "Do not persist warm state under --run-dir (neither the XLA "
-            "compilation cache nor the warm-geometry ledger): a "
+            "Do not persist warm state (neither the shared XLA "
+            "compilation cache nor the --run-dir warm-geometry ledger): a "
             "restarted daemon then recompiles from scratch and honestly "
             "reports every first geometry cold."
         ),

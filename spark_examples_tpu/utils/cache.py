@@ -1,8 +1,11 @@
-"""Shared persistent XLA compile cache configuration + warm-geometry ledger.
+"""Shared persistent XLA compile cache placement + warm-geometry ledger.
 
-First TPU compile of a shape costs tens of seconds; the CLI and the
-benchmark reuse one cache location (outside the repo, so compile artifacts
-never enter git — a 152 MB lesson from round 1).
+First TPU compile of a shape costs seconds to tens of seconds. Every entry
+point (CLI, daemon, ``bench.py``, ``chip_smoke.py``) keeps its compile
+cache in the one directory :func:`compile_cache_dir` names:
+``JAX_COMPILATION_CACHE_DIR`` when it is set, else ``<checkout>/.jax_cache``
+(gitignored). The path is part of the cache key, so it never derives from
+a run directory, a temp dir, a pid or the time.
 
 The warm-geometry ledger is the resident service's half of the story
 (``serve/``): a process-wide record of every analysis geometry this
@@ -24,44 +27,48 @@ import os
 import threading
 from typing import Optional, Set, Tuple
 
+#: The environment variable JAX itself reads for the cache directory.
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-def enable_persistent_compile_cache(cache_dir: Optional[str] = None) -> None:
-    """Point XLA's persistent compilation cache at ``cache_dir`` (the
-    resident daemon keys it under its run directory, so a restarted daemon
-    reloads the previous incarnation's compile artifacts instead of paying
-    the ~9.5 s whole-genome recompile) or, by default, the shared
-    per-user location the CLI and the benchmark use.
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
-    The default location is a write OUTSIDE the working tree, so
-    ``SPARK_EXAMPLES_TPU_NO_CACHE=1`` (test/CI hygiene) disables it; an
-    EXPLICIT ``cache_dir`` is caller-owned placement (the daemon's run
-    dir, a test's tmp dir) and is honored regardless. An explicit dir
-    also persists EVERY compile (min-compile-time 0): the daemon's
-    geometry ledger claims "warm" for every fingerprint it primes, which
-    is only honest if sub-second compiles left artifacts too — the
-    shared default location keeps the 1 s floor so ad-hoc CLI runs don't
-    churn it with trivia. Never raises."""
-    min_compile_seconds = 0.0 if cache_dir is not None else 1.0
-    if cache_dir is None:
-        if os.environ.get("SPARK_EXAMPLES_TPU_NO_CACHE") == "1":
-            return
-        cache_dir = os.path.join(
-            os.path.expanduser("~/.cache"), "spark_examples_tpu", "jax_cache"
-        )
+
+def compile_cache_dir() -> str:
+    """The persistent compile cache directory every entry point uses:
+    ``$JAX_COMPILATION_CACHE_DIR`` if set, else ``<checkout>/.jax_cache``."""
+    return os.environ.get(CACHE_DIR_ENV) or os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def compile_cache_entries() -> int:
+    """Entries in :func:`compile_cache_dir` (0 when it does not exist yet)."""
     try:
-        import jax
+        return len(os.listdir(compile_cache_dir()))
+    except OSError:
+        return 0
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_compile_seconds
-        )
-    except Exception as e:  # never block the caller on cache configuration
-        import sys
 
-        print(
-            f"warning: persistent compile cache disabled ({e})",
-            file=sys.stderr,
-        )
+def enable_persistent_compile_cache(persist_all: bool = False) -> None:
+    """Turn on XLA's persistent compilation cache in
+    :func:`compile_cache_dir`.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already reads that
+    directory and no directory is set in code. ``SPARK_EXAMPLES_TPU_NO_CACHE=1``
+    (test/CI hygiene) leaves the default location off. ``persist_all``
+    (the daemon) stores every compile, not only those past JAX's 1 s
+    floor: the daemon's geometry ledger claims "warm" for every fingerprint
+    it primes, which is only honest if sub-second compiles left artifacts
+    too."""
+    import jax
+
+    if persist_all:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if os.environ.get(CACHE_DIR_ENV):
+        return
+    if os.environ.get("SPARK_EXAMPLES_TPU_NO_CACHE") == "1":
+        return
+    jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
 
 
 # ---------------------------------------------------------------------------
@@ -223,8 +230,8 @@ def attach_geometry_ledger(path: str) -> int:
 
     A primed fingerprint makes ``geometry_seen`` answer ``True`` in a
     process that never compiled it — that is the POINT: paired with the
-    persistent XLA compilation cache keyed under the same run directory
-    (``enable_persistent_compile_cache``), a repeat-geometry job after a
+    persistent XLA compilation cache (``enable_persistent_compile_cache``),
+    a repeat-geometry job after a
     daemon restart rebuilds its jit entries from disk artifacts instead of
     recompiling, so "warm" honestly means "no from-scratch compile", not
     only "in-process jit cache populated". Priming moves no hit/miss
@@ -272,6 +279,8 @@ def reset_compile_cache_stats() -> None:
 
 
 __all__ = [
+    "compile_cache_dir",
+    "compile_cache_entries",
     "enable_persistent_compile_cache",
     "compile_fingerprint",
     "batch_compile_fingerprint",

@@ -11,10 +11,10 @@ ports 8080/4040, ``README.md:148-178``) and log4j. The TPU equivalents:
   TensorBoard-loadable profile of the XLA ops (the fine-grained equivalent
   of drilling into a Spark stage), enabled by ``--profile-dir``.
 
-Honest-timing note (remote-attached backends): dispatch is asynchronous and
-``block_until_ready`` can ACK before execution completes, so a stage's wall
-time is only meaningful when the stage ends in a synchronous fetch (the
-driver's PCA stage does) or when ``sync=`` passes a device value to fetch.
+Honest-timing note: dispatch is asynchronous, so a stage's wall time
+includes its device work only when the stage ends in a synchronous fetch
+(the driver's PCA stage does) or when ``sync=`` passes a device value to
+fetch.
 The span recorder carries this as the per-span ``synced`` flag.
 """
 

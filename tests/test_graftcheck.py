@@ -270,7 +270,7 @@ def test_gc010_shard_map_decoration_and_scope():
         """
         import functools
         import numpy as np
-        from spark_examples_tpu.utils.compat import shard_map
+        from jax import shard_map
         @functools.partial(shard_map, mesh=None, in_specs=(), out_specs=())
         def per_device(x):
             return np.packbits(x)

@@ -44,7 +44,7 @@ from spark_examples_tpu.parallel.mesh import (
     padded_cohort,
     ring_traffic_bytes,
 )
-from spark_examples_tpu.utils.compat import shard_map
+from jax import shard_map
 
 _PACKAGE_DIR = os.path.dirname(
     os.path.abspath(__import__("spark_examples_tpu").__file__)
@@ -170,7 +170,7 @@ def _fixture_update(kernel_body, packed_width):
     builder uses, with the defect injected in the body."""
     from jax.sharding import AbstractMesh, PartitionSpec as P
 
-    mesh = AbstractMesh(((DATA_AXIS, 1), (SAMPLES_AXIS, 4)))
+    mesh = AbstractMesh((1, 4), (DATA_AXIS, SAMPLES_AXIS))
     g_spec = P(DATA_AXIS, SAMPLES_AXIS, None)
     x_spec = P(DATA_AXIS, None, SAMPLES_AXIS)
 

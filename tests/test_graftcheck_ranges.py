@@ -224,7 +224,7 @@ def test_ring_passes_multiply_refined_increment():
     T = 3
 
     def build():
-        mesh = AbstractMesh(((DATA_AXIS, 1), (SAMPLES_AXIS, 4)))
+        mesh = AbstractMesh((1, 4), (DATA_AXIS, SAMPLES_AXIS))
         update = build_sharded_update(mesh, np.float32, True)
 
         def repeated(G, X):

@@ -222,7 +222,7 @@ def _serialized_hier_trace(hosts, per_host, num_samples, block_size):
     from jax import lax
     from jax.sharding import AbstractMesh, PartitionSpec as P
 
-    from spark_examples_tpu.utils.compat import shard_map
+    from jax import shard_map
     from spark_examples_tpu.parallel.mesh import (
         DATA_AXIS,
         HOST_AXIS,
@@ -235,7 +235,7 @@ def _serialized_hier_trace(hosts, per_host, num_samples, block_size):
     padded = padded_cohort(num_samples, samples, pack=True)
     n_local = padded // samples
     mesh = AbstractMesh(
-        ((DATA_AXIS, 1), (HOST_AXIS, hosts), (SAMPLES_AXIS, per_host))
+        (1, hosts, per_host), (DATA_AXIS, HOST_AXIS, SAMPLES_AXIS)
     )
 
     from spark_examples_tpu.ops.gramian import _unpack_bits

@@ -1,0 +1,148 @@
+"""Main-path programs compiled for a described TPU v5e at real widths.
+
+Nothing runs: the TPU compiler installed beside JAX compiles for a v5e:2x2
+topology that is described, not attached, and refuses what the chip would
+refuse (layouts, memory, partitioning). The topology is built inside a
+module fixture, never at import, so only the xdist worker that runs this
+file loads libtpu.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import ml_dtypes
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+N_1KG = 2504  # 1000 Genomes phase 3 cohort
+N_LARGE = 25_000  # the large-cohort cell
+BLOCK = 16384  # bench.py's whole-genome block size
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip cannot be read back from the
+    # persistent cache without the chip; keep these compiles out of it.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _about(nbytes, logical):
+    """Device bytes match ``logical`` up to the chip's tile padding (the
+    minor dimension rounds up to a multiple of 128 lanes)."""
+    return logical <= nbytes < 1.05 * logical
+
+
+@pytest.mark.parametrize(
+    "operand, accum",
+    [(np.int8, jnp.int32), (ml_dtypes.bfloat16, jnp.float32)],
+    ids=["int8", "bf16"],
+)
+def test_dense_update_compiles(one_chip, operand, accum):
+    """The host-fed dense Gramian update (bit-packed block in, unpack + dot
+    on device) at N=2504, B=16384, in both MXU operand dtypes."""
+    from spark_examples_tpu.ops.gramian import _dense_update
+
+    G = _spec((1, N_1KG, N_1KG), accum, one_chip)
+    X = _spec((1, BLOCK, -(-N_1KG // 8)), jnp.uint8, one_chip)
+    compiled = _dense_update.lower(
+        G, X, operand_dtype=operand, num_samples=N_1KG
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert _about(mem.output_size_in_bytes, N_1KG * N_1KG * 4)
+
+
+def test_devicegen_whole_genome_update_compiles(one_chip):
+    """The fused generate→accumulate scan at the whole-genome dispatch
+    geometry (bench.py's whole-genome config: spacing 73, B=16384, the
+    auto dispatch length)."""
+    from spark_examples_tpu.ops.devicegen import _fused_update, auto_blocks_per_dispatch
+    from spark_examples_tpu.sources.synthetic import SyntheticGenomicsSource
+
+    source = SyntheticGenomicsSource(num_samples=N_1KG, seed=42, variant_spacing=73)
+    K = auto_blocks_per_dispatch(N_1KG, BLOCK)
+    with jax.enable_x64(True):
+        update = _fused_update(
+            (source.genotype_stream_key("bench-1kg"),),
+            np.asarray(source.populations, np.int32).tobytes(),
+            int(source.site_key),
+            73,
+            float(source.ref_block_fraction),
+            None,
+            BLOCK,
+            K,
+            "int8",
+            "int32",
+            int(source.n_pops),
+            None,
+        )
+        scalar = _spec((), jnp.int64, one_chip)
+        compiled = update.lower(
+            _spec((N_1KG, N_1KG), jnp.int32, one_chip),
+            _spec((1,), jnp.int64, one_chip),
+            scalar,
+            scalar,
+            scalar,
+        ).compile()
+    assert compiled.memory_analysis().output_size_in_bytes >= N_1KG * N_1KG * 4
+
+
+def test_finalize_compiles(one_chip):
+    """Gower centering (f64 arithmetic under x64) and the subspace
+    eigensolve of the 2504² Gramian."""
+    from spark_examples_tpu.ops.centering import gower_center
+    from spark_examples_tpu.ops.pca import principal_components_subspace
+
+    with jax.enable_x64(True):
+        gower_center.lower(_spec((N_1KG, N_1KG), jnp.int32, one_chip)).compile()
+    compiled = principal_components_subspace.lower(
+        _spec((N_1KG, N_1KG), jnp.float32, one_chip), num_pc=2
+    ).compile()
+    assert compiled.memory_analysis().output_size_in_bytes < N_1KG * 64
+
+
+@pytest.mark.parametrize("shape", [(1, 4), (2, 2)], ids=["1x4", "2x2"])
+def test_sharded_update_compiles_per_device(topo, shape):
+    """The packed ring update at N=25,000 over four described chips: each
+    device holds one row tile of the Gramian, and the ring is
+    collective-permutes."""
+    from spark_examples_tpu.ops.gramian import build_sharded_update
+    from spark_examples_tpu.parallel.mesh import DATA_AXIS, SAMPLES_AXIS, padded_cohort
+
+    data, samples = shape
+    mesh = Mesh(np.array(topo.devices).reshape(shape), (DATA_AXIS, SAMPLES_AXIS))
+    padded = padded_cohort(N_LARGE, samples, pack=True)
+    G = _spec(
+        (data, padded, padded),
+        jnp.int32,
+        NamedSharding(mesh, P(DATA_AXIS, SAMPLES_AXIS, None)),
+    )
+    X = _spec(
+        (data, 1024, padded // 8),
+        jnp.uint8,
+        NamedSharding(mesh, P(DATA_AXIS, None, SAMPLES_AXIS)),
+    )
+    compiled = build_sharded_update(mesh, np.int8, True).lower(G, X).compile()
+    tile = padded * padded * 4 // samples
+    assert _about(compiled.memory_analysis().output_size_in_bytes, tile)
+    assert "collective-permute" in compiled.as_text()
