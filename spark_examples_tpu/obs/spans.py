@@ -15,6 +15,23 @@ includes its device work only when it ends in a synchronous fetch.
 span records ``synced: true`` — manifest consumers can tell honest
 wall-clock from dispatch-time-only numbers.
 
+Three views of one measurement:
+
+- the recorder's own tree (:meth:`SpanRecorder.as_list`), which the run
+  manifest snapshots;
+- the profiler's timeline: each span opened with :meth:`SpanRecorder.span`
+  is also a ``jax.profiler.TraceAnnotation`` named ``sxt:<path>`` (e.g.
+  ``sxt:ingest/enqueue``), so under a profiler session (``--profile-dir``)
+  it sits on the host timeline beside the device programs, on the same
+  clock; with no session the annotation is a no-op. JAX is used only if
+  something in the process has already imported it, so JAX-free callers
+  (``check/``) stay JAX-free;
+- a bounded process-wide buffer of closed spans, :func:`recent_spans`,
+  for readers that outlive the recorder (a driver is discarded after its
+  job). Each record carries its path, its parent's path, the recorder's
+  ``run_id`` (one per driver run: a served job's trace id, or a fresh one)
+  and its integer attributes — counts taken at the span's boundary.
+
 Thread model: the open-span stack is per-thread (ingest worker threads and
 the driver thread each nest correctly); completed spans attach to their
 parent, or to the recorder's root list when nothing is open on that
@@ -24,64 +41,144 @@ thread. Pre-measured durations recorded with :meth:`SpanRecorder.add`
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import sys
 import threading
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional
+
+#: Closed spans of every recorder in the process, newest last. Bounded: a
+#: long-lived service keeps only the latest spans; a batch job closes about
+#: ten, so hundreds of jobs fit.
+_RECENT: Deque["Span"] = collections.deque(maxlen=4096)
+
+#: Prefix of the profiler annotations the spans open.
+ANNOTATION_PREFIX = "sxt:"
 
 
 class Span:
-    """One timed region: name, seconds, sync-honesty flag, children."""
+    """One timed region: name, path, seconds, sync-honesty flag, children,
+    integer attributes."""
 
-    __slots__ = ("name", "seconds", "synced", "children", "started_unix")
+    __slots__ = (
+        "name",
+        "path",
+        "parent",
+        "run_id",
+        "seconds",
+        "synced",
+        "children",
+        "started_unix_ns",
+        "attrs",
+    )
 
-    def __init__(self, name: str, synced: bool, started_unix: float):
+    def __init__(
+        self,
+        name: str,
+        synced: bool,
+        parent: Optional["Span"] = None,
+        run_id: Optional[str] = None,
+    ):
         self.name = str(name)
+        self.parent = parent.path if parent is not None else None
+        self.path = f"{self.parent}/{self.name}" if parent is not None else self.name
+        self.run_id = run_id
         self.seconds: Optional[float] = None  # None while still open
         self.synced = bool(synced)
         self.children: List["Span"] = []
-        self.started_unix = started_unix
+        self.started_unix_ns = time.time_ns()
+        self.attrs: Dict[str, int] = {}
+
+    @property
+    def started_unix(self) -> float:
+        return self.started_unix_ns * 1e-9
+
+    @property
+    def self_seconds(self) -> Optional[float]:
+        """Seconds not covered by a closed child span."""
+        if self.seconds is None:
+            return None
+        return self.seconds - sum(c.seconds for c in self.children if c.seconds is not None)
 
     def as_dict(self) -> Dict:
-        return {
+        doc = {
             "name": self.name,
             "seconds": self.seconds,
             "synced": self.synced,
             "started_unix": self.started_unix,
             "children": [c.as_dict() for c in self.children],
         }
+        if self.attrs:
+            doc["attrs"] = dict(self.attrs)
+        return doc
+
+    def record(self) -> Dict:
+        """The flat form :func:`recent_spans` returns."""
+        return {
+            "name": self.name,
+            "path": self.path,
+            "parent": self.parent,
+            "run_id": self.run_id,
+            "started_unix_ns": self.started_unix_ns,
+            "seconds": self.seconds,
+            "self_seconds": self.self_seconds,
+            "synced": self.synced,
+            "attrs": dict(self.attrs),
+        }
+
+
+def recent_spans() -> List[Dict]:
+    """The process's most recently closed spans, oldest first, as
+    :meth:`Span.record` dicts."""
+    return [span.record() for span in list(_RECENT)]
+
+
+def _annotation(path: str):
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + path)
 
 
 class SpanRecorder:
-    """A tree of spans with a per-thread open stack."""
+    """A tree of spans with a per-thread open stack. ``run_id`` stamps
+    every span this recorder closes."""
 
-    def __init__(self) -> None:
+    def __init__(self, run_id: Optional[str] = None) -> None:
         # lock order: recorder lock is a leaf — nothing else is acquired
         # while holding it.
         self._lock = threading.Lock()
+        self.run_id = run_id
         self.roots: List[Span] = []
         self._stacks: Dict[int, List[Span]] = {}
 
-    def _attach(self, span: Span) -> None:
+    def _open(self, name: str, synced: bool) -> Span:
+        """A new span, attached under this thread's innermost open span
+        (or as a root)."""
         tid = threading.get_ident()
         with self._lock:
             stack = self._stacks.get(tid)
-            if stack:
-                stack[-1].children.append(span)
+            parent = stack[-1] if stack else None
+            span = Span(name, synced, parent, self.run_id)
+            if parent is not None:
+                parent.children.append(span)
             else:
                 self.roots.append(span)
+        return span
 
     @contextlib.contextmanager
     def span(self, name: str, sync: Optional[Callable[[], object]] = None):
         """Open a child span of the current thread's innermost open span
         (or a new root). ``sync`` is called before the measurement closes —
         pass a tiny device fetch for honest wall-clock on async backends."""
-        span = Span(name, synced=sync is not None, started_unix=time.time())
-        self._attach(span)
+        span = self._open(name, sync is not None)
         tid = threading.get_ident()
         with self._lock:
             self._stacks.setdefault(tid, []).append(span)
-        start = time.perf_counter()
+        annotation = _annotation(span.path)
+        annotation.__enter__()
+        start = time.perf_counter_ns()
         try:
             yield span
         finally:
@@ -93,7 +190,8 @@ class SpanRecorder:
                 # fetch raises (device error mid-measurement) — otherwise
                 # every later span on this thread would silently nest
                 # under a dead parent.
-                span.seconds = time.perf_counter() - start
+                span.seconds = (time.perf_counter_ns() - start) * 1e-9
+                annotation.__exit__(None, None, None)
                 with self._lock:
                     stack = self._stacks.get(tid, [])
                     if span in stack:
@@ -103,13 +201,14 @@ class SpanRecorder:
                         del stack[stack.index(span):]
                     if not stack:
                         self._stacks.pop(tid, None)
+                _RECENT.append(span)
 
     def add(self, name: str, seconds: float, synced: bool = False) -> None:
         """Attach a pre-measured duration (an aggregate timed elsewhere,
         e.g. total Gramian flush host time) as a closed span."""
-        span = Span(name, synced=synced, started_unix=time.time())
+        span = self._open(name, synced)
         span.seconds = float(seconds)
-        self._attach(span)
+        _RECENT.append(span)
 
     # -------------------------------------------------------------- exports
 
@@ -125,18 +224,17 @@ class SpanRecorder:
         the grep-able form of the tree."""
         rows: List[Dict] = []
 
-        def walk(span: Span, prefix: str) -> None:
-            path = f"{prefix}/{span.name}" if prefix else span.name
+        def walk(span: Span) -> None:
             rows.append(
-                {"path": path, "seconds": span.seconds, "synced": span.synced}
+                {"path": span.path, "seconds": span.seconds, "synced": span.synced}
             )
             for child in span.children:
-                walk(child, path)
+                walk(child)
 
         with self._lock:
             roots = list(self.roots)
         for root in roots:
-            walk(root, "")
+            walk(root)
         return rows
 
     def find(self, path: str) -> Optional[Span]:
@@ -153,4 +251,4 @@ class SpanRecorder:
         return span
 
 
-__all__ = ["Span", "SpanRecorder"]
+__all__ = ["ANNOTATION_PREFIX", "Span", "SpanRecorder", "recent_spans"]

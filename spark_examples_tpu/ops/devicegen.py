@@ -32,8 +32,12 @@ rule every host path uses (``sources/synthetic.py:af_passes``).
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import functools
-from typing import Optional, Sequence, Tuple
+import re
+import time
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -337,6 +341,110 @@ def auto_blocks_per_dispatch(total_columns: int, block_size: int) -> int:
     return int(min(512, max(32, (k // 8) * 8)))
 
 
+#: Named scopes of the update programs' scan body: site metadata, the
+#: genotype hash and the operand cast (``generate``), the MXU dot and its
+#: accumulate (``int8_dot``), the kept/variant-row reductions (``count``),
+#: and, in the ring program only, the tile ``ppermute`` (``ring_exchange``,
+#: set in ``ops/gramian.py``).
+UPDATE_SCOPES = ("generate", "int8_dot", "count", "ring_exchange")
+
+#: The update programs this process has dispatched, by XLA module name:
+#: ``(jitted program, its arguments' abstract shapes)`` of the latest one
+#: under that name, noted at each dispatch (an identity check) so
+#: :func:`update_op_scopes` can rebuild the compiled text later.
+_DISPATCHED: Dict[str, tuple] = {}
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def _note_dispatch(update, args) -> None:
+    module = "jit_" + update.__name__
+    entry = _DISPATCHED.get(module)
+    if entry is None or entry[0] is not update:
+        _DISPATCHED[module] = (
+            update,
+            tuple(
+                jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+                for a in args
+            ),
+        )
+
+
+def _innermost_scope(op_name: str) -> Optional[str]:
+    for part in reversed(op_name.split("/")):
+        if part in UPDATE_SCOPES:
+            return part
+    return None
+
+
+def hlo_op_scopes(hlo_text: str) -> Dict[str, str]:
+    """``{HLO instruction name: scope}`` for the instructions of a compiled
+    module (``compiled.as_text()``) whose ``op_name`` metadata passes
+    through one of :data:`UPDATE_SCOPES` (the innermost wins).
+
+    A fusion takes the scope that most of its fused instructions carry
+    (ties go to its own metadata): XLA gives a fusion the metadata of one
+    of its roots, and a multi-output fusion that computes the genotype hash
+    and also emits the per-row ``count`` reduction would otherwise be
+    charged to ``count``."""
+    own: Dict[str, Optional[str]] = {}
+    calls: Dict[str, str] = {}
+    members: Dict[str, collections.Counter] = {}
+    computation: collections.Counter = collections.Counter()
+    for line in hlo_text.splitlines():
+        text = line.strip()
+        if " = " not in text:
+            if text.endswith("{"):
+                words = text.split()
+                name = words[1 if words[0] == "ENTRY" else 0].lstrip("%")
+                computation = members.setdefault(name, collections.Counter())
+            continue
+        lhs = text.split(" = ", 1)[0]
+        name = lhs.removeprefix("ROOT ").lstrip("%")
+        found = _OP_NAME.search(text)
+        scope = _innermost_scope(found.group(1)) if found else None
+        own[name] = scope
+        if scope is not None:
+            computation[scope] += 1
+        called = _CALLS.search(text)
+        if called:
+            calls[name] = called.group(1)
+    scopes: Dict[str, str] = {}
+    for name, scope in own.items():
+        counts = members.get(calls.get(name, ""))
+        if counts:
+            most = max(counts.values())
+            if counts.get(scope) != most:
+                scope = next(s for s in UPDATE_SCOPES if counts.get(s) == most)
+        if scope is not None:
+            scopes[name] = scope
+    return scopes
+
+
+@functools.lru_cache(maxsize=8)
+def _compiled_text(update, shapes) -> str:
+    with jax.enable_x64(True):
+        return update.lower(*shapes).compile().as_text()
+
+
+def update_op_scopes() -> Dict[str, Dict[str, str]]:
+    """``{XLA module name: {HLO instruction name: scope}}`` for the update
+    programs this process has dispatched (``jit_devicegen_update``,
+    ``jit_devicegen_update_tail``, ``jit_devicegen_ring_update``).
+
+    The profiler's device-op events carry the instruction's name (``%fusion.3
+    = ...``) but neither its scope nor its metadata, so a trace reader maps
+    them through this. Each program is lowered and compiled again from the
+    abstract shapes of its first dispatch (a compile-cache hit where the
+    persistent cache is on): call it after the measured window, never on the
+    hot path."""
+    return {
+        module: hlo_op_scopes(_compiled_text(update, shapes))
+        for module, (update, shapes) in list(_DISPATCHED.items())
+    }
+
+
 @functools.lru_cache(maxsize=32)
 def _fused_update(
     vs_keys: Tuple[int, ...],
@@ -351,6 +459,7 @@ def _fused_update(
     accum_name: str,
     n_pops: int,
     set_sizes: Optional[Tuple[int, ...]] = None,
+    tail: bool = False,
 ):
     """Build (and memoize) the scanned generate→accumulate program for one
     static configuration. Memoizing at module level means every accumulator
@@ -367,7 +476,11 @@ def _fused_update(
     ``set_sizes`` carries per-variant-set cohort sizes for asymmetric
     joint-cohort configurations (``pops_bytes`` is then the concatenation of
     each set's population vector); ``None`` means every set shares the one
-    cohort ``pops_bytes`` describes."""
+    cohort ``pops_bytes`` describes.
+
+    The program is named ``devicegen_update`` (``devicegen_update_tail``
+    with ``tail``), so its XLA module is ``jit_devicegen_update[_tail]``,
+    and its scan body carries the :data:`UPDATE_SCOPES` named scopes."""
     operand_dtype = np.dtype(operand_name)
     accum_dtype = np.dtype(accum_name)
     K, B = blocks_per_dispatch, block_size
@@ -384,54 +497,61 @@ def _fused_update(
         pops_arr = jnp.asarray(np.frombuffer(pops_bytes, dtype=np.int32))
         site_key_arr = _c64(site_key)
 
-        @jax.jit
-        def update(G, rows_count, kept_count, grid_offset, n_valid):  # graftcheck: disable=GC005 -- G is not donated, same policy as ops/gramian.py:_dense_update; G here is the scan carry, and each queued dispatch holds its own output G
+        def scan_update(G, rows_count, kept_count, grid_offset, n_valid):
             block_idx = jnp.arange(K * B, dtype=jnp.int64).reshape(K, B)
 
             def body(carry, idx):
                 G, rows_count, kept_count = carry
-                index = grid_offset + idx  # (B,) grid indices
-                positions = index * spacing
-                valid = idx < n_valid
-                T = site_thresholds_on_device(
-                    site_key_arr,
-                    positions,
-                    valid,
-                    n_pops,
-                    ref_block_fraction,
-                    min_af_micro,
-                )
-                kept_count += jnp.sum(jnp.any(T > 0, axis=1)).astype(
-                    kept_count.dtype
-                )
-                hv = generate_has_variation(
-                    positions, T, vs_keys_arr, pops_arr, set_sizes
-                )
-                if column_splits is None:
-                    per_set_any = jnp.any(
-                        hv.reshape(hv.shape[0], rows_count.shape[0], -1), axis=2
+                with jax.named_scope("generate"):
+                    index = grid_offset + idx  # (B,) grid indices
+                    positions = index * spacing
+                    valid = idx < n_valid
+                    T = site_thresholds_on_device(
+                        site_key_arr,
+                        positions,
+                        valid,
+                        n_pops,
+                        ref_block_fraction,
+                        min_af_micro,
                     )
-                else:
-                    per_set_any = jnp.stack(
-                        [
-                            jnp.any(part, axis=1)
-                            for part in jnp.split(hv, column_splits, axis=1)
-                        ],
-                        axis=1,
+                with jax.named_scope("count"):
+                    kept_count += jnp.sum(jnp.any(T > 0, axis=1)).astype(
+                        kept_count.dtype
                     )
-                rows_count += jnp.sum(per_set_any, axis=0).astype(
-                    rows_count.dtype
-                )
-                # The barrier forces X to MATERIALIZE once: without it XLA
-                # fuses the whole u32 generation chain into the dot's operand
-                # producers and recomputes it per output tile — measured
-                # 4.43 s → 3.14 s whole-genome, 8.85 s → 5.46 s large-cohort
-                # on v5e (it must sit on the int8 cast; a barrier on the
-                # bool lets the cast re-fuse and drag generation with it).
-                X = lax.optimization_barrier(hv.astype(operand_dtype))
-                G = G + jnp.einsum(
-                    "bn,bm->nm", X, X, preferred_element_type=accum_dtype
-                )
+                with jax.named_scope("generate"):
+                    hv = generate_has_variation(
+                        positions, T, vs_keys_arr, pops_arr, set_sizes
+                    )
+                with jax.named_scope("count"):
+                    if column_splits is None:
+                        per_set_any = jnp.any(
+                            hv.reshape(hv.shape[0], rows_count.shape[0], -1),
+                            axis=2,
+                        )
+                    else:
+                        per_set_any = jnp.stack(
+                            [
+                                jnp.any(part, axis=1)
+                                for part in jnp.split(hv, column_splits, axis=1)
+                            ],
+                            axis=1,
+                        )
+                    rows_count += jnp.sum(per_set_any, axis=0).astype(
+                        rows_count.dtype
+                    )
+                with jax.named_scope("generate"):
+                    # The barrier forces X to MATERIALIZE once: without it
+                    # XLA fuses the whole u32 generation chain into the
+                    # dot's operand producers and recomputes it per output
+                    # tile — measured 4.43 s → 3.14 s whole-genome, 8.85 s
+                    # → 5.46 s large-cohort on v5e (it must sit on the int8
+                    # cast; a barrier on the bool lets the cast re-fuse and
+                    # drag generation with it).
+                    X = lax.optimization_barrier(hv.astype(operand_dtype))
+                with jax.named_scope("int8_dot"):
+                    G = G + jnp.einsum(
+                        "bn,bm->nm", X, X, preferred_element_type=accum_dtype
+                    )
                 return (G, rows_count, kept_count), None
 
             (G, rows_count, kept_count), _ = lax.scan(
@@ -439,7 +559,19 @@ def _fused_update(
             )
             return G, rows_count, kept_count
 
-        return update
+        if tail:
+
+            @jax.jit
+            def devicegen_update_tail(G, rows_count, kept_count, grid_offset, n_valid):  # graftcheck: disable=GC005 -- G is not donated, as in devicegen_update below
+                return scan_update(G, rows_count, kept_count, grid_offset, n_valid)
+
+            return devicegen_update_tail
+
+        @jax.jit
+        def devicegen_update(G, rows_count, kept_count, grid_offset, n_valid):  # graftcheck: disable=GC005 -- G is not donated, same policy as ops/gramian.py:_dense_update; G here is the scan carry, and each queued dispatch holds its own output G
+            return scan_update(G, rows_count, kept_count, grid_offset, n_valid)
+
+        return devicegen_update
 
 
 @functools.lru_cache(maxsize=32)
@@ -457,10 +589,11 @@ def _fused_update_mesh(
     n_pops: int,
     set_sizes: Optional[Tuple[int, ...]],
     mesh,
+    tail: bool = False,
 ):
     """The data-parallel (shard_map) wrapper of :func:`_fused_update`,
     memoized on (config, mesh) so warmup and measured accumulators share one
-    traced/compiled program, like the single-slice path."""
+    traced/compiled program, like the single-slice path; named like it."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -479,6 +612,7 @@ def _fused_update_mesh(
         accum_name,
         n_pops,
         set_sizes,
+        tail,
     )
     g_spec = P(DATA_AXIS, None, None)
     r_spec = P(DATA_AXIS, None)
@@ -488,6 +622,7 @@ def _fused_update_mesh(
         g1, r1, k1 = update(g[0], r[0], k[0], o[0], v[0])
         return g1[None], r1[None], k1[None]
 
+    per_slice.__name__ = per_slice.__qualname__ = update.__name__
     return jax.jit(
         shard_map(
             per_slice,
@@ -518,6 +653,12 @@ class _GridDispatchAccumulator:
     #: ``ring_bytes_total`` for the ring accumulator.
     sites_capacity = 0
     sites_valid = 0
+    #: host nanoseconds spent handing dispatches to the runtime: operand
+    #: uploads and the program call, where the host waits while the
+    #: device's queue is full.
+    dispatch_ns = 0
+    #: the run's span recorder; the early sync fetch is its ``poke`` span.
+    spans = None
 
     def add_ranges(self, grid_offsets: np.ndarray, n_valids: np.ndarray) -> None:
         """Data-parallel dispatch: slice d processes grid indices
@@ -547,14 +688,18 @@ class _GridDispatchAccumulator:
             # device and silently corrupt the Gramian.
             raise ValueError("grid_offsets must be non-negative")
         self._maybe_poke()
+        start = time.perf_counter_ns()
         with jax.enable_x64(True):
-            self.G, self.variant_rows, self.kept_sites = update(
+            args = (
                 self.G,
                 self.variant_rows,
                 self.kept_sites,
                 device_put_global(grid_offsets, self._scalar_sharding),
                 device_put_global(n_valids, self._scalar_sharding),
             )
+            _note_dispatch(update, args)
+            self.G, self.variant_rows, self.kept_sites = update(*args)
+        self.dispatch_ns += time.perf_counter_ns() - start
         self.dispatches += 1
         self.sites_capacity += int(cap) * D
         self.sites_valid += int(n_valids.sum())
@@ -637,7 +782,12 @@ class _GridDispatchAccumulator:
         """
         from spark_examples_tpu.parallel.mesh import local_shard
 
-        with jax.enable_x64(True):
+        span = (
+            self.spans.span("poke")
+            if self.spans is not None
+            else contextlib.nullcontext()
+        )
+        with span, jax.enable_x64(True):
             local_shard(self.kept_sites)
         self._poked = True
 
@@ -818,10 +968,11 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
         self._update_tail = None
 
     def _compile_update(self, key):
+        # Only the tail program is built through here.
         return (
-            _fused_update_mesh(*key, self.mesh)
+            _fused_update_mesh(*key, self.mesh, tail=True)
             if self.data_parallel > 1
-            else _fused_update(*key)
+            else _fused_update(*key, tail=True)
         )
 
     def _reduce_row_counts(self, rows: np.ndarray) -> np.ndarray:
@@ -852,14 +1003,18 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
         self, update, grid_offset: int, n_valid: int, cap: Optional[int] = None
     ) -> None:
         self._maybe_poke()
+        start = time.perf_counter_ns()
         with jax.enable_x64(True):
-            self.G, self.variant_rows, self.kept_sites = update(
+            args = (
                 self.G,
                 self.variant_rows,
                 self.kept_sites,
                 jnp.asarray(np.int64(grid_offset)),
                 jnp.asarray(np.int64(n_valid)),
             )
+            _note_dispatch(update, args)
+            self.G, self.variant_rows, self.kept_sites = update(*args)
+        self.dispatch_ns += time.perf_counter_ns() - start
         self.dispatches += 1
         self.sites_capacity += int(
             self.sites_per_dispatch if cap is None else cap
@@ -952,7 +1107,9 @@ def _ring_update(
     schedule (``ops/gramian.py:_hier_ring_tiles``): generation is
     schedule-independent (each device still generates its flat column
     slot) and only the tile circulation changes, so flat and hier runs are
-    byte-identical (CI-asserted)."""
+    byte-identical (CI-asserted). The program's XLA module is
+    ``jit_devicegen_ring_update``; its scan body carries the
+    :data:`UPDATE_SCOPES`."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -998,7 +1155,7 @@ def _ring_update(
         site_key_arr = _c64(site_key)
         pops_all = jnp.asarray(pops_padded)
 
-        def per_device(g, rows, kept, offset, n_valid):
+        def devicegen_ring_update(g, rows, kept, offset, n_valid):
             # g: (1, n_local, padded); offset/n_valid/kept: (1,);
             # rows: (1, n_sets)
             s_idx = jax.lax.axis_index(SAMPLES_AXIS)
@@ -1017,72 +1174,82 @@ def _ring_update(
 
             def body(carry, idx):
                 g_l, rows_l, kept_l = carry
-                positions = (offset[0] + idx) * spacing
-                valid = idx < n_valid[0]
-                T = site_thresholds_on_device(
-                    site_key_arr,
-                    positions,
-                    valid,
-                    n_pops,
-                    ref_block_fraction,
-                    min_af_micro,
-                )
-                kept_l += jnp.sum(jnp.any(T > 0, axis=1)).astype(kept_l.dtype)
-                hv = generate_column_block(
-                    positions,
-                    T,
-                    vs_keys_arr if set_sizes is not None else vs_keys_arr[0],
-                    pops_local,
-                    col_start,
-                    num_samples,
-                    set_sizes,
-                )
-                # A row "has variation" for set s if ANY of set s's columns
-                # do, across every slice (matches the dense accumulator's
-                # per-set accounting).
-                # range: bool any() → {0,1} per row, exact in int32.
-                per_set_local = jnp.stack(
-                    [
-                        jnp.any(
-                            hv
-                            & (
-                                (cols >= int(set_bounds[s]))
-                                & (cols < int(set_bounds[s + 1]))
-                            )[None, :],
-                            axis=1,
-                        ).astype(jnp.int32)
-                        for s in range(n_sets)
-                    ],
-                    axis=1,
-                )  # (B, n_sets)
-                total_any = jax.lax.psum(per_set_local, sample_axes)
-                rows_l += jnp.sum(total_any > 0, axis=0).astype(rows_l.dtype)
-                # Same materialization barrier as the dense update: the ring
-                # exchange dots the local column block against every rotated
-                # tile, so a fused generation chain would recompute per tile
-                # AND per ring step. Under the packed wire format the
-                # barrier sits on the PACKED tile — the ⅛-size buffer is
-                # what the ring circulates, and packing right after
-                # generation keeps the u32 chain materialized exactly once.
-                if pack:
-                    # range: hv is {0,1} (ops/contracts.py:HAS_VARIATION)
-                    # — exact in uint8 for the bit pack.
-                    x_cols = jax.lax.optimization_barrier(
-                        _pack_bits_device(hv.astype(jnp.uint8))
+                with jax.named_scope("generate"):
+                    positions = (offset[0] + idx) * spacing
+                    valid = idx < n_valid[0]
+                    T = site_thresholds_on_device(
+                        site_key_arr,
+                        positions,
+                        valid,
+                        n_pops,
+                        ref_block_fraction,
+                        min_af_micro,
                     )
-                else:
-                    x_cols = jax.lax.optimization_barrier(
-                        hv.astype(operand_dtype)
+                with jax.named_scope("count"):
+                    kept_l += jnp.sum(jnp.any(T > 0, axis=1)).astype(
+                        kept_l.dtype
                     )
-                if hier:
-                    g_l = _hier_ring_tiles(
-                        g_l, x_cols, HOST_AXIS, SAMPLES_AXIS,
-                        operand_dtype, packed=pack,
+                with jax.named_scope("generate"):
+                    hv = generate_column_block(
+                        positions,
+                        T,
+                        vs_keys_arr if set_sizes is not None else vs_keys_arr[0],
+                        pops_local,
+                        col_start,
+                        num_samples,
+                        set_sizes,
                     )
-                else:
-                    g_l = _ring_tiles(
-                        g_l, x_cols, SAMPLES_AXIS, operand_dtype, packed=pack
-                    )
+                with jax.named_scope("count"):
+                    # A row "has variation" for set s if ANY of set s's
+                    # columns do, across every slice (matches the dense
+                    # accumulator's per-set accounting).
+                    # range: bool any() → {0,1} per row, exact in int32.
+                    per_set_local = jnp.stack(
+                        [
+                            jnp.any(
+                                hv
+                                & (
+                                    (cols >= int(set_bounds[s]))
+                                    & (cols < int(set_bounds[s + 1]))
+                                )[None, :],
+                                axis=1,
+                            ).astype(jnp.int32)
+                            for s in range(n_sets)
+                        ],
+                        axis=1,
+                    )  # (B, n_sets)
+                    total_any = jax.lax.psum(per_set_local, sample_axes)
+                    rows_l += jnp.sum(total_any > 0, axis=0).astype(rows_l.dtype)
+                with jax.named_scope("generate"):
+                    # Same materialization barrier as the dense update: the
+                    # ring exchange dots the local column block against
+                    # every rotated tile, so a fused generation chain would
+                    # recompute per tile AND per ring step. Under the packed
+                    # wire format the barrier sits on the PACKED tile — the
+                    # ⅛-size buffer is what the ring circulates, and packing
+                    # right after generation keeps the u32 chain
+                    # materialized exactly once.
+                    if pack:
+                        # range: hv is {0,1} (ops/contracts.py:HAS_VARIATION)
+                        # — exact in uint8 for the bit pack.
+                        x_cols = jax.lax.optimization_barrier(
+                            _pack_bits_device(hv.astype(jnp.uint8))
+                        )
+                    else:
+                        x_cols = jax.lax.optimization_barrier(
+                            hv.astype(operand_dtype)
+                        )
+                # The tile ppermutes inside are scoped ``ring_exchange``.
+                with jax.named_scope("int8_dot"):
+                    if hier:
+                        g_l = _hier_ring_tiles(
+                            g_l, x_cols, HOST_AXIS, SAMPLES_AXIS,
+                            operand_dtype, packed=pack,
+                        )
+                    else:
+                        g_l = _ring_tiles(
+                            g_l, x_cols, SAMPLES_AXIS, operand_dtype, packed=pack
+                        )
                 return (g_l, rows_l, kept_l), None
 
             (g_l, rows_l, kept_l), _ = jax.lax.scan(
@@ -1092,7 +1259,7 @@ def _ring_update(
 
         return jax.jit(  # graftcheck: disable=GC005 -- G is not donated, same policy as ops/gramian.py:_dense_update; graftcheck ir cross-checks this disable against the traced donated_invars (GI002)
             shard_map(
-                per_device,
+                devicegen_ring_update,
                 mesh=mesh,
                 in_specs=(g_spec, r_spec, s_spec, s_spec, s_spec),
                 out_specs=(g_spec, r_spec, s_spec),
@@ -1377,11 +1544,14 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
 
 
 __all__ = [
+    "UPDATE_SCOPES",
     "DeviceGenGramianAccumulator",
     "DeviceGenRingGramianAccumulator",
     "auto_blocks_per_dispatch",
     "generate_column_block",
     "generate_has_variation",
+    "hlo_op_scopes",
     "mix64",
     "site_thresholds_on_device",
+    "update_op_scopes",
 ]

@@ -677,7 +677,8 @@ def _ring_tiles(G_local, X_cols, samples_axis: str, operand_dtype, packed=False)
         G, cur = carry
         # Issue step k+1's transfer first; the dot below shares no data
         # dependency with it, so the ICI permute runs behind the matmul.
-        nxt = lax.ppermute(cur, samples_axis, perm)
+        with jax.named_scope("ring_exchange"):
+            nxt = lax.ppermute(cur, samples_axis, perm)
         return dot_into(G, cur, k), nxt
 
     G_local, last = lax.fori_loop(0, D - 1, body, (G_local, X_cols))
@@ -755,7 +756,8 @@ def _hier_ring_tiles(
         def body(j, carry):
             G, cur = carry
             # Step j+1's ICI transfer first; the dot shares no dependency.
-            nxt = lax.ppermute(cur, device_axis, perm_d)
+            with jax.named_scope("ring_exchange"):
+                nxt = lax.ppermute(cur, device_axis, perm_d)
             return dot_into(G, cur, k, j), nxt
 
         G, last = lax.fori_loop(0, D - 1, body, (G, block))
@@ -770,7 +772,8 @@ def _hier_ring_tiles(
         G, cur = carry
         # Host block k+1's DCN transfer is issued before the inner ring
         # consumes block k — the whole inner ring hides one DCN hop.
-        nxt = lax.ppermute(cur, host_axis, perm_h)
+        with jax.named_scope("ring_exchange"):
+            nxt = lax.ppermute(cur, host_axis, perm_h)
         return inner_ring(G, cur, k), nxt
 
     G_local, last = lax.fori_loop(0, H - 1, outer_body, (G_local, X_cols))

@@ -248,6 +248,7 @@ def run_fused_pipeline(
     kinds: Sequence[str],
     devices=None,
     stdout_factory: Optional[Callable[[int], ContextManager]] = None,
+    run_ids: Optional[Sequence[Optional[str]]] = None,
 ) -> List[PipelineResult]:
     """Run an eligible group as ONE stacked device program; one
     :class:`PipelineResult` per job, in group order, each byte-identical
@@ -256,7 +257,8 @@ def run_fused_pipeline(
     ``stdout_factory(j)`` returns a context manager routing prints to job
     j's log; per-job phases (driver construction, result emission,
     manifest notice) run inside it. The interleaved accumulation phase
-    runs outside any job context and prints nothing."""
+    runs outside any job context and prints nothing. ``run_ids[j]``
+    stamps job j's spans (its trace id)."""
     from spark_examples_tpu.obs.manifest import (
         build_run_manifest,
         write_manifest,
@@ -284,7 +286,11 @@ def run_fused_pipeline(
             with job_stdout(j):
                 # The serial preamble, per lane: contig banner + driver
                 # construction ("Matrix size: N.") print into job j's log.
-                driver = VariantsPcaDriver(conf, devices=devices)
+                driver = VariantsPcaDriver(
+                    conf,
+                    devices=devices,
+                    run_id=run_ids[j] if run_ids is not None else None,
+                )
                 _export_compile_cache_gauges(driver.registry)
                 drivers.append(driver)
                 times.append(StageTimes(recorder=driver.spans))

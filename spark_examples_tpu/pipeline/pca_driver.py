@@ -24,6 +24,7 @@ the reference algorithm, kept as the cross-check oracle).
 from __future__ import annotations
 
 import os
+import time
 from dataclasses import dataclass
 from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
@@ -149,7 +150,23 @@ class VariantsPcaDriver:
         conf: PcaConf,
         source: Optional[GenomicsSource] = None,
         devices=None,
+        run_id: Optional[str] = None,
     ):
+        # One telemetry namespace per run: every counter/gauge/span of this
+        # driver's pipeline lands here, and the run manifest
+        # (``--metrics-json``) snapshots exactly this registry+recorder —
+        # concurrent drivers (tests, bench configs) never cross-contaminate.
+        # ``run_id`` stamps every span of the run (a served job passes its
+        # trace id); a fresh id is minted otherwise.
+        from spark_examples_tpu.obs import MetricsRegistry, SpanRecorder
+        from spark_examples_tpu.obs.trace import mint_trace_id
+
+        self.registry = MetricsRegistry()
+        self.spans = SpanRecorder(run_id=run_id or mint_trace_id())
+        with self.spans.span("driver-init"):
+            self._init_run(conf, source, devices)
+
+    def _init_run(self, conf: PcaConf, source, devices) -> None:
         self.conf = conf
         self.source = source if source is not None else make_source(conf)
         # Executor-slice support (serve/): when given, every mesh this
@@ -157,14 +174,6 @@ class VariantsPcaDriver:
         # concurrent drivers on disjoint slices never contend for HBM or
         # accumulator state. None = all devices (the historical rule).
         self.devices = list(devices) if devices is not None else None
-        # One telemetry namespace per run: every counter/gauge/span of this
-        # driver's pipeline lands here, and the run manifest
-        # (``--metrics-json``) snapshots exactly this registry+recorder —
-        # concurrent drivers (tests, bench configs) never cross-contaminate.
-        from spark_examples_tpu.obs import MetricsRegistry, SpanRecorder
-
-        self.registry = MetricsRegistry()
-        self.spans = SpanRecorder()
         self._overlap: Optional[Dict] = None
         # Crash-consistent Gramian checkpointing (pipeline/checkpoint.py):
         # the resume artifact is loaded HERE, before any ingest, so a conf
@@ -753,41 +762,167 @@ class VariantsPcaDriver:
         ≥3-set merge-intersect (``VariantsPca.scala:155-188``) reduce to
         column concatenation of per-set genotype matrices — verified against
         the wire path in tests.
+
+        The ``ingest`` span covers the call; its children are
+        ``accumulator-init`` (G and the counters), ``enqueue`` (the contig
+        loop) and ``sync`` (the closing fetch). Inside ``enqueue``, the
+        ``dispatch`` aggregate is the host time spent handing dispatches to
+        the runtime (where the host waits on a full device queue), ``stats``
+        the per-shard stats accounting and ``poke`` the early sync fetch;
+        its self time is the loop's own host work. ``ingest`` carries
+        ``sites_valid`` and ``sites_capacity``, the padding of the
+        dispatched grid.
         """
+        from spark_examples_tpu.ops.devicegen import auto_blocks_per_dispatch
+
+        with self.spans.span("ingest") as span:
+            conf = self.conf
+            mesh = self._make_mesh()
+            # Dispatch-group length: explicit flag, or constant-work auto rule
+            # (small cohorts get longer scans — per-dispatch overhead is fixed).
+            # `is None`, not falsy-or: config validation rejects non-positive
+            # explicit values, and a falsy test would silently remap them to
+            # auto if that gate were ever bypassed.
+            blocks_per_dispatch = (
+                conf.blocks_per_dispatch
+                if conf.blocks_per_dispatch is not None
+                else auto_blocks_per_dispatch(len(self.indexes), conf.block_size)
+            )
+            use_ring = self._resolve_sharded(None, mesh)
+            # The generation ring speaks both schedules: `hier` factors the
+            # samples axis host-major and runs the two-level tile exchange
+            # (ops/gramian.py:_hier_ring_tiles inside ops/devicegen.py:
+            # _ring_update), byte-identical to flat. An explicit hier request
+            # whose host factor does not divide the samples axis still raises
+            # inside the accumulator — same policy as the host-fed path.
+            reduce_schedule = getattr(conf, "reduce_schedule", "auto")
+            if not use_ring:
+                # Dense multi-process: host-sharded pod ingest. Each process
+                # generates/accumulates only its contig partition on its local
+                # devices; the partials merge exactly at finalize.
+                contigs = self._host_contigs(contigs)
+                mesh = self._ingest_mesh()
+            with self.spans.span("accumulator-init"):
+                acc = self._device_gen_accumulator(
+                    mesh, use_ring, blocks_per_dispatch, reduce_schedule
+                )
+            acc.spans = self.spans
+
+            from spark_examples_tpu.obs.metrics import (
+                INGEST_PARTITIONS_PLANNED,
+                INGEST_SITES_SCANNED,
+                well_known_gauge,
+            )
+
+            source: SyntheticGenomicsSource = self.source  # type: ignore[assignment]
+            self._device_gen_scanned = 0
+            sites_gauge = well_known_gauge(self.registry, INGEST_SITES_SCANNED)
+            ring_counter = None
+            if use_ring:
+                from spark_examples_tpu.obs.metrics import (
+                    GRAMIAN_RING_BYTES,
+                    well_known_counter,
+                )
+
+                # Deterministic host-side accounting of the ICI ring traffic
+                # (the device-generation ring has no host flush to instrument);
+                # same counter the host-fed sharded accumulator feeds. Advanced
+                # per contig so the heartbeat's "ring traffic" segment is live
+                # during ingest, not a post-finalize surprise.
+                ring_counter = well_known_counter(self.registry, GRAMIAN_RING_BYTES)
+            ring_bytes_published = 0
+            with self.spans.span("enqueue"):
+                stats_start = time.perf_counter()
+                # One shard enumeration per contig, shared by the planned-work
+                # gauge and the per-contig stats accounting below.
+                shards_by_contig = [
+                    (contig, contig.get_shards(conf.bases_per_partition))
+                    for contig in contigs
+                ]
+                well_known_gauge(self.registry, INGEST_PARTITIONS_PLANNED).set(
+                    sum(len(shards) for _, shards in shards_by_contig)
+                    * len(conf.variant_set_id)
+                )
+                stats_seconds = time.perf_counter() - stats_start
+                for contig, shards in shards_by_contig:
+                    k0, k1 = source.site_grid_range(contig)
+                    if k1 > k0:
+                        acc.add_grid(k0, k1)
+                    self._device_gen_scanned += k1 - k0
+                    sites_gauge.set(self._device_gen_scanned)
+                    if ring_counter is not None:
+                        ring_counter.inc(acc.ring_bytes_total - ring_bytes_published)
+                        ring_bytes_published = acc.ring_bytes_total
+                    if self.io_stats is not None:
+                        stats_start = time.perf_counter()
+                        # Wire-equivalent accounting: per shard, per variant set
+                        # (``SyntheticGenomicsSource.page_requests``).
+                        for _ in conf.variant_set_id:
+                            for shard in shards:
+                                self.io_stats.add_partition(shard.range)
+                        self.io_stats.add_requests(
+                            source.page_requests(contig, conf.bases_per_partition)
+                            * len(conf.variant_set_id)
+                        )
+                        stats_seconds += time.perf_counter() - stats_start
+                self.spans.add("dispatch", acc.dispatch_ns * 1e-9)
+                self.spans.add("stats", stats_seconds)
+            self._device_gen_acc = acc
+            if use_ring:
+                # Row-sharded (padded) result; compute_pca routes to the sharded
+                # centering/eigensolve from its NamedSharding.
+                self._sched_block = acc.schedule_block()
+                result = acc.finalize_sharded()
+            else:
+                result = self._merge_host_partials(acc.finalize_device())
+            from spark_examples_tpu.obs.metrics import (
+                DEVICEGEN_DISPATCHES,
+                DEVICEGEN_SITES_CAPACITY,
+            )
+
+            well_known_gauge(self.registry, DEVICEGEN_DISPATCHES).set(
+                acc.dispatches
+            )
+            # Dispatched grid capacity vs the valid sites inside it — the
+            # padding-waste denominator (the fixed tail-group overhead that
+            # dominates small regions). Ring traffic was already published
+            # incrementally inside the ingest loop.
+            well_known_gauge(self.registry, DEVICEGEN_SITES_CAPACITY).set(
+                acc.sites_capacity
+            )
+            # Epilogue: record the device-counted variant rows (per variant set,
+            # rows with variation in that set's columns — the same count the
+            # packed host path reports after its nonzero drop). Doing it here
+            # rather than leaving a flush for callers to remember keeps the
+            # stats-parity invariant even if a later stage raises, and the
+            # synchronous counter fetch makes the ingest stage's wall-clock
+            # honest on asynchronous backends. With stats disabled only the
+            # honesty sync remains (one fetch instead of two).
+            with self.spans.span("sync"):
+                if self.io_stats is not None:
+                    per_set, _kept = acc.ingest_counters()
+                    self.io_stats.add_variants(int(per_set.sum()))
+                else:
+                    acc.sync()
+            span.attrs.update(
+                sites_valid=int(acc.sites_valid),
+                sites_capacity=int(acc.sites_capacity),
+            )
+        return result
+
+    def _device_gen_accumulator(
+        self, mesh, use_ring: bool, blocks_per_dispatch: int, reduce_schedule
+    ):
+        """The device-generation accumulator of this run's configuration:
+        the ring when the samples axis is sharded, else the dense one."""
         from spark_examples_tpu.ops.devicegen import (
             DeviceGenGramianAccumulator,
             DeviceGenRingGramianAccumulator,
-            auto_blocks_per_dispatch,
         )
         from spark_examples_tpu.sources.synthetic import af_filter_micro
 
         source: SyntheticGenomicsSource = self.source  # type: ignore[assignment]
         conf = self.conf
-        mesh = self._make_mesh()
-        # Dispatch-group length: explicit flag, or constant-work auto rule
-        # (small cohorts get longer scans — per-dispatch overhead is fixed).
-        # `is None`, not falsy-or: config validation rejects non-positive
-        # explicit values, and a falsy test would silently remap them to
-        # auto if that gate were ever bypassed.
-        blocks_per_dispatch = (
-            conf.blocks_per_dispatch
-            if conf.blocks_per_dispatch is not None
-            else auto_blocks_per_dispatch(len(self.indexes), conf.block_size)
-        )
-        use_ring = self._resolve_sharded(None, mesh)
-        # The generation ring speaks both schedules: `hier` factors the
-        # samples axis host-major and runs the two-level tile exchange
-        # (ops/gramian.py:_hier_ring_tiles inside ops/devicegen.py:
-        # _ring_update), byte-identical to flat. An explicit hier request
-        # whose host factor does not divide the samples axis still raises
-        # inside the accumulator — same policy as the host-fed path.
-        reduce_schedule = getattr(conf, "reduce_schedule", "auto")
-        if not use_ring:
-            # Dense multi-process: host-sharded pod ingest. Each process
-            # generates/accumulates only its contig partition on its local
-            # devices; the partials merge exactly at finalize.
-            contigs = self._host_contigs(contigs)
-            mesh = self._ingest_mesh()
         if use_ring and len(conf.variant_set_id) > 1:
             # Sharded multi-set: the joint cohort's concatenated per-set
             # column blocks ride the same ring kernel (the join/merge
@@ -864,95 +999,7 @@ class VariantsPcaDriver:
                     else None
                 ),
             )
-
-        from spark_examples_tpu.obs.metrics import (
-            INGEST_PARTITIONS_PLANNED,
-            INGEST_SITES_SCANNED,
-            well_known_gauge,
-        )
-
-        self._device_gen_scanned = 0
-        # One shard enumeration per contig, shared by the planned-work
-        # gauge and the per-contig stats accounting below.
-        shards_by_contig = [
-            (contig, contig.get_shards(conf.bases_per_partition))
-            for contig in contigs
-        ]
-        well_known_gauge(self.registry, INGEST_PARTITIONS_PLANNED).set(
-            sum(len(shards) for _, shards in shards_by_contig)
-            * len(conf.variant_set_id)
-        )
-        sites_gauge = well_known_gauge(self.registry, INGEST_SITES_SCANNED)
-        ring_counter = None
-        if use_ring:
-            from spark_examples_tpu.obs.metrics import (
-                GRAMIAN_RING_BYTES,
-                well_known_counter,
-            )
-
-            # Deterministic host-side accounting of the ICI ring traffic
-            # (the device-generation ring has no host flush to instrument);
-            # same counter the host-fed sharded accumulator feeds. Advanced
-            # per contig so the heartbeat's "ring traffic" segment is live
-            # during ingest, not a post-finalize surprise.
-            ring_counter = well_known_counter(self.registry, GRAMIAN_RING_BYTES)
-        ring_bytes_published = 0
-        for contig, shards in shards_by_contig:
-            k0, k1 = source.site_grid_range(contig)
-            if k1 > k0:
-                acc.add_grid(k0, k1)
-            self._device_gen_scanned += k1 - k0
-            sites_gauge.set(self._device_gen_scanned)
-            if ring_counter is not None:
-                ring_counter.inc(acc.ring_bytes_total - ring_bytes_published)
-                ring_bytes_published = acc.ring_bytes_total
-            if self.io_stats is not None:
-                # Wire-equivalent accounting: per shard, per variant set
-                # (``SyntheticGenomicsSource.page_requests``).
-                for _ in conf.variant_set_id:
-                    for shard in shards:
-                        self.io_stats.add_partition(shard.range)
-                self.io_stats.add_requests(
-                    source.page_requests(contig, conf.bases_per_partition)
-                    * len(conf.variant_set_id)
-                )
-        self._device_gen_acc = acc
-        if use_ring:
-            # Row-sharded (padded) result; compute_pca routes to the sharded
-            # centering/eigensolve from its NamedSharding.
-            self._sched_block = acc.schedule_block()
-            result = acc.finalize_sharded()
-        else:
-            result = self._merge_host_partials(acc.finalize_device())
-        from spark_examples_tpu.obs.metrics import (
-            DEVICEGEN_DISPATCHES,
-            DEVICEGEN_SITES_CAPACITY,
-        )
-
-        well_known_gauge(self.registry, DEVICEGEN_DISPATCHES).set(
-            acc.dispatches
-        )
-        # Dispatched grid capacity vs the valid sites inside it — the
-        # padding-waste denominator bench.py reports per config (the fixed
-        # tail-group overhead that dominates small regions). Ring traffic
-        # was already published incrementally inside the ingest loop.
-        well_known_gauge(self.registry, DEVICEGEN_SITES_CAPACITY).set(
-            acc.sites_capacity
-        )
-        # Epilogue: record the device-counted variant rows (per variant set,
-        # rows with variation in that set's columns — the same count the
-        # packed host path reports after its nonzero drop). Doing it here
-        # rather than leaving a flush for callers to remember keeps the
-        # stats-parity invariant even if a later stage raises, and the
-        # synchronous counter fetch makes the ingest stage's wall-clock
-        # honest on asynchronous backends. With stats disabled only the
-        # honesty sync remains (one fetch instead of two).
-        if self.io_stats is not None:
-            per_set, _kept = acc.ingest_counters()
-            self.io_stats.add_variants(int(per_set.sum()))
-        else:
-            acc.sync()
-        return result
+        return acc
 
     def _host_similarity(self, calls: Iterable[List[int]]) -> np.ndarray:
         """Literal host replication of ``getSimilarityMatrix``
@@ -975,6 +1022,11 @@ class VariantsPcaDriver:
         ``similarity`` may be a host array or a device-resident matrix from
         :meth:`get_similarity_matrix`; the TPU path runs every stage on
         device and fetches only the (N, num_pc) result.
+
+        Spans: ``center`` and ``eigh`` time the host ENQUEUE of their
+        device programs (nothing waits for the device inside them); the
+        ``fetch`` span around the one synchronous transfer of the
+        components waits for all of it.
         """
         import jax
         import jax.numpy as jnp
@@ -1016,9 +1068,10 @@ class VariantsPcaDriver:
             # x64 because the finalize reduce hands back an int64 Gramian.
             with jax.enable_x64(True):
                 nz = jnp.any(similarity != 0, axis=1).sum()
-            fetched, nonzero = _fetch_components_and_nonzero(
-                device_components, nz, sharded_mesh
-            )
+            with self.spans.span("fetch"):
+                fetched, nonzero = _fetch_components_and_nonzero(
+                    device_components, nz, sharded_mesh
+                )
             print(f"Non zero rows in matrix: {nonzero} / {n}.")
             components = fetched.astype(np.float64)[:n]
         else:
@@ -1045,9 +1098,10 @@ class VariantsPcaDriver:
             # result of the finalize reduce.
             with jax.enable_x64(True):
                 nz = jnp.any(S != 0, axis=1).sum()
-            fetched, nonzero = _fetch_components_and_nonzero(
-                device_components, nz, None
-            )
+            with self.spans.span("fetch"):
+                fetched, nonzero = _fetch_components_and_nonzero(
+                    device_components, nz, None
+                )
             print(f"Non zero rows in matrix: {nonzero} / {n}.")
             components = fetched.astype(np.float64)
         reverse = {i: cs_id for cs_id, i in self.indexes.items()}
@@ -1134,7 +1188,10 @@ def run(argv: Sequence[str]) -> List[str]:
 
 
 def run_pipeline(
-    conf: PcaConf, similarity_only: bool = False, devices=None
+    conf: PcaConf,
+    similarity_only: bool = False,
+    devices=None,
+    run_id: Optional[str] = None,
 ) -> PipelineResult:
     """The run-an-analysis core, CLI-free: config in, result + manifest
     out. ``run`` (batch) and the resident service's executor
@@ -1147,7 +1204,8 @@ def run_pipeline(
     (``parallel/mesh.py:plan_executor_slices``): meshes resolve over the
     slice only, and mesh-less (dense, single-device) work is pinned to
     the slice's first device so concurrent slices never contend for one
-    default device."""
+    default device. ``run_id`` stamps the run's spans (a served job's
+    trace id; a fresh id when ``None``)."""
     if getattr(conf, "fault_plan", None) is not None:
         # The flag wins over the SPARK_EXAMPLES_TPU_FAULTS environment
         # variable; configuring resets hit counts, so every run starts a
@@ -1298,7 +1356,7 @@ def run_pipeline(
                 f"--ingest packed needs a .vcf[.gz] input; got {selected!r} "
                 "(use --ingest wire for JSONL/checkpoint inputs)"
             )
-    driver = VariantsPcaDriver(conf, source, devices=devices)
+    driver = VariantsPcaDriver(conf, source, devices=devices, run_id=run_id)
     _export_compile_cache_gauges(driver.registry)
     from spark_examples_tpu.utils.tracing import StageTimes, device_trace
 

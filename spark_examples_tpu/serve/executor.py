@@ -158,7 +158,10 @@ def execute_job(job: Job, run_dir: str) -> ExecutionOutcome:
                 manifest_path = grm.manifest_path
             else:
                 pipeline = run_pipeline(
-                    conf, similarity_only=similarity_only, devices=devices
+                    conf,
+                    similarity_only=similarity_only,
+                    devices=devices,
+                    run_id=job.trace_id,
                 )
                 if similarity_only:
                     result = {"similarity": pipeline.similarity_summary}
@@ -265,6 +268,7 @@ def execute_fused_batch(
                 kinds,
                 devices=getattr(jobs[0], "slice_devices", None),
                 stdout_factory=lambda j: switch.routed(files[j]),
+                run_ids=[job.trace_id for job in jobs],
             )
         finally:
             sys.stdout = previous

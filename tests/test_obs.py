@@ -223,6 +223,96 @@ def test_span_records_on_exception_and_across_threads():
     assert [s["name"] for s in rec.as_list()] == ["outer", "worker"]
 
 
+def test_closed_spans_reach_the_process_buffer():
+    from spark_examples_tpu.obs.spans import recent_spans
+
+    rec = SpanRecorder(run_id="run-a")
+    with rec.span("ingest") as ingest:
+        with rec.span("enqueue"):
+            rec.add("stats", 0.125)
+        ingest.attrs.update(sites_valid=10, sites_capacity=16)
+    mine = [r for r in recent_spans() if r["run_id"] == "run-a"]
+    assert [r["path"] for r in mine] == [
+        "ingest/enqueue/stats", "ingest/enqueue", "ingest",
+    ]
+    assert [r["parent"] for r in mine] == ["ingest/enqueue", "ingest", None]
+    assert mine[-1]["attrs"] == {"sites_valid": 10, "sites_capacity": 16}
+    assert mine[0]["seconds"] == 0.125
+    assert all(isinstance(r["started_unix_ns"], int) for r in mine)
+    # The manifest's tree carries the attributes too.
+    assert rec.as_list()[0]["attrs"] == {"sites_valid": 10, "sites_capacity": 16}
+
+
+def test_span_buffer_stays_bounded():
+    from spark_examples_tpu.obs import spans
+
+    limit = spans._RECENT.maxlen
+    rec = SpanRecorder(run_id="flood")
+    for _ in range(limit + 50):
+        with rec.span("tick"):
+            pass
+    assert len(spans.recent_spans()) == limit
+    assert all(r["run_id"] == "flood" for r in spans.recent_spans())
+
+
+def test_span_self_time_excludes_children():
+    rec = SpanRecorder()
+    with rec.span("enqueue"):
+        rec.add("stats", 0.5)
+        with rec.span("poke"):
+            pass
+    enqueue = rec.find("enqueue")
+    poke = rec.find("enqueue/poke")
+    # The pre-measured aggregate may exceed the real elapsed time; self
+    # time is duration minus every closed child, whatever they claim.
+    assert enqueue.self_seconds == pytest.approx(
+        enqueue.seconds - 0.5 - poke.seconds
+    )
+    assert poke.self_seconds == poke.seconds
+
+
+def test_device_gen_spans_land_in_the_profiler_trace(tmp_path):
+    """Under a profiler session, a tiny device-generation job's stage
+    spans are host events of the xplane, named ``sxt:<path>`` and nested
+    in time."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from spark_examples_tpu.config import PcaConf
+    from spark_examples_tpu.pipeline.pca_driver import VariantsPcaDriver
+    from spark_examples_tpu.sources.synthetic import SyntheticGenomicsSource
+
+    source = SyntheticGenomicsSource(num_samples=8, seed=3)
+    conf = PcaConf.parse(
+        ["--ingest", "device", "--num-samples", "8", "--block-size", "64",
+         "--references", "1:0:40000"]
+    )
+    driver = VariantsPcaDriver(conf, source, devices=jax.devices()[:1])
+    contigs = conf.get_contigs(source, conf.variant_set_id)
+    with jax.profiler.trace(str(tmp_path)):
+        driver.get_similarity_device_gen(contigs)
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("sxt:"):
+                    events[ev.name] = (ev.start_ns, ev.start_ns + ev.duration_ns)
+    assert {"sxt:ingest", "sxt:ingest/enqueue"} <= set(events)
+    outer, inner = events["sxt:ingest"], events["sxt:ingest/enqueue"]
+    assert outer[0] <= inner[0] <= inner[1] <= outer[1]
+    ingest = driver.spans.find("ingest")
+    assert ingest.run_id == driver.spans.run_id and ingest.run_id
+    assert [c.name for c in ingest.children] == [
+        "accumulator-init", "enqueue", "sync",
+    ]
+    enqueue = driver.spans.find("ingest/enqueue")
+    assert {c.name for c in enqueue.children} >= {"dispatch", "stats"}
+    assert 0 < driver.spans.find("ingest/enqueue/dispatch").seconds <= enqueue.seconds
+    assert set(ingest.attrs) == {"sites_valid", "sites_capacity"}
+    assert 0 < ingest.attrs["sites_valid"] <= ingest.attrs["sites_capacity"]
+
+
 def test_stage_times_format_and_recorder_shim():
     rec = SpanRecorder()
     times = StageTimes(recorder=rec)

@@ -72,10 +72,10 @@ def test_dense_update_compiles(one_chip, operand, accum):
     assert _about(mem.output_size_in_bytes, N_1KG * N_1KG * 4)
 
 
-def test_devicegen_whole_genome_update_compiles(one_chip):
+def _whole_genome_update(one_chip):
     """The fused generate→accumulate scan at the whole-genome dispatch
-    geometry (bench.py's whole-genome config: spacing 73, B=16384, the
-    auto dispatch length)."""
+    geometry (spacing 73, B=16384, the auto dispatch length) and its
+    arguments' shapes on one described chip."""
     from spark_examples_tpu.ops.devicegen import _fused_update, auto_blocks_per_dispatch
     from spark_examples_tpu.sources.synthetic import SyntheticGenomicsSource
 
@@ -97,14 +97,95 @@ def test_devicegen_whole_genome_update_compiles(one_chip):
             None,
         )
         scalar = _spec((), jnp.int64, one_chip)
-        compiled = update.lower(
+        shapes = (
             _spec((N_1KG, N_1KG), jnp.int32, one_chip),
             _spec((1,), jnp.int64, one_chip),
             scalar,
             scalar,
             scalar,
-        ).compile()
+        )
+    return update, shapes
+
+
+def test_devicegen_whole_genome_update_compiles(one_chip):
+    update, shapes = _whole_genome_update(one_chip)
+    with jax.enable_x64(True):
+        compiled = update.lower(*shapes).compile()
     assert compiled.memory_analysis().output_size_in_bytes >= N_1KG * N_1KG * 4
+
+
+def _instructions(hlo_text):
+    """``{instruction name: its line}`` of a compiled module's text."""
+    out = {}
+    for line in hlo_text.splitlines():
+        text = line.strip()
+        if " = " in text:
+            out[text.split(" = ", 1)[0].removeprefix("ROOT ").lstrip("%")] = text
+    return out
+
+
+def test_devicegen_update_op_scopes_split_generation_from_the_dot(one_chip):
+    """The scope map of the whole-genome update as the chip compiles it:
+    the int8 dot (every convolution) is ``int8_dot``; the genotype hash
+    fusion, the concatenation of the population segments and the cast to
+    the int8 operand are ``generate``."""
+    from spark_examples_tpu.ops import devicegen
+
+    update, shapes = _whole_genome_update(one_chip)
+    saved = dict(devicegen._DISPATCHED)
+    devicegen._DISPATCHED.clear()
+    try:
+        devicegen._note_dispatch(update, shapes)
+        scopes = devicegen.update_op_scopes()
+        text = devicegen._compiled_text(update, shapes)
+    finally:
+        devicegen._DISPATCHED.clear()
+        devicegen._DISPATCHED.update(saved)
+    assert list(scopes) == ["jit_devicegen_update"]
+    scope = scopes["jit_devicegen_update"]
+    lines = _instructions(text)
+
+    def named(test):
+        found = [name for name, line in lines.items() if test(name, line.split(" = ", 1)[1])]
+        assert found
+        return {scope.get(name) for name in found}
+
+    assert named(lambda n, rhs: "convolution" in n or " convolution(" in rhs) == {"int8_dot"}
+    assert named(
+        lambda n, rhs: " fusion(" in rhs and f"pred[{BLOCK}," in rhs.split(" fusion(")[0]
+    ) == {"generate"}
+    assert named(lambda n, rhs: " concatenate(" in rhs) == {"generate"}
+    assert named(lambda n, rhs: " convert(" in rhs and rhs.startswith(f"s8[{BLOCK},")) == {
+        "generate"
+    }
+    assert set(scope.values()) == {"generate", "int8_dot", "count"}
+
+
+def test_ring_update_op_scopes_hold_the_ring_exchange():
+    """On four virtual CPU devices: the ring program is named
+    ``jit_devicegen_ring_update`` and its tile ``ppermute`` is scoped
+    ``ring_exchange``."""
+    from spark_examples_tpu.ops import devicegen
+    from spark_examples_tpu.parallel.mesh import SAMPLES_AXIS, make_mesh
+    from spark_examples_tpu.sources.synthetic import SyntheticGenomicsSource
+
+    if jax.devices()[0].platform != "cpu" or jax.device_count() < 4:
+        pytest.skip("needs four virtual CPU devices")
+    source = SyntheticGenomicsSource(num_samples=18, seed=9)
+    acc = devicegen.DeviceGenRingGramianAccumulator(
+        num_samples=18,
+        vs_key=source.genotype_stream_key("vs"),
+        pops=source.populations,
+        site_key=source.site_key,
+        spacing=source.variant_spacing,
+        ref_block_fraction=source.ref_block_fraction,
+        mesh=make_mesh({SAMPLES_AXIS: 4}),
+        block_size=16,
+        blocks_per_dispatch=2,
+    )
+    acc.add_grid(0, 40)
+    scopes = devicegen.update_op_scopes()["jit_devicegen_ring_update"]
+    assert {"generate", "int8_dot", "count", "ring_exchange"} <= set(scopes.values())
 
 
 def test_finalize_compiles(one_chip):
