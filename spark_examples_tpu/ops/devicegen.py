@@ -153,7 +153,8 @@ def _pop_segments(pops_np: np.ndarray) -> Optional[list]:
     population vector, or ``None`` when the vector is not contiguous (or too
     fragmented to be worth unrolling). The synthetic source assigns
     contiguous population blocks by construction, which lets the kernel
-    broadcast one scalar threshold per segment instead of a (B, N) gather."""
+    pick each column's threshold by a static select over the segment stops
+    (:func:`_segment_thresholds`) instead of a (B, N) gather."""
     if pops_np.ndim != 1 or len(pops_np) == 0:
         return None
     diffs = np.diff(pops_np)
@@ -172,6 +173,31 @@ def _pop_segments(pops_np: np.ndarray) -> Optional[list]:
     return [
         (int(pops_np[s]), int(s), int(e)) for s, e in zip(starts, stops)
     ]
+
+
+def _set_segments(
+    pops_np: np.ndarray, n_sets: int, set_sizes: Optional[Tuple[int, ...]]
+) -> list:
+    """Per variant set, its :func:`_pop_segments` (``None`` where the set
+    takes the gather). ``pops_np`` is the concatenation of the per-set
+    population vectors when ``set_sizes`` is given, else the one cohort
+    every set shares."""
+    if set_sizes is None:
+        return [_pop_segments(pops_np)] * n_sets
+    cum = np.concatenate([[0], np.cumsum(set_sizes)])
+    return [_pop_segments(pops_np[cum[s] : cum[s + 1]]) for s in range(n_sets)]
+
+
+def _segment_thresholds(Tq32: jax.Array, segments: list) -> jax.Array:
+    """(B, n) per-column Q32 thresholds of one set's contiguous segments: a
+    static select chain over the segment stops on a column iota, so the
+    threshold broadcast fuses into the hash that compares against it."""
+    n = segments[-1][2]
+    cols = lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    tf = Tq32[:, segments[-1][0] : segments[-1][0] + 1]
+    for pop, _, stop in reversed(segments[:-1]):
+        tf = jnp.where(cols < stop, Tq32[:, pop : pop + 1], tf)
+    return tf
 
 
 def _allele_pair(h2_col: jax.Array, samples_u64: jax.Array):
@@ -213,9 +239,11 @@ def generate_has_variation(
     ``pops`` describes.
 
     When ``pops`` is a concrete array (always the case from the memoized
-    update builders, which close over it), contiguous population blocks are
-    unrolled into per-segment scalar-threshold compares — no (B, N) gather;
-    a traced or non-contiguous ``pops`` falls back to the gather.
+    update builders, which close over it), a set of contiguous population
+    blocks picks each column's threshold by a static select chain
+    (:func:`_segment_thresholds`) — no (B, N) gather, and one hash over all
+    of the set's columns; a traced or non-contiguous ``pops`` falls back to
+    the gather.
     """
     n_sets = vs_keys.shape[0]
     try:
@@ -234,6 +262,11 @@ def generate_has_variation(
             lax.slice_in_dim(pops, offsets[s], offsets[s] + sizes[s])
             for s in range(n_sets)
         ]
+    segments = (
+        _set_segments(pops_np, n_sets, set_sizes)
+        if pops_np is not None
+        else [None] * n_sets
+    )
     # range: Q32 thresholds are < 2^32 by construction (clipped at
     # _POP_HI_Q32, sources/synthetic.py) — uint32 holds them exactly.
     Tq32 = thresholds.astype(jnp.uint32)
@@ -242,28 +275,13 @@ def generate_has_variation(
     for s in range(n_sets):
         h1 = mix64(vs_keys[s] ^ pos_term)  # (B,)
         h2 = mix64(h1 ^ _c64(_S_GENOTYPE * _P3))[:, None]
-        segments = (
-            _pop_segments(pops_np[offsets[s] : offsets[s] + sizes[s]])
-            if pops_np is not None
-            else None
-        )
-        if segments is not None:
-            columns = []
-            for pop, start, stop in segments:
-                samples = (
-                    jnp.arange(start, stop, dtype=jnp.uint64) * _c64(_P4)
-                )[None, :]
-                d1, d2 = _allele_pair(h2, samples)
-                tf = Tq32[:, pop : pop + 1]  # (B, 1) broadcast
-                columns.append((d1 < tf) | (d2 < tf))
-            parts.append(jnp.concatenate(columns, axis=1))
+        samples = (jnp.arange(sizes[s], dtype=jnp.uint64) * _c64(_P4))[None, :]
+        d1, d2 = _allele_pair(h2, samples)
+        if segments[s] is not None:
+            tf = _segment_thresholds(Tq32, segments[s])  # (B, N_s)
         else:
-            samples = (jnp.arange(sizes[s], dtype=jnp.uint64) * _c64(_P4))[
-                None, :
-            ]
-            d1, d2 = _allele_pair(h2, samples)
             tf = jnp.take(Tq32, pops_dyn[s], axis=1)  # (B, N_s)
-            parts.append((d1 < tf) | (d2 < tf))
+        parts.append((d1 < tf) | (d2 < tf))
     return jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
 
 
@@ -494,8 +512,30 @@ def _fused_update(
         vs_keys_arr = jnp.asarray(
             np.array([k & _MASK64 for k in vs_keys], dtype=np.uint64)
         )
-        pops_arr = jnp.asarray(np.frombuffer(pops_bytes, dtype=np.int32))
+        pops_np = np.frombuffer(pops_bytes, dtype=np.int32)
+        pops_arr = jnp.asarray(pops_np)
         site_key_arr = _c64(site_key)
+        segmented = any(_set_segments(pops_np, len(vs_keys), set_sizes))
+
+        def count_rows(rows_count, rows):
+            """Add each set's rows with variation in any of its columns."""
+            with jax.named_scope("count"):
+                if column_splits is None:
+                    per_set_any = jnp.any(
+                        rows.reshape(rows.shape[0], rows_count.shape[0], -1),
+                        axis=2,
+                    )
+                else:
+                    per_set_any = jnp.stack(
+                        [
+                            jnp.any(part, axis=1)
+                            for part in jnp.split(rows, column_splits, axis=1)
+                        ],
+                        axis=1,
+                    )
+                return rows_count + jnp.sum(per_set_any, axis=0).astype(
+                    rows_count.dtype
+                )
 
         def scan_update(G, rows_count, kept_count, grid_offset, n_valid):
             block_idx = jnp.arange(K * B, dtype=jnp.int64).reshape(K, B)
@@ -522,23 +562,8 @@ def _fused_update(
                     hv = generate_has_variation(
                         positions, T, vs_keys_arr, pops_arr, set_sizes
                     )
-                with jax.named_scope("count"):
-                    if column_splits is None:
-                        per_set_any = jnp.any(
-                            hv.reshape(hv.shape[0], rows_count.shape[0], -1),
-                            axis=2,
-                        )
-                    else:
-                        per_set_any = jnp.stack(
-                            [
-                                jnp.any(part, axis=1)
-                                for part in jnp.split(hv, column_splits, axis=1)
-                            ],
-                            axis=1,
-                        )
-                    rows_count += jnp.sum(per_set_any, axis=0).astype(
-                        rows_count.dtype
-                    )
+                if not segmented:
+                    rows_count = count_rows(rows_count, hv)
                 with jax.named_scope("generate"):
                     # The barrier forces X to MATERIALIZE once: without it
                     # XLA fuses the whole u32 generation chain into the
@@ -546,8 +571,18 @@ def _fused_update(
                     # tile — measured 4.43 s → 3.14 s whole-genome, 8.85 s
                     # → 5.46 s large-cohort on v5e (it must sit on the int8
                     # cast; a barrier on the bool lets the cast re-fuse and
-                    # drag generation with it).
+                    # drag generation with it). With contiguous population
+                    # segments the hash, the per-column threshold select and
+                    # the cast are one fusion that writes X in its final
+                    # layout: joining per-segment results instead cost a
+                    # standalone copy and cast (475 + 78 ms per whole-genome
+                    # job at 2,504 columns, PERF.md).
                     X = lax.optimization_barrier(hv.astype(operand_dtype))
+                if segmented:
+                    # Read back from X: counting from hv would make a
+                    # second fusion that recomputes the hash (the gathered
+                    # path's hash fusion emits the count beside its rows).
+                    rows_count = count_rows(rows_count, X)
                 with jax.named_scope("int8_dot"):
                     G = G + jnp.einsum(
                         "bn,bm->nm", X, X, preferred_element_type=accum_dtype
@@ -653,6 +688,10 @@ class _GridDispatchAccumulator:
     #: ``ring_bytes_total`` for the ring accumulator.
     sites_capacity = 0
     sites_valid = 0
+    #: population segments whose columns the update generates in one pass
+    #: against a selected per-column threshold (:func:`_set_segments`,
+    #: summed over variant sets); 0 where every set gathers its thresholds.
+    pop_segments = 0
     #: host nanoseconds spent handing dispatches to the runtime: operand
     #: uploads and the program call, where the host waits while the
     #: device's queue is full.
@@ -902,6 +941,11 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
         self.dispatches = 0
 
         pops32 = np.asarray(pops, dtype=np.int32)
+        self.pop_segments = sum(
+            len(segments)
+            for segments in _set_segments(pops32, self.n_sets, self.set_sizes)
+            if segments
+        )
         update_key = (
             tuple(int(k) for k in vs_keys),
             pops32.tobytes(),

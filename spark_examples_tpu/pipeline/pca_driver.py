@@ -771,7 +771,8 @@ class VariantsPcaDriver:
         the per-shard stats accounting and ``poke`` the early sync fetch;
         its self time is the loop's own host work. ``ingest`` carries
         ``sites_valid`` and ``sites_capacity``, the padding of the
-        dispatched grid.
+        dispatched grid, and ``pop_segments``, the population segments
+        generated in one pass (0 where the thresholds are gathered).
         """
         from spark_examples_tpu.ops.devicegen import auto_blocks_per_dispatch
 
@@ -907,6 +908,7 @@ class VariantsPcaDriver:
             span.attrs.update(
                 sites_valid=int(acc.sites_valid),
                 sites_capacity=int(acc.sites_capacity),
+                pop_segments=int(acc.pop_segments),
             )
         return result
 

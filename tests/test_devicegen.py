@@ -182,6 +182,77 @@ def test_device_rows_bitwise_match_host_packed_path():
     np.testing.assert_array_equal(rows[keep], host_rows)
 
 
+@pytest.mark.parametrize(
+    "sets", [("vs",), ("setA", "setB")], ids=["one-set", "two-sets"]
+)
+def test_segmented_generation_matches_gather_and_host(sets):
+    """Cohorts of contiguous populations of at least 128 columns (512 = 4 ×
+    128, and 640 = 4 × 160 for the second set) select each column's
+    threshold inside the hash instead of gathering it: the rows equal the
+    gather branch (a traced ``pops``) and the host packed path bitwise, and
+    the fused accumulator's Gramian and counters equal the host's."""
+    source = SyntheticGenomicsSource(num_samples=512, seed=5, cohort_sizes={"setB": 640})
+    contig = Contig("1", 0, 40_000)
+    sizes = tuple(source.num_samples_for(s) for s in sets)
+    set_sizes = sizes if len(sets) > 1 else None
+    pops = np.concatenate([source.populations_for(s) for s in sets]).astype(np.int32)
+    keys = np.array([source.genotype_stream_key(s) for s in sets], dtype=np.uint64)
+    k0, k1 = source.site_grid_range(contig)
+    grid_pos = np.arange(k0, k1, dtype=np.int64) * source.variant_spacing
+
+    # The host's rows on the site grid, per set side by side; rows the host
+    # drops are all-zero.
+    host = np.zeros((len(grid_pos), sum(sizes)), dtype=np.uint8)
+    host_rows = []
+    for s, (vsid, lo) in enumerate(zip(sets, np.cumsum((0,) + sizes))):
+        blocks = _host_blocks(source, vsid, contig)
+        rows = np.concatenate([b["has_variation"] for b in blocks])
+        at = np.searchsorted(grid_pos, np.concatenate([b["positions"] for b in blocks]))
+        host[at, lo : lo + sizes[s]] = rows
+        host_rows.append(len(rows))
+
+    with jax.enable_x64(True):
+        T = site_thresholds_on_device(
+            jax.numpy.asarray(np.uint64(source.site_key)),
+            jax.numpy.asarray(grid_pos),
+            jax.numpy.asarray(np.ones(len(grid_pos), dtype=bool)),
+            source.n_pops,
+            source.ref_block_fraction,
+            None,
+        )
+        args = (jax.numpy.asarray(grid_pos), T, jax.numpy.asarray(keys))
+        segmented = np.asarray(
+            generate_has_variation(*args, jax.numpy.asarray(pops), set_sizes)
+        )
+        gathered = np.asarray(
+            jax.jit(generate_has_variation, static_argnums=4)(
+                *args, jax.numpy.asarray(pops), set_sizes
+            )
+        )
+    np.testing.assert_array_equal(segmented, gathered)
+    np.testing.assert_array_equal(segmented.astype(np.uint8), host)
+
+    acc = DeviceGenGramianAccumulator(
+        num_samples=512,
+        vs_keys=[int(k) for k in keys],
+        pops=pops,
+        site_key=source.site_key,
+        spacing=source.variant_spacing,
+        ref_block_fraction=source.ref_block_fraction,
+        block_size=64,
+        blocks_per_dispatch=4,
+        n_pops=source.n_pops,
+        set_sizes=set_sizes,
+        pops_per_set=[source.populations_for(s) for s in sets] if set_sizes else None,
+    )
+    assert acc.pop_segments == 4 * len(sets)
+    acc.add_grid(k0, k1)
+    np.testing.assert_array_equal(acc.finalize(), gramian_reference(host))
+    rows, kept = acc.ingest_counters()
+    assert rows.tolist() == host_rows
+    assert kept == sum(len(p) for p, _ in source.site_threshold_plan(contig))
+
+
 @pytest.mark.parametrize("exact_int", [True, False])
 def test_fused_accumulator_matches_reference_gramian(exact_int):
     source = SyntheticGenomicsSource(num_samples=24, seed=11)

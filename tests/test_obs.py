@@ -309,8 +309,9 @@ def test_device_gen_spans_land_in_the_profiler_trace(tmp_path):
     enqueue = driver.spans.find("ingest/enqueue")
     assert {c.name for c in enqueue.children} >= {"dispatch", "stats"}
     assert 0 < driver.spans.find("ingest/enqueue/dispatch").seconds <= enqueue.seconds
-    assert set(ingest.attrs) == {"sites_valid", "sites_capacity"}
+    assert set(ingest.attrs) == {"sites_valid", "sites_capacity", "pop_segments"}
     assert 0 < ingest.attrs["sites_valid"] <= ingest.attrs["sites_capacity"]
+    assert ingest.attrs["pop_segments"] == 0  # 8 samples gather their thresholds
 
 
 def test_stage_times_format_and_recorder_shim():

@@ -124,11 +124,25 @@ def _instructions(hlo_text):
     return out
 
 
+def _computations(hlo_text):
+    """``{computation name: its instruction lines}`` of a compiled module."""
+    out, body = {}, None
+    for line in hlo_text.splitlines():
+        text = line.strip()
+        if text.endswith("{") and " = " not in text:
+            words = text.split()
+            body = out.setdefault(words[1 if words[0] == "ENTRY" else 0].lstrip("%"), [])
+        elif body is not None and " = " in text:
+            body.append(text)
+    return out
+
+
 def test_devicegen_update_op_scopes_split_generation_from_the_dot(one_chip):
     """The scope map of the whole-genome update as the chip compiles it:
-    the int8 dot (every convolution) is ``int8_dot``; the genotype hash
-    fusion, the concatenation of the population segments and the cast to
-    the int8 operand are ``generate``."""
+    the int8 dot (every convolution) is ``int8_dot``; one ``generate``
+    fusion evaluates the genotype hash over all 2,504 columns and writes
+    the int8 operand itself, with no concatenation of population segments
+    (their per-column thresholds are selected inside the hash)."""
     from spark_examples_tpu.ops import devicegen
 
     update, shapes = _whole_genome_update(one_chip)
@@ -151,14 +165,36 @@ def test_devicegen_update_op_scopes_split_generation_from_the_dot(one_chip):
         return {scope.get(name) for name in found}
 
     assert named(lambda n, rhs: "convolution" in n or " convolution(" in rhs) == {"int8_dot"}
-    assert named(
-        lambda n, rhs: " fusion(" in rhs and f"pred[{BLOCK}," in rhs.split(" fusion(")[0]
-    ) == {"generate"}
-    assert named(lambda n, rhs: " concatenate(" in rhs) == {"generate"}
-    assert named(lambda n, rhs: " convert(" in rhs and rhs.startswith(f"s8[{BLOCK},")) == {
-        "generate"
-    }
     assert set(scope.values()) == {"generate", "int8_dot", "count"}
+
+    # The scan body: the computation that calls the dot's fusion.
+    bodies = _computations(text)
+
+    def called(line):
+        return line.split("calls=")[1].split(",")[0].lstrip("%") if "calls=" in line else None
+
+    dot = next(name for name, ops in bodies.items() if any(" convolution(" in op for op in ops))
+    (body,) = [ops for ops in bodies.values() if any(called(op) == dot for op in ops)]
+    assert not [
+        op for op in body
+        if " concatenate(" in op and f"[{BLOCK}," in op.split(" concatenate(")[0]
+    ]
+    hashes = [
+        op for op in body
+        if " fusion(" in op
+        and any(
+            f"u32[{BLOCK},{N_1KG}]" in inner and " multiply(" in inner
+            for inner in bodies[called(op)]
+        )
+    ]
+    assert len(hashes) == 1
+    (operand,) = [
+        op.split(" = ", 1)[0].lstrip("%")
+        for op in body
+        if " fusion(" in op and op.split(" = ", 1)[1].startswith(f"s8[{BLOCK},{N_1KG}]")
+    ]
+    assert hashes[0].split(" = ", 1)[0].lstrip("%") == operand
+    assert scope[operand] == "generate"
 
 
 def test_ring_update_op_scopes_hold_the_ring_exchange():
