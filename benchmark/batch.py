@@ -42,7 +42,7 @@ class Job:
             "--num-pc", str(cfg["num_pc"]),
             "--num-samples", str(cfg["num_samples"]),
             "--seed", str(cfg["cohort_seed"]),
-        ]
+        ] + [str(flag) for flag in cfg.get("flags", [])]
 
     def __call__(self, references: str) -> dict:
         from spark_examples_tpu.config import PcaConf
@@ -112,25 +112,43 @@ def window(job: Job, seed: int, seconds: float) -> dict:
 
 def check(cell: dict, kept: dict) -> dict:
     """Compare the kept jobs with the plain reference: the Gramian exactly,
-    the components after sign alignment."""
-    import jax
-
+    tile by tile on the devices that hold it (one scalar fetched per
+    shard, no N² array on the host), then the components against the
+    reference's float64 eigenpairs. Prints the seconds of each part and the
+    process's peak host memory."""
     cfg, spacing = cell["config"], int(cell["traffic"]["spacing"])
-    fetched = []
+    n = int(cfg["num_samples"])
+    resident = core.resident_bytes()
+    seconds = {"tiles": 0.0, "comparison": 0.0, "eigen": 0.0}
+    refs, jobs = {}, []
+    gap_g = gap_pc = 0.0
     for index in sorted(kept):
         record = kept.pop(index)
-        G = np.asarray(jax.device_get(record["S"])).astype(np.int64)
-        fetched.append((G[: cfg["num_samples"], : cfg["num_samples"]], record["pcs"], record["ranges"]))
-        del record
-    refs = {}
-    gap_g, gap_pc = 0.0, 0.0
-    for G, V, ranges in fetched:
-        key = tuple(sorted(ranges))
+        key = tuple(sorted(record["ranges"]))
+        start = time.perf_counter()
         if key not in refs:
-            G_ref = reference.gramian(cfg, ranges, spacing)
-            refs[key] = (G_ref, reference.reference_eigen(cfg, G_ref))
-            core.say(f"reference eigenvalues {refs[key][1][0].tolist()}")
-        G_ref, (vals, vecs) = refs[key]
-        gap_g = max(gap_g, float(np.abs(G - G_ref).max()))
-        gap_pc = max(gap_pc, reference.eigenspace_gap(V, vals, vecs))
+            layout = reference.shard_layout(record["S"], n)
+            refs[key] = reference.gramian_tiles(cfg, record["ranges"], spacing, layout)
+            for tile in refs[key]:
+                tile.data.block_until_ready()
+            seconds["tiles"] += time.perf_counter() - start
+            start = time.perf_counter()
+        gap_g = max(gap_g, reference.max_abs_diff(record["S"], refs[key], n))
+        seconds["comparison"] += time.perf_counter() - start
+        jobs.append((key, record["pcs"]))
+        del record
+    start = time.perf_counter()
+    for key in refs:
+        vals, vecs = reference.reference_eigen(cfg, refs[key])
+        core.say(f"reference eigenvalues {vals.tolist()}")
+        for job_key, V in jobs:
+            if job_key == key:
+                gap_pc = max(gap_pc, reference.eigenspace_gap(V, vals, vecs))
+    seconds["eigen"] = time.perf_counter() - start
+    refs.clear()
+    core.say(
+        "check: " + ", ".join(f"{name} {s:.3f} s" for name, s in seconds.items())
+        + f"; host memory {resident / 1e9:.3f} GB resident at its start, "
+        f"process peak {core.peak_resident_bytes() / 1e9:.3f} GB"
+    )
     return {"gramian_max_abs_diff": gap_g, "pc_eigenspace_gap": gap_pc}

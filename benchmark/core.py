@@ -95,6 +95,19 @@ def process_age_s() -> float:
     return time.clock_gettime(time.CLOCK_BOOTTIME) - start_ticks / os.sysconf("SC_CLK_TCK")
 
 
+def resident_bytes() -> int:
+    """This process's resident host memory now (Linux)."""
+    with open("/proc/self/statm", encoding="ascii") as f:
+        return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+
+
+def peak_resident_bytes() -> int:
+    """The most host memory this process has held resident (Linux)."""
+    import resource
+
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+
+
 class CompileClock:
     """Tracing, lowering and compiling seconds, read from JAX's own
     monitoring events (copied from ``chip_smoke.py``)."""

@@ -1,11 +1,13 @@
 """Plain reference of a PCoA job, independent of the program.
 
 It imports nothing of ``spark_examples_tpu`` and takes nothing the program
-made. From the cohort's published definition (the configuration file: seed,
-cohort size, populations, reference-block fraction) and a job's site grid it
-regenerates the has-variation genotypes, forms the exact integer Gramian
-``G = XᵀX``, double-centres it in float64 and takes the top principal
-components with a float64 symmetric eigensolve.
+made: from the program it reads only the Gramian it checks and the row
+ranges of that array's shards. From the cohort's published definition (the
+configuration file: seed, cohort size, populations, reference-block
+fraction) and a job's site grid it regenerates the has-variation genotypes,
+forms the exact integer Gramian ``G = XᵀX`` in row tiles, one per shard of
+the checked array and on that shard's device, double-centres it in float64
+and takes the top principal components in float64.
 
 The genotype definition is the synthetic cohort's, written out here from its
 specification: counter-based splitmix64 site streams give each grid site a
@@ -19,21 +21,28 @@ job's contigs that hold grid index ``k``.
 
 Exactness: the operands are {0, 1} times a multiplicity of at most a few
 dozen, exact in bfloat16; each block's product accumulates in float32 below
-2^24 and is added into an int64 host total, so the Gramian is exact.
+2^24 and is added into an int32 tile whose totals stay far below 2^31, so
+the Gramian is exact. The comparison runs on the devices and fetches one
+scalar per shard. The eigenpairs come from a dense float64 ``eigh`` up to
+``DENSE_EIGH_MAX_N`` samples and from Lanczos over the centred Gramian as
+an operator above it (``CentredGramian``: exact integer products, float64
+centring, no N² float64 array); each pair's residual is held to
+``RESIDUAL_BOUND``.
 
 The control of ``PERF.md`` is the same job one rung down the precision
 ladder at every stage: the Gramian carried in bfloat16
-(``gramian(..., precision="control")``), the centring in float32, and the
-program's own algorithm, subspace iteration, with float8 matmul operands
+(``gramian_tiles(..., precision="control")``), the centring in float32, and
+the program's own algorithm, subspace iteration, with float8 matmul operands
 where the program's float32 matmuls take bfloat16 ones on the TPU
-(``control_pcs``).
+(``control_pcs``), all over the same row tiles.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Iterable, List, Sequence, Tuple
+import time
+from typing import Iterable, List, NamedTuple, Sequence, Tuple
 
 import numpy as np
 
@@ -154,8 +163,10 @@ def block_sites(num_samples: int) -> int:
 
 @functools.lru_cache(maxsize=None)
 def _block_program(
-    num_samples: int, n_pops: int, ref_block_fraction: float, block: int, carrier: str
+    num_samples: int, n_pops: int, ref_block_fraction: float, block: int, rows: int, carrier: str
 ):
+    """``G += xw[:, r0:r0+rows]ᵀ · x`` for one block of grid sites: the
+    ``rows`` rows of the Gramian from sample ``r0`` on, over all columns."""
     import jax
     import jax.numpy as jnp
 
@@ -181,18 +192,21 @@ def _block_program(
 
     ref_thresh = math.ceil(ref_block_fraction * 2.0**53)
 
-    def run(G, site_key, vs_key, pops, start, n_valid, spacing, weight):
+    def run(G, site_key, vs_key, pops, start, n_valid, spacing, weight, r0):
         i = jnp.arange(block, dtype=jnp.int64)
         pos_term = ((start + i) * spacing).astype(u64) * c(_P2)
         is_ref = (stream(site_key, pos_term, _S_REF_BLOCK) >> u64(11)) < c(ref_thresh)
         u_af = stream(site_key, pos_term, _S_AF) >> u64(48)
         af = c(_AF_BASE_Q32) + ((u_af * u_af * c(_AF_SPAN_Q16)) >> u64(16))
-        per_pop = []
+        # Each sample's population threshold, (B, N): a select over the few
+        # populations (the same values as a gather, without one).
+        thresholds = None
         for p in range(n_pops):
             u_p = stream(site_key, pos_term, _S_POP_BASE + p) >> u64(48)
             factor = c(_POP_BASE_Q16) + ((u_p * c(_POP_SPAN_Q17)) >> u64(16))
-            per_pop.append(jnp.clip((af * factor) >> u64(16), c(_POP_LO_Q32), c(_POP_HI_Q32)))
-        thresholds = jnp.stack(per_pop, axis=1)[:, pops].astype(u32)  # (B, N)
+            t_p = jnp.clip((af * factor) >> u64(16), c(_POP_LO_Q32), c(_POP_HI_Q32))
+            t_p = t_p.astype(u32)[:, None]
+            thresholds = t_p if thresholds is None else jnp.where(pops[None, :] == p, t_p, thresholds)
         h2 = mix(mix(vs_key ^ pos_term) ^ c(_S_GENOTYPE * _P3))
         sample_term = jnp.arange(num_samples, dtype=jnp.int64).astype(u64) * c(_P4)
         x64 = h2[:, None] ^ sample_term[None, :]
@@ -201,60 +215,154 @@ def _block_program(
         keep = (i < n_valid) & ~is_ref
         has = keep[:, None] & ((d1 < thresholds) | (d2 < thresholds))
         x = has.astype(jnp.bfloat16)
-        xw = (has.astype(jnp.int32) * weight).astype(jnp.bfloat16)
+        xw = jax.lax.dynamic_slice_in_dim(has, r0, rows, axis=1)
+        xw = (xw.astype(jnp.int32) * weight).astype(jnp.bfloat16)
+        # Written once: fused into the dot, the hash would be recomputed for
+        # every tile of the product.
+        x, xw = jax.lax.optimization_barrier((x, xw))
         part = jnp.dot(xw.T, x, preferred_element_type=jnp.float32)
         if carrier == "bfloat16":
             return (G.astype(jnp.float32) + part).astype(jnp.bfloat16)
         return G + part.astype(jnp.int32)
 
-    return jax.jit(run)
+    return jax.jit(run, donate_argnums=0)
 
 
-def gramian(
+# ------------------------------------------------------------ row tiles
+
+
+class Tile(NamedTuple):
+    """Rows ``r0:r1`` of a job's Gramian, all columns, on ``device``."""
+
+    r0: int
+    r1: int
+    device: object
+    data: object
+
+
+def row_layout(n: int, devices) -> List[Tuple[int, int, object]]:
+    """``n`` rows split into equal ``(r0, r1, device)`` ranges, one per
+    device (the last one shorter)."""
+    per = -(-n // len(devices))
+    return [(r0, min(n, r0 + per), d) for r0, d in zip(range(0, n, per), devices)]
+
+
+def shard_layout(S, n: int) -> List[Tuple[int, int, object]]:
+    """The row ranges of ``S``'s addressable shards that hold rows below
+    ``n`` (padding left out), each once, on the first device holding it."""
+    layout = {}
+    for shard in S.addressable_shards:
+        r0, r1, _ = shard.index[0].indices(S.shape[0])
+        if min(r1, n) > r0:
+            layout.setdefault((r0, min(r1, n)), shard.device)
+    return [(r0, r1, d) for (r0, r1), d in sorted(layout.items())]
+
+
+def gramian_tiles(
     cohort: dict,
     ranges: Sequence[Tuple[int, int]],
     spacing: int,
+    layout: Sequence[Tuple[int, int, object]],
     precision: str = "exact",
-    device=None,
-) -> np.ndarray:
-    """The job's Gramian over grid-index ``ranges`` (one per contig), as
-    float64 on the host: exact integers (int32 on the device, whose totals
-    stay far below 2^31), or the control's bfloat16 carrier."""
+) -> List[Tile]:
+    """The job's Gramian over grid-index ``ranges`` (one per contig), one
+    tile of rows per ``(r0, r1, device)`` of ``layout``, each computed on its
+    device: exact integers (int32, whose totals stay far below 2^31), or the
+    control's bfloat16 carrier. Every tile sees the same blocks in the same
+    order, so a tile is exactly those rows of the whole matrix."""
     import jax
     import jax.numpy as jnp
 
     n = int(cohort["num_samples"])
     block = block_sites(n)
     carrier = "bfloat16" if precision == "control" else "int32"
+    dtype = jnp.bfloat16 if carrier == "bfloat16" else jnp.int32
     site_key, vs_key = cohort_keys(int(cohort["cohort_seed"]), cohort["variant_set_id"])
-    program = _block_program(
-        n, int(cohort["n_pops"]), float(cohort["ref_block_fraction"]), block, carrier
-    )
-    with jax.enable_x64(True), jax.default_device(device or jax.devices()[0]):
-        keys = (jnp.asarray(np.uint64(site_key)), jnp.asarray(np.uint64(vs_key)))
-        pops = jnp.asarray(populations(n, int(cohort["n_pops"])).astype(np.int32))
-        G = jnp.zeros((n, n), dtype=jnp.bfloat16 if carrier == "bfloat16" else jnp.int32)
+    pops = populations(n, int(cohort["n_pops"])).astype(np.int32)
+
+    def program(rows):
+        return _block_program(
+            n, int(cohort["n_pops"]), float(cohort["ref_block_fraction"]), block, rows, carrier
+        )
+
+    with jax.enable_x64(True):
+        consts = {
+            d: jax.device_put((np.uint64(site_key), np.uint64(vs_key), pops), d)
+            for _, _, d in layout
+        }
+        tiles = [jnp.zeros((r1 - r0, n), dtype=dtype, device=d) for r0, r1, d in layout]
         for a, b, m in weighted_segments(ranges):
             for start in range(a, b, block):
-                G = program(
-                    G, *keys, pops, np.int64(start), np.int64(min(block, b - start)),
-                    np.int64(spacing), np.int32(m),
-                )
-        return np.asarray(G.astype(jnp.float32) if carrier == "bfloat16" else G).astype(
-            np.float64
-        )
+                for t, (r0, r1, d) in enumerate(layout):
+                    tiles[t] = program(r1 - r0)(
+                        tiles[t], *consts[d], np.int64(start), np.int64(min(block, b - start)),
+                        np.int64(spacing), np.int32(m), np.int64(r0),
+                    )
+    return [Tile(r0, r1, d, g) for (r0, r1, d), g in zip(layout, tiles)]
+
+
+def host_gramian(tiles: Sequence[Tile]) -> np.ndarray:
+    """The whole matrix in float64 on the host, from its tiles (small
+    cohorts only: the dense eigensolve and tests)."""
+    parts = [np.asarray(t.data) for t in sorted(tiles, key=lambda t: t.r0)]
+    return np.concatenate(parts).astype(np.float64)
+
+
+@functools.lru_cache(maxsize=None)
+def _tile_gap(rows: int, n: int):
+    import jax
+    import jax.numpy as jnp
+
+    def gap(s, t):
+        return jnp.max(jnp.abs(s[:rows, :n].astype(jnp.int64) - t.astype(jnp.int64)))
+
+    return jax.jit(gap)
+
+
+def max_abs_diff(S, tiles: Sequence[Tile], n: int) -> float:
+    """``max |S − G|`` over the ``n`` × ``n`` block, compared tile by tile
+    on the devices that hold ``S``'s shards (every replica of a shard), one
+    scalar fetched per shard. ``S`` may be padded past ``n``; a smaller one,
+    or a shard that holds only part of its rows' columns, reads infinite."""
+    import jax
+
+    if S.shape[0] < n or S.shape[1] < n:
+        return math.inf
+    by_rows = {(t.r0, t.r1): t for t in tiles}
+    gaps = []
+    with jax.enable_x64(True):
+        for shard in S.addressable_shards:
+            r0, r1, _ = shard.index[0].indices(S.shape[0])
+            c0, c1, _ = shard.index[1].indices(S.shape[1])
+            if min(r1, n) <= r0:
+                continue
+            if c0 != 0 or c1 < n:
+                return math.inf
+            tile = by_rows[(r0, min(r1, n))]
+            data = tile.data if tile.device == shard.device else jax.device_put(tile.data, shard.device)
+            gaps.append(_tile_gap(tile.r1 - tile.r0, n)(shard.data, data))
+        return float(max(int(g) for g in gaps))
+
+
+def tiles_max_abs_diff(a: Sequence[Tile], b: Sequence[Tile]) -> float:
+    """``max |A − B|`` of two tilings over the same layout (the control's
+    Gramian against the exact one), one scalar fetched per tile."""
+    import jax
+
+    with jax.enable_x64(True):
+        gaps = [_tile_gap(x.r1 - x.r0, x.data.shape[1])(x.data, y.data) for x, y in zip(a, b)]
+        return float(max(int(g) for g in gaps))
 
 
 # ------------------------------------------------------------ finalize
 
 
-def center(G: np.ndarray, precision: str = "exact") -> np.ndarray:
-    """Gower double centring: v − rowMean − colMean + matrixMean."""
-    dtype = np.float32 if precision == "control" else np.float64
-    S = G.astype(dtype)
-    row = S.mean(axis=1, keepdims=True, dtype=dtype)
-    col = S.mean(axis=0, keepdims=True, dtype=dtype)
-    return (S - row - col + S.mean(dtype=dtype)).astype(dtype)
+def center(G: np.ndarray) -> np.ndarray:
+    """Gower double centring in float64: v − rowMean − colMean + matrixMean."""
+    S = G.astype(np.float64)
+    row = S.mean(axis=1, keepdims=True)
+    col = S.mean(axis=0, keepdims=True)
+    return S - row - col + S.mean()
 
 
 def eigenpairs(B: np.ndarray, count: int) -> tuple:
@@ -304,49 +412,250 @@ def _round_fp8(x):
     magnitude maps near the format's top), as an fp8 matmul takes it."""
     import jax.numpy as jnp
 
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 256.0
+    return _round_fp8_scaled(x, jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 256.0)
+
+
+def _round_fp8_scaled(x, scale):
+    import jax.numpy as jnp
+
     return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
 
 
-def control_components(B: np.ndarray, num_pc: int, iterations: int = 80, oversample: int = 8):
-    """The control's eigensolve: the program's algorithm (subspace iteration
-    and Rayleigh-Ritz) one rung below the precision its float32 matmuls run
-    at on the TPU by default (bfloat16 operands, float32 sums): every
-    matmul operand rounded to float8 e4m3, sums and the QR in float32."""
+@functools.lru_cache(maxsize=None)
+def _control_programs():
+    """Jitted pieces of the control's eigensolve: the float32 centring of a
+    row tile (``G − (r_i + r_j) + m``, symmetric by construction), its
+    largest magnitude, its fp8 rounding under the whole matrix's scale, and
+    the fp8-operand products."""
     import jax
     import jax.numpy as jnp
 
-    n = B.shape[0]
-    k = min(num_pc + oversample, n)
-    V0 = np.linalg.qr(np.random.default_rng(0).standard_normal((n, k)))[0]
+    def centred(g, r_rows, r, m):
+        return g.astype(jnp.float32) - (r_rows[:, None] + r[None, :]) + m
+
+    def row_means(g):
+        return jnp.mean(g.astype(jnp.float32), axis=1)
+
+    def absmax(g, r_rows, r, m):
+        return jnp.max(jnp.abs(centred(g, r_rows, r, m)))
+
+    def rounded(g, r_rows, r, m, scale):
+        return _round_fp8_scaled(centred(g, r_rows, r, m), scale)
+
+    def rows_times(bq, v):
+        return jnp.dot(bq, _round_fp8(v), precision="highest")
 
     def mm(a, b):
         return jnp.dot(_round_fp8(a), _round_fp8(b), precision="highest")
 
-    @jax.jit
-    def solve(B, V):
-        B = (B + B.T) * 0.5
+    def qr(w):
+        return jnp.linalg.qr(w)[0]
 
-        def body(_, V):
-            return jnp.linalg.qr(mm(B, V))[0]
-
-        V = jax.lax.fori_loop(0, iterations, body, V)
-        T = mm(V.T, mm(B, V))
-        evals, Wk = jnp.linalg.eigh((T + T.T) * 0.5)
-        order = jnp.argsort(-jnp.abs(evals))[:num_pc]
-        return mm(V, Wk[:, order])
-
-    out = solve(jnp.asarray(B, dtype=jnp.float32), jnp.asarray(V0, dtype=jnp.float32))
-    return np.asarray(out).astype(np.float64)
+    return {name: jax.jit(f) for name, f in (
+        ("row_means", row_means), ("absmax", absmax), ("rounded", rounded),
+        ("rows_times", rows_times), ("mm", mm), ("qr", qr),
+    )}
 
 
-def reference_eigen(cohort: dict, G: np.ndarray) -> tuple:
+def control_components(tiles: Sequence[Tile], num_pc: int, iterations: int = 80, oversample: int = 8):
+    """The control's eigensolve over row tiles of its bfloat16-carried
+    Gramian: the centring in float32, then the program's algorithm
+    (subspace iteration and Rayleigh-Ritz) one rung below the precision its
+    float32 matmuls run at on the TPU by default (bfloat16 operands,
+    float32 sums): every matmul operand rounded to float8 e4m3 with one
+    scale for the whole array, sums and the QR in float32. Each product
+    ``B·V`` is the tiles' row blocks, each on its own device; the QR and
+    the small products run on the first tile's device."""
+    import jax
+
+    f = _control_programs()
+    tiles = sorted(tiles, key=lambda t: t.r0)
+    n = tiles[0].data.shape[1]
+    home = tiles[0].device
+    r = np.concatenate([np.asarray(f["row_means"](t.data)) for t in tiles]).astype(np.float32)
+    m = r.mean(dtype=np.float32)
+    args = [(t, jax.device_put(r[t.r0 : t.r1], t.device), jax.device_put(r, t.device)) for t in tiles]
+    top = max(float(f["absmax"](t.data, rr, ra, m)) for t, rr, ra in args)
+    scale = np.float32(max(top, 1e-30) / 256.0)
+    Bq = [(t.device, f["rounded"](t.data, rr, ra, m, scale)) for t, rr, ra in args]
+    del args
+
+    def times_b(V):
+        parts = [f["rows_times"](bq, jax.device_put(V, d)) for d, bq in Bq]
+        return jax.device_put(np.concatenate([np.asarray(p) for p in parts]), home)
+
+    k = min(num_pc + oversample, n)
+    V0 = np.linalg.qr(np.random.default_rng(0).standard_normal((n, k)))[0]
+    V = jax.device_put(V0.astype(np.float32), home)
+    for _ in range(iterations):
+        V = f["qr"](times_b(V))
+    T = np.asarray(f["mm"](V.T, times_b(V)))
+    evals, Wk = np.linalg.eigh((T + T.T) * np.float32(0.5))
+    order = np.argsort(-np.abs(evals))[:num_pc]
+    return np.asarray(f["mm"](V, jax.device_put(Wk[:, order], home))).astype(np.float64)
+
+
+def control_pcs(cohort: dict, tiles_control: Sequence[Tile]) -> np.ndarray:
+    """The control's components from its own (bfloat16-carried) Gramian."""
+    return control_components(tiles_control, int(cohort["num_pc"]))
+
+
+# ------------------------------------------------------------ eigen reference
+
+#: Largest cohort whose reference eigenpairs come from a dense float64
+#: eigensolve of the whole centred matrix on the host (both batch cells and
+#: the served mix); above it, Lanczos over ``CentredGramian``.
+DENSE_EIGH_MAX_N = 4096
+
+#: Every reference eigenpair's residual ``‖Bu − λu‖₂ / λ₁`` is at most this.
+RESIDUAL_BOUND = 1e-9
+
+#: ARPACK's stopping tolerance: each Ritz pair's residual under this share
+#: of its eigenvalue, far inside ``RESIDUAL_BOUND``.
+LANCZOS_TOL = 1e-12
+
+#: Base-128 digits of a vector's fixed-point form: 2^-54 of its largest
+#: entry, finer than float64's own rounding.
+_VECTOR_DIGITS = 8
+
+
+@functools.lru_cache(maxsize=None)
+def _digit_programs(digits: int, rows: int):
+    import jax
+    import jax.numpy as jnp
+
+    def planes(g):
+        g = jnp.pad(g, ((0, rows - g.shape[0]), (0, 0)))
+        return jnp.stack([((g >> (7 * i)) & 127).astype(jnp.int8) for i in range(digits)])
+
+    def times(p, d):
+        return jnp.einsum("prn,nc->prc", p, d, preferred_element_type=jnp.int32)
+
+    return jax.jit(planes), jax.jit(times)
+
+
+class CentredGramian:
+    """The Gower-centred exact Gramian ``B = JGJ`` as an operator, applied
+    in float64 without forming B: ``Bv = Gv − r(1ᵀv) − 1(rᵀv) + m(1ᵀv)1``,
+    with ``r`` the row means (row sums exact in int64), ``m`` the mean, and
+    G symmetric, so its column means are ``r`` too.
+
+    ``Gv`` is exact integer arithmetic on the tiles' devices: each tile is
+    held as base-128 digit planes in int8 (padded with zero rows to the
+    longest tile, one array sharded by rows over the tiles' devices), ``v``
+    as a power-of-two scale per column and eight signed base-128 digits of
+    its fixed-point form, and each plane by digit product is an int8 matmul
+    with exact int32 sums (at most N·127·64 < 2^31), put together on the
+    host in float64: one dispatch and one fetch per product."""
+
+    def __init__(self, tiles: Sequence[Tile]):
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        tiles = sorted(tiles, key=lambda t: t.r0)
+        self.n = n = tiles[0].data.shape[1]
+        if [t.r0 for t in tiles] != [0] + [t.r1 for t in tiles[:-1]] or tiles[-1].r1 != n:
+            raise ValueError("the tiles do not cover the rows 0..N once each")
+        if n * 127 * 64 >= 2**31:
+            raise ValueError(f"N = {n} is too large for exact int32 digit sums")
+        with jax.enable_x64(True):
+            sums = [jnp.sum(t.data, axis=1, dtype=jnp.int64) for t in tiles]
+            low = min(int(jnp.min(t.data)) for t in tiles)
+            top = max(int(jnp.max(t.data)) for t in tiles)
+            row_sums = np.concatenate([np.asarray(s) for s in sums])
+        if low < 0:
+            raise ValueError("a Gramian of counts has no negative entry")
+        self.digits = max(1, -(-top.bit_length() // 7))
+        rows = max(t.r1 - t.r0 for t in tiles)
+        planes, self._times = _digit_programs(self.digits, rows)
+        mesh = Mesh(np.array([t.device for t in tiles]), ("rows",))
+        self.planes = jax.make_array_from_single_device_arrays(
+            (self.digits, rows * len(tiles), n),
+            NamedSharding(mesh, P(None, "rows", None)),
+            [planes(t.data) for t in tiles],
+        )
+        self._replicated = NamedSharding(mesh, P())
+        self._valid = np.concatenate([i * rows + np.arange(t.r1 - t.r0) for i, t in enumerate(tiles)])
+        self.r = row_sums / n
+        self.m = float(int(row_sums.sum())) / n / n
+        self.products = 0
+        self.device_seconds = 0.0
+
+    def gv(self, V: np.ndarray) -> np.ndarray:
+        """``G·V`` for an (N, b) float64 ``V``, to float64 rounding."""
+        import jax
+
+        b = V.shape[1]
+        # A power of two at or over each column's largest entry, so that
+        # scaling rounds nothing.
+        scale = np.ldexp(1.0, np.frexp(np.abs(V).max(axis=0))[1])
+        Y = np.rint(V / scale * 2.0**54).astype(np.int64)
+        D = np.empty((self.n, _VECTOR_DIGITS, b), np.int8)
+        for j in range(_VECTOR_DIGITS):
+            d = ((Y + 64) & 127) - 64
+            D[:, j, :] = d
+            Y = (Y - d) >> 7
+        start = time.perf_counter()
+        D = jax.device_put(D.reshape(self.n, _VECTOR_DIGITS * b), self._replicated)
+        R = np.asarray(self._times(self.planes, D))
+        self.device_seconds += time.perf_counter() - start
+        R = R[:, self._valid].astype(np.float64).reshape(self.digits, self.n, _VECTOR_DIGITS, b)
+        weight = 2.0 ** (7 * (np.arange(self.digits)[:, None] + np.arange(_VECTOR_DIGITS)[None, :]))
+        self.products += 1
+        return np.einsum("prjb,pj->rb", R, weight) * (scale / 2.0**54)
+
+    def matmat(self, V: np.ndarray) -> np.ndarray:
+        V = np.asarray(V, dtype=np.float64).reshape(self.n, -1)
+        ones_v = V.sum(axis=0)
+        return self.gv(V) - np.outer(self.r, ones_v) - (self.r @ V)[None, :] + self.m * ones_v[None, :]
+
+    def matvec(self, v: np.ndarray) -> np.ndarray:
+        return self.matmat(v).reshape(np.shape(v))
+
+
+def lanczos_eigenpairs(op: CentredGramian, count: int) -> tuple:
+    """The ``count`` largest eigenpairs of the operator in float64,
+    descending: implicitly restarted Lanczos (ARPACK) from a fixed start.
+    Its vector operations are small and many, so BLAS runs them on one
+    thread rather than waking a pool that shares the host's cores with the
+    accelerator runtime at every step."""
+    from scipy.sparse.linalg import LinearOperator, eigsh
+    from threadpoolctl import threadpool_limits
+
+    n = op.n
+    A = LinearOperator((n, n), matvec=op.matvec, matmat=op.matmat, dtype=np.float64)
+    with threadpool_limits(limits=1, user_api="blas"):
+        vals, vecs = eigsh(
+            A, k=count, which="LA", tol=LANCZOS_TOL, ncv=min(n - 1, 4 * count + 8),
+            v0=np.random.default_rng(0).standard_normal(n),
+        )
+    order = np.argsort(vals)[::-1]
+    return vals[order], vecs[:, order]
+
+
+def reference_eigen(cohort: dict, tiles: Sequence[Tile]) -> tuple:
     """The reference's leading eigenpairs of the centred Gramian: a few
     more than the components asked for, so a cluster at the last one is
-    whole."""
-    return eigenpairs(center(G), int(cohort["num_pc"]) + 6)
+    whole. Dense float64 ``eigh`` up to ``DENSE_EIGH_MAX_N`` samples,
+    Lanczos above; either way each pair's residual is printed and held to
+    ``RESIDUAL_BOUND``."""
+    from benchmark.core import BenchFailure, say
 
-
-def control_pcs(cohort: dict, G_control: np.ndarray) -> np.ndarray:
-    """The control's components from its own (bfloat16-carried) Gramian."""
-    return control_components(center(G_control, "control"), int(cohort["num_pc"]))
+    count = int(cohort["num_pc"]) + 6
+    n = tiles[0].data.shape[1]
+    if n <= DENSE_EIGH_MAX_N:
+        B = center(host_gramian(tiles))
+        vals, vecs = eigenpairs(B, count)
+        B = (B + B.T) * 0.5
+        apply, method = (lambda V: B @ V), "dense eigh"
+    else:
+        op = CentredGramian(tiles)
+        vals, vecs = lanczos_eigenpairs(op, count)
+        apply = op.matmat
+        method = f"Lanczos, {op.products} products, {op.device_seconds:.3f} s on the devices and links"
+    residuals = np.linalg.norm(apply(vecs) - vecs * vals, axis=0) / abs(vals[0])
+    say(f"reference eigenpairs ({method}): residuals ‖Bu − λu‖/λ1 {residuals.tolist()}")
+    if not np.all(residuals <= RESIDUAL_BOUND):
+        raise BenchFailure(f"reference eigenpairs not converged: residuals {residuals.tolist()}")
+    return vals, vecs

@@ -1,5 +1,6 @@
-"""The operations and bytes a Gramian update needs, from its shapes, and
-the table of peaks they are held against (``peaks.json``)."""
+"""The operations and bytes a Gramian update needs, from its shapes, the
+chip-to-chip bytes a samples-sharded one must exchange, and the table of
+peaks they are held against (``peaks.json``)."""
 
 from __future__ import annotations
 
@@ -35,9 +36,20 @@ def gramian_bytes(num_samples: int, accum_bytes: int = 4) -> int:
     return num_samples * num_samples * accum_bytes
 
 
-def least_seconds(num_samples: int, sites: int, device_kind: str) -> tuple:
-    """(least seconds, which bound sets it) for one Gramian."""
+def least_seconds(num_samples: int, sites: int, device_kind: str, chips: int = 1) -> tuple:
+    """(least seconds, which bound sets it) for one Gramian computed on
+    ``chips`` chips together: the work and the bytes against ``chips`` times
+    one chip's peaks."""
     p = peaks(device_kind)
-    ops_s = gramian_ops(num_samples, sites) / p["int8_ops_per_s"]
-    bytes_s = gramian_bytes(num_samples) / p["hbm_bytes_per_s"]
+    ops_s = gramian_ops(num_samples, sites) / (chips * p["int8_ops_per_s"])
+    bytes_s = gramian_bytes(num_samples) / (chips * p["hbm_bytes_per_s"])
     return (ops_s, "ops") if ops_s >= bytes_s else (bytes_s, "bytes")
+
+
+def ring_bytes(num_samples: int, sites: int, chips: int, packed: bool) -> int:
+    """The least bytes each chip must receive over the chip-to-chip links
+    for a Gramian whose samples are split over ``chips``: the genotypes of
+    every site for the samples the other chips hold, ``(chips − 1)/chips``
+    of the cohort, one byte per genotype, or one bit when ``packed``."""
+    genotypes = sites * num_samples * (chips - 1) // chips
+    return -(-genotypes // 8) if packed else genotypes

@@ -155,6 +155,8 @@ def parse_pc_lines(lines: list) -> np.ndarray:
 def check(cell: dict, result: dict, seed: int) -> dict:
     """Compare a seed-drawn sample of the finished requests with the plain
     reference; a request that failed or never finished is wrong."""
+    import jax
+
     cfg, trf = cell["config"], cell["traffic"]
     spacing = int(trf["spacing"])
     done = [r for r in result["requests"] if r["status"] == "done"]
@@ -163,12 +165,14 @@ def check(cell: dict, result: dict, seed: int) -> dict:
     picks = np.random.default_rng([seed & ((1 << 64) - 1), 11]).choice(
         len(done), size=count, replace=False
     ) if count else []
+    layout = reference.row_layout(int(cfg["num_samples"]), jax.devices()[:1])
     gap = 0.0
     for i in picks:
         req = done[int(i)]
         contig, start, end = req["references"].split(":")
         ranges = [reference.grid_range(int(start), int(end), spacing)]
-        vals, vecs = reference.reference_eigen(cfg, reference.gramian(cfg, ranges, spacing))
+        tiles = reference.gramian_tiles(cfg, ranges, spacing, layout)
+        vals, vecs = reference.reference_eigen(cfg, tiles)
         V = parse_pc_lines(req["job"]["result"]["pc_lines"])
         gap = max(gap, reference.eigenspace_gap(V, vals, vecs))
     return {

@@ -4,12 +4,15 @@ Each test drives a whole dry run of a cell (``benchmark/run.py --dry``
 skips the look for a chip) with the timed path broken underneath, and sees
 ``correct`` come out false: a step that returns its state unchanged, half
 of a job's sites left out, an answer altered where it is produced, an
-answer that never comes. The control, the plain reference one rung down
-the precision ladder, fails the cells' limits at test size too. One cell
-runs on one chip, so there is no exchange between chips to leave out.
+answer that never comes, and, on a samples-sharded ring over four
+devices, a ring step whose tile exchange is left out and a shard that
+keeps its state. The control, the plain reference one rung down the
+precision ladder, fails the cells' limits at test size too.
 
 The served cell is not in ``BENCHMARK.json`` (``PERF.md``, Open
-questions); its harness is driven here through a manifest that holds it.
+questions), nor is the four-device ring cell (a 64-sample cohort on a 1x4
+mesh, ``data/ring64.json``, held to kg1000's limits); their harness is
+driven here through a manifest that holds them.
 """
 
 import json
@@ -27,6 +30,7 @@ from benchmark import run as bench_run  # noqa: E402
 
 
 SERVED = "kg1000.brca1-served"
+RING = "ring64.wgs-batch"
 
 
 @pytest.fixture()
@@ -55,6 +59,34 @@ def served_cell(monkeypatch):
         return doc
 
     monkeypatch.setattr(core, "manifest", with_served)
+
+
+@pytest.fixture()
+def ring_cell(monkeypatch):
+    """The manifest with a four-device ring cell: the configuration's
+    flags put 64 samples on a 1x4 mesh through the sharded strategy."""
+    manifest, load_limits = core.manifest, core.load_limits
+
+    def with_ring():
+        doc = manifest()
+        doc["configs"].append(
+            {"name": "ring64", "source": "test cohort", "file": "tests/benchmark/data/ring64.json",
+             "reduced": [], "why": "the samples-sharded ring at test size"}
+        )
+        doc["workloads"].append(
+            {"name": RING, "config": "ring64", "traffic": "wgs-batch", "chips": 4,
+             "why": "a Gramian sharded over four devices, tiles exchanged in a ring"}
+        )
+        for metric in doc["end_to_end"] + doc["per_layer"]:
+            if "kg1000.wgs-batch" in metric.get("workloads", []):
+                metric["workloads"].append(RING)
+        return doc
+
+    def limits(cell):
+        return load_limits("kg1000.wgs-batch" if cell == RING else cell)
+
+    monkeypatch.setattr(core, "manifest", with_ring)
+    monkeypatch.setattr(core, "load_limits", limits)
 
 
 def _run(capfd, cell, trace=0):
@@ -121,6 +153,69 @@ def half_sites(monkeypatch, half_grid):
 
 
 @pytest.fixture()
+def ring_programs_rebuilt():
+    """Ring update programs built afresh inside the test, and dropped after
+    it, so that a planted fault neither misses a cached program nor
+    outlives its test."""
+    from spark_examples_tpu.ops import devicegen
+
+    devicegen._ring_update.cache_clear()
+    yield
+    devicegen._ring_update.cache_clear()
+
+
+@pytest.fixture()
+def ring_step_dropped(monkeypatch, ring_programs_rebuilt):
+    """The ring update leaves out its second step's tile exchange: each
+    device dots the tile it already holds again, in its next neighbour's
+    place."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from spark_examples_tpu.ops import gramian
+
+    def broken(G_local, X_cols, samples_axis, operand_dtype, packed=False):
+        D = gramian.axis_size(samples_axis)
+        i = lax.axis_index(samples_axis)
+        n_local = X_cols.shape[1] * 8 if packed else X_cols.shape[1]
+
+        def unpack(tile):
+            return gramian._unpack_bits(tile, n_local) if packed else tile
+
+        mine = unpack(X_cols).astype(operand_dtype).T
+        tile, zero = X_cols, jnp.int32(0)
+        for k in range(D):
+            col = (((i + k) % D) * n_local).astype(jnp.int32)
+            t = jnp.matmul(mine, unpack(tile).astype(operand_dtype), preferred_element_type=G_local.dtype)
+            G_local = lax.dynamic_update_slice(
+                G_local, lax.dynamic_slice(G_local, (zero, col), (n_local, n_local)) + t, (zero, col)
+            )
+            if k != 1:
+                tile = lax.ppermute(tile, samples_axis, [((p + 1) % D, p) for p in range(D)])
+        return G_local
+
+    monkeypatch.setattr(gramian, "_ring_tiles", broken)
+
+
+@pytest.fixture()
+def shard_unchanged(monkeypatch, ring_programs_rebuilt):
+    """The first device of the samples axis returns its row tile unchanged
+    from every ring update."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from spark_examples_tpu.ops import gramian
+
+    original = gramian._ring_tiles
+
+    def broken(G_local, X_cols, samples_axis, operand_dtype, packed=False):
+        updated = original(G_local, X_cols, samples_axis, operand_dtype, packed=packed)
+        return jnp.where(lax.axis_index(samples_axis) == 0, G_local, updated)
+
+    monkeypatch.setattr(gramian, "_ring_tiles", broken)
+
+
+@pytest.fixture()
 def answer_altered(monkeypatch):
     """One coordinate of every job's components altered where made."""
     from spark_examples_tpu.pipeline.pca_driver import VariantsPcaDriver
@@ -170,6 +265,17 @@ def test_batch_fault_is_not_correct(fault, cell, request, capfd):
     assert any(c["value"] > c["limit"] for c in line["compared"].values())
 
 
+@pytest.mark.parametrize(
+    "fault", ["state_unchanged", "ring_step_dropped", "shard_unchanged", "answer_altered"]
+)
+def test_ring_fault_is_not_correct(fault, request, capfd, ring_cell):
+    request.getfixturevalue(fault)
+    line = _run(capfd, RING)
+    assert line["correct"] is False
+    broken = "pc_eigenspace_gap" if fault == "answer_altered" else "gramian_max_abs_diff"
+    assert line["compared"][broken]["value"] > line["compared"][broken]["limit"]
+
+
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_sites", "answer_altered", "answer_never_comes"])
 def test_served_fault_is_not_correct(fault, request, capfd, served_cell):
     request.getfixturevalue(fault)
@@ -177,11 +283,12 @@ def test_served_fault_is_not_correct(fault, request, capfd, served_cell):
     assert line["correct"] is False
 
 
-@pytest.mark.parametrize("cell", ["kg1000.wgs-batch", "platinum.wgs-batch", SERVED])
-def test_sound_run_is_correct(cell, capfd, served_cell):
+@pytest.mark.parametrize("cell", ["kg1000.wgs-batch", "platinum.wgs-batch", SERVED, RING])
+def test_sound_run_is_correct(cell, capfd, served_cell, ring_cell):
     line = _run(capfd, cell)
     assert line["correct"] is True
     assert line["failed"] == 0 and line["attempted"] >= 1
+    assert line["device"]["count"] == core.cell(cell)["chips"]
 
 
 def test_served_traced_run_reads_its_metrics(capfd, served_cell):
@@ -192,9 +299,9 @@ def test_served_traced_run_reads_its_metrics(capfd, served_cell):
 
 
 @pytest.mark.parametrize(
-    "cell, seed", [("kg1000.wgs-batch", 3), ("platinum.wgs-batch", 4), (SERVED, 5)]
+    "cell, seed", [("kg1000.wgs-batch", 3), ("platinum.wgs-batch", 4), (SERVED, 5), (RING, 6)]
 )
-def test_control_is_not_correct(cell, seed, served_cell):
+def test_control_is_not_correct(cell, seed, served_cell, ring_cell):
     doc = core.dry_overrides(core.cell(cell))
     numbers = readings.control_numbers(doc, seed)
     limits = doc["limits"]

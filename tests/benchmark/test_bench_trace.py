@@ -80,7 +80,7 @@ def test_no_device_plane_reads_nothing():
     class FakeRun:
         trace = Trace({}, {}, [("bench:window", 0, 10), ("bench:ingest", 0, 5)])
         jobs = [{"sites_scanned": 10, "dispatches": 3}]
-        cell = {"config": {"num_samples": 4}}
+        cell = {"config": {"num_samples": 4}, "chips": 1}
         device_kind = "cpu"
 
     for name in ("idle_share.job", "gramian_update_ms.job", "gramian_roofline.job",
@@ -124,7 +124,7 @@ def test_recorded_trace_metrics(recorded):
     class RecordedRun:
         trace = recorded
         jobs = [{"sites_scanned": 659314, "dispatches": 1}] * 2
-        cell = {"config": {"num_samples": 17}}
+        cell = {"config": {"num_samples": 17}, "chips": 1}
         device_kind = "TPU v5 lite"
 
     run = RecordedRun()
@@ -133,3 +133,17 @@ def test_recorded_trace_metrics(recorded):
     share = core.load_reader("gramian_roofline.job")(run)
     assert share == pytest.approx(100 * 17 * 18 * 659314 / 393e12 / (0.005899237 / 2))
     assert 0 < share < 100
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_roofline_reader_holds_the_cells_chips(recorded, chips):
+    class RecordedRun:
+        trace = recorded
+        jobs = [{"sites_scanned": 659314, "dispatches": 1}] * 2
+        cell = {"config": {"num_samples": 17}, "chips": chips}
+        device_kind = "TPU v5 lite"
+
+    # Device time is averaged over the chips, so the least time is that of
+    # all of the cell's chips together.
+    share = core.load_reader("gramian_roofline.job")(RecordedRun())
+    assert share == pytest.approx(100 * 17 * 18 * 659314 / (chips * 393e12) / (0.005899237 / 2))
