@@ -397,10 +397,20 @@ def _eval_stacked_update(
     )
 
 
-#: Simultaneous per-device buffers of the sharded strategy at peak: the
-#: local G row-tile, its non-donated update output, and the (smaller)
-#: column-block operands rounded up to one more tile.
-_SHARDED_BUFFERS = 3
+def _ring_copies(conf: PcaConf, tile_bytes: int, device_bytes: int) -> int:
+    """Copies of one device's G row tile the run's update loop can keep
+    live in a job long enough to fill its queue, from the rule that loop
+    runs (``ops/devicegen.py:gramian_copies_max``): the device-generation
+    ring waits for the dispatch
+    :func:`~spark_examples_tpu.ops.devicegen.dispatch_depth` back, the
+    host-fed ring for each flush it has just handed over (depth 0)."""
+    from spark_examples_tpu.ops.devicegen import (
+        dispatch_depth,
+        gramian_copies_max,
+    )
+
+    depth = dispatch_depth(tile_bytes, device_bytes) if conf.ingest == "device" else 0
+    return gramian_copies_max(depth + 1, depth)
 
 
 def _eval_sharded_update(
@@ -419,6 +429,7 @@ def _eval_sharded_update(
 
     from spark_examples_tpu.ops.gramian import (
         _DEFAULT_DEVICE_BYTES,
+        _DENSE_BUFFERS,
         DENSE_HBM_FRACTION,
         resolve_ring_pack,
     )
@@ -468,25 +479,32 @@ def _eval_sharded_update(
         data * B, samples, n_local, pack
     )
     # Sharded HBM feasibility against the default budget (the validator
-    # never queries devices): per device, the local (padded/samples, padded)
-    # accumulator tile dominates, times the non-donation working copies.
+    # never queries devices): per device, the copies of the local
+    # (padded/samples, padded) accumulator tile the update loop can keep,
+    # and one more tile for the operands or the finalize's centred copy.
+    # It binds wherever the run resolves the sharded strategy: explicitly,
+    # or by the auto rule when the dense Gramian does not fit.
     accum_bytes = 4
     tile_bytes = n_local * padded * accum_bytes
+    copies = _ring_copies(conf, tile_bytes, _DEFAULT_DEVICE_BYTES)
+    need = (copies + 1) * tile_bytes
     report.geometry["sharded_tile_bytes_per_device"] = tile_bytes
-    if (
-        conf.similarity_strategy == "sharded"
-        and _SHARDED_BUFFERS * tile_bytes
-        > DENSE_HBM_FRACTION * _DEFAULT_DEVICE_BYTES
-    ):
+    report.geometry["gramian_copies_max"] = copies
+    report.geometry["ring_hbm_bytes_per_device"] = need
+    budget = DENSE_HBM_FRACTION * _DEFAULT_DEVICE_BYTES
+    resolves_sharded = conf.similarity_strategy == "sharded" or (
+        conf.similarity_strategy == "auto"
+        and _DENSE_BUFFERS * N * N * accum_bytes > budget
+    )
+    if resolves_sharded and need > budget:
         report.error(
             "sharded-exceeds-hbm",
-            f"--similarity-strategy sharded with N={N} over samples="
-            f"{samples} needs ~"
-            f"{_SHARDED_BUFFERS * tile_bytes / (1 << 30):.1f} GiB of "
-            f"ring working buffers per device, past "
-            f"{DENSE_HBM_FRACTION:.0%} of the "
-            f"{_DEFAULT_DEVICE_BYTES >> 30} GiB default budget; widen the "
-            "samples axis",
+            f"the sharded strategy with N={N} over samples={samples} "
+            f"needs ~{need / (1 << 30):.1f} GiB per device ({copies} "
+            f"live copies of a {tile_bytes / (1 << 30):.2f} GiB Gramian "
+            f"tile and one more tile), past {DENSE_HBM_FRACTION:.0%} of "
+            f"the {_DEFAULT_DEVICE_BYTES / (1 << 30):g} GiB default "
+            "budget; widen the samples axis",
         )
 
     accum = jnp.int32 if conf.exact_similarity else jnp.float32

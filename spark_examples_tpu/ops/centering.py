@@ -9,6 +9,8 @@ pass; the driver-side ``collect`` of row sums and the broadcast disappear.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -60,13 +62,17 @@ def gower_center_sharded(
 
     Centering arithmetic runs in float64 when x64 is live (see
     :func:`_dtypes`); the row-tile output is f32 either way — the downstream
-    sharded eigensolve's dtype.
+    sharded eigensolve's dtype. The program is built once per (mesh, true
+    size); its XLA module is ``jit_gower_center_sharded``.
     """
-    n_padded = S.shape[0]
-    n = n_padded if n_true is None else int(n_true)
-    wide, _ = _dtypes(S.dtype)
+    n = S.shape[0] if n_true is None else int(n_true)
+    return _gower_center_sharded(mesh, n)(S)
 
-    def per_tile(S_local):
+
+@functools.lru_cache(maxsize=16)
+def _gower_center_sharded(mesh: Mesh, n: int):
+    def gower_center_sharded(S_local):
+        wide, _ = _dtypes(S_local.dtype)
         S_local = S_local.astype(wide)
         n_local = S_local.shape[0]
         row_start = jax.lax.axis_index(SAMPLES_AXIS) * n_local
@@ -89,14 +95,12 @@ def gower_center_sharded(
         ).astype(jnp.float32)
 
     fn = shard_map(
-        per_tile,
+        gower_center_sharded,
         mesh=mesh,
         in_specs=P(SAMPLES_AXIS, None),
         out_specs=P(SAMPLES_AXIS, None),
     )
-    return jax.jit(
-        fn, out_shardings=NamedSharding(mesh, P(SAMPLES_AXIS, None))
-    )(S)
+    return jax.jit(fn, out_shardings=NamedSharding(mesh, P(SAMPLES_AXIS, None)))
 
 
 __all__ = ["gower_center", "gower_center_sharded"]

@@ -359,6 +359,44 @@ def auto_blocks_per_dispatch(total_columns: int, block_size: int) -> int:
     return int(min(512, max(32, (k // 8) * 8)))
 
 
+#: Share of one device's memory the copies of G a dispatch loop keeps
+#: live may take, where one queued dispatch leaves room: half of the dense
+#: rule's 80% (``ops/gramian.py:DENSE_HBM_FRACTION``), the other half left
+#: to the finalize (the result and its centred copy) and to what the
+#: caller holds.
+LOOP_HBM_FRACTION = 0.4
+
+
+def dispatch_depth(gramian_bytes: int, device_bytes: int) -> int:
+    """The most dispatches a device-generation loop leaves queued once it
+    has handed the next one over: after each dispatch it waits for the one
+    ``depth`` back. The update does not donate G, so the copies this keeps
+    live (:func:`gramian_copies_max`, ``depth + 2``) are to fit
+    :data:`LOOP_HBM_FRACTION` of the device; but at least one dispatch
+    stays queued behind the running one, so the device never waits for the
+    host between dispatches. A 2,504-sample G (25 MB) gets a depth of
+    hundreds, past the 158 dispatches of a whole-genome job, and never
+    waits; a 50,000-sample ring tile (2.5 GB) gets 1, three copies."""
+    copies = int(LOOP_HBM_FRACTION * device_bytes) // max(1, int(gramian_bytes))
+    return max(1, copies - 2)
+
+
+def gramian_copies_max(dispatches: int, depth: int) -> int:
+    """The most G buffers per device a loop of ``dispatches`` dispatches
+    can keep live when it waits, after each, for the one ``depth`` back:
+    just before that wait ``depth + 1`` dispatches may be queued, each
+    writing its own output, and the oldest still reads its input. The loop
+    reports it (the driver's ``ingest`` span) and ``check/plan.py`` sizes a
+    ring's device memory with it."""
+    return min(int(dispatches), int(depth) + 1) + 1
+
+
+def _shard_bytes(array: jax.Array) -> int:
+    """Bytes of one device's shard of ``array``."""
+    shape = array.sharding.shard_shape(array.shape)
+    return int(np.prod(shape, dtype=np.int64)) * array.dtype.itemsize
+
+
 #: Named scopes of the update programs' scan body: site metadata, the
 #: genotype hash and the operand cast (``generate``), the MXU dot and its
 #: accumulate (``int8_dot``), the kept/variant-row reductions (``count``),
@@ -698,6 +736,35 @@ class _GridDispatchAccumulator:
     dispatch_ns = 0
     #: the run's span recorder; the early sync fetch is its ``poke`` span.
     spans = None
+    #: bytes of one device's shard of G, set where G is made.
+    gramian_bytes_per_device = 0
+    #: :func:`dispatch_depth` of this loop, set at its first dispatch.
+    depth = None
+
+    @property
+    def gramian_copies_max(self) -> int:
+        """The most G buffers this loop can have kept live per device so
+        far (:func:`gramian_copies_max`)."""
+        if self.depth is None:
+            return 1
+        return gramian_copies_max(self.dispatches, self.depth)
+
+    def _bound_queue(self) -> None:
+        """After a dispatch: wait for the one ``depth`` dispatches back, so
+        no more than ``depth`` stay queued and G's live copies stay within
+        :func:`gramian_copies_max`. The loop keeps each dispatch's
+        ``kept_sites`` output, a scalar per slice that is ready once that
+        dispatch has run, never its G."""
+        if self.depth is None:
+            from spark_examples_tpu.ops.gramian import per_device_memory_bytes
+
+            self.depth = dispatch_depth(
+                self.gramian_bytes_per_device, per_device_memory_bytes()
+            )
+            self._in_flight = collections.deque()
+        self._in_flight.append(self.kept_sites)
+        if len(self._in_flight) > self.depth:
+            jax.block_until_ready(self._in_flight.popleft())
 
     def add_ranges(self, grid_offsets: np.ndarray, n_valids: np.ndarray) -> None:
         """Data-parallel dispatch: slice d processes grid indices
@@ -738,6 +805,7 @@ class _GridDispatchAccumulator:
             )
             _note_dispatch(update, args)
             self.G, self.variant_rows, self.kept_sites = update(*args)
+        self._bound_queue()
         self.dispatch_ns += time.perf_counter_ns() - start
         self.dispatches += 1
         self.sites_capacity += int(cap) * D
@@ -1001,6 +1069,7 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
                     np.zeros((D,), np.int64), NamedSharding(mesh, s_spec)
                 )
                 self._update = _fused_update_mesh(*update_key, mesh)
+        self.gramian_bytes_per_device = _shard_bytes(self.G)
         # Tail program: a ~K/8-length variant of the same scanned update for
         # contig remainders. Large dispatch groups amortize per-dispatch
         # overhead, but a whole-genome run has 22 contig tails — padding
@@ -1058,6 +1127,7 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
             )
             _note_dispatch(update, args)
             self.G, self.variant_rows, self.kept_sites = update(*args)
+        self._bound_queue()
         self.dispatch_ns += time.perf_counter_ns() - start
         self.dispatches += 1
         self.sites_capacity += int(
@@ -1133,11 +1203,14 @@ def _ring_update(
     mesh,
     set_sizes: Optional[Tuple[int, ...]] = None,
     pack: bool = False,
+    tail: bool = False,
 ):
     """Memoized scanned generate→ring-accumulate program for one static
     configuration (warmup and measured accumulators share one compiled
     program, like :func:`_fused_update`). Signature of the returned jit:
-    ``(G, variant_rows, kept_sites, offsets, valids)``. ``n_pops`` is the
+    ``(G, variant_rows, kept_sites, offsets, valids)``. G is not donated,
+    so every queued dispatch holds its own output row tile; the dispatch
+    loop bounds how many are queued (:func:`dispatch_depth`). ``n_pops`` is the
     source's population count (see :func:`_fused_update`). ``set_sizes``
     makes the column space a multi-set concatenation
     (:func:`generate_column_block`); ``variant_rows`` is then per set —
@@ -1152,10 +1225,10 @@ def _ring_update(
     schedule-independent (each device still generates its flat column
     slot) and only the tile circulation changes, so flat and hier runs are
     byte-identical (CI-asserted). The program's XLA module is
-    ``jit_devicegen_ring_update``; its scan body carries the
-    :data:`UPDATE_SCOPES`."""
+    ``jit_devicegen_ring_update`` (``jit_devicegen_ring_update_tail`` with
+    ``tail``); its scan body carries the :data:`UPDATE_SCOPES`."""
     from jax import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from spark_examples_tpu.ops.gramian import (
         _hier_ring_tiles,
@@ -1301,7 +1374,16 @@ def _ring_update(
             )
             return g_l[None], rows_l[None], kept_l[None]
 
-        return jax.jit(  # graftcheck: disable=GC005 -- G is not donated, same policy as ops/gramian.py:_dense_update; graftcheck ir cross-checks this disable against the traced donated_invars (GI002)
+        if tail:
+            devicegen_ring_update.__name__ = "devicegen_ring_update_tail"
+            devicegen_ring_update.__qualname__ = "devicegen_ring_update_tail"
+        # The outputs keep the shardings the accumulator made its zeros
+        # with: left to the compiler, a unit data axis comes back as
+        # ``P()``, and the next dispatch would lower the program again.
+        out_shardings = tuple(
+            NamedSharding(mesh, spec) for spec in (g_spec, r_spec, s_spec)
+        )
+        return jax.jit(  # graftcheck: disable=GC005 -- G is not donated: a caller may hold an earlier G, which donation would delete; the dispatch loop bounds the queued copies instead (dispatch_depth); graftcheck ir cross-checks this disable against the traced donated_invars (GI002)
             shard_map(
                 devicegen_ring_update,
                 mesh=mesh,
@@ -1310,8 +1392,38 @@ def _ring_update(
                 # kept/rows are samples-replicated by construction
                 # (identical metadata / psum'd flags on every slice).
                 check_vma=False,
-            )
+            ),
+            out_shardings=out_shardings,
         )
+
+
+@functools.lru_cache(maxsize=8)
+def _device_zeros(shape: Tuple[int, ...], dtype_name: str, sharding):
+    """A program that writes a zero ``shape`` array straight into its
+    shards on the devices of ``sharding``."""
+
+    def devicegen_ring_zeros():
+        return jnp.zeros(shape, dtype_name)
+
+    return jax.jit(devicegen_ring_zeros, out_shardings=sharding)
+
+
+@functools.lru_cache(maxsize=8)
+def _single_slice_result(mesh):
+    """``(1, P, P) → (P, P)`` row-sharded over ``samples``, written into the
+    donated input's buffer (the shapes differ only by the unit data axis)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spark_examples_tpu.parallel.mesh import SAMPLES_AXIS
+
+    def devicegen_ring_result(g):
+        return g.reshape(g.shape[1:])
+
+    return jax.jit(
+        devicegen_ring_result,
+        donate_argnums=(0,),
+        out_shardings=NamedSharding(mesh, P(SAMPLES_AXIS, None)),
+    )
 
 
 class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
@@ -1457,10 +1569,14 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
         self._scalar_sharding = NamedSharding(mesh, P(data_axis))
 
         with jax.enable_x64(True):
-            self.G = device_put_global(
-                np.zeros((D, self.padded, self.padded), np.dtype(accum_dtype)),
+            # Zeroed on the devices: a host zeros array would be the whole
+            # (padded, padded) Gramian, 10 GB at 50,000 samples, sent over
+            # PCIe at every job (20.8 s of a 27.5 s job on four v5e chips).
+            self.G = _device_zeros(
+                (D, self.padded, self.padded),
+                np.dtype(accum_dtype).name,
                 NamedSharding(mesh, g_spec),
-            )
+            )()
             self.kept_sites = device_put_global(
                 np.zeros((D,), np.int64), self._scalar_sharding
             )
@@ -1468,6 +1584,7 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
                 np.zeros((D, self.n_sets), np.int64),
                 NamedSharding(mesh, P(data_axis, None)),
             )
+        self.gramian_bytes_per_device = _shard_bytes(self.G)
         self._update_key = (
             vs_keys,
             pops_padded.tobytes(),
@@ -1496,7 +1613,8 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
         self._update_tail = None
 
     def _compile_update(self, key):
-        return _ring_update(*key)
+        # Only the tail program is built through here.
+        return _ring_update(*key, tail=True)
 
     @property
     def ring_bytes_total(self) -> int:
@@ -1553,22 +1671,28 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
             "predicted_dcn_bytes": dcn,
         }
 
-    def finalize_sharded(self) -> jax.Array:
+    def finalize_sharded(self, donate: bool = False) -> jax.Array:
         """(padded, padded) Gramian, row-sharded over ``samples`` — feeds
         the sharded centering/eigensolve without ever gathering N×N.
 
         The cross-data-slice sum promotes integer accumulators to int64
         (``ops/gramian.py:data_axis_sum`` — the per-slice int32 accumulators
         are each bounded by their own kept sites, but the total across
-        slices is not)."""
+        slices is not). With ``donate`` the accumulator is spent: it lets
+        go of G, and a single data slice's G becomes the result in its own
+        buffer, so a job never holds its Gramian twice on a device."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from spark_examples_tpu.ops.gramian import data_axis_sum
         from spark_examples_tpu.parallel.mesh import SAMPLES_AXIS
 
+        G = self.G
+        if donate:
+            self.G = None
+            if G.shape[0] == 1:
+                return _single_slice_result(self.mesh)(G)
         return data_axis_sum(
-            self.G,
-            out_shardings=NamedSharding(self.mesh, P(SAMPLES_AXIS, None)),
+            G, out_shardings=NamedSharding(self.mesh, P(SAMPLES_AXIS, None))
         )
 
     def _reduce_row_counts(self, rows: np.ndarray) -> np.ndarray:

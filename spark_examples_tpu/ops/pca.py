@@ -123,17 +123,24 @@ def principal_components_subspace_sharded(
     gathered skinny matrix (identical on every device). Padded rows/columns
     (all-zero after :func:`gower_center_sharded` with ``n_true``) contribute
     nothing and the returned components simply carry zero rows for padding.
+    The program is built once per (mesh, parameters, true size); its XLA
+    module is ``jit_principal_components_subspace_sharded``.
     """
+    n = centered.shape[0] if n_true is None else int(n_true)
+    return _subspace_sharded(mesh, num_pc, iterations, oversample, n)(centered)
+
+
+@functools.lru_cache(maxsize=16)
+def _subspace_sharded(mesh, num_pc: int, iterations: int, oversample: int, n: int):
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from spark_examples_tpu.parallel.mesh import SAMPLES_AXIS
 
-    n_padded = centered.shape[0]
-    n = n_padded if n_true is None else int(n_true)
     k = min(num_pc + oversample, n)
 
-    def per_tile(B_local):
+    def principal_components_subspace_sharded(B_local):
+        n_padded = B_local.shape[1]
         V = jax.random.normal(jax.random.PRNGKey(0), (n_padded, k), jnp.float32)
 
         def gathered_bv(V):
@@ -157,13 +164,13 @@ def principal_components_subspace_sharded(
     # the static replication checker can't follow; the replicated out_specs
     # are correct because every device computes the same gathered iterate.
     fn = shard_map(
-        per_tile,
+        principal_components_subspace_sharded,
         mesh=mesh,
         in_specs=P(SAMPLES_AXIS, None),
         out_specs=(P(), P()),
         check_vma=False,
     )
-    return jax.jit(fn)(centered)
+    return jax.jit(fn)
 
 
 def mllib_reference_pca(centered, num_pc: int = 2):

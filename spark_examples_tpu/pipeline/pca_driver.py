@@ -120,6 +120,18 @@ def _fetch_components_and_nonzero(device_components, nz, mesh):
     return flat[:-1].reshape(rows, num_pc), int(flat[-1])
 
 
+def _device_peak_bytes(devices) -> Optional[int]:
+    """The largest ``peak_bytes_in_use`` over ``devices``, or ``None`` where
+    a device reports no memory stats (the CPU)."""
+    peaks = []
+    for device in devices:
+        stats = device.memory_stats()
+        if not stats or "peak_bytes_in_use" not in stats:
+            return None
+        peaks.append(int(stats["peak_bytes_in_use"]))
+    return max(peaks, default=None)
+
+
 def make_source(conf: PcaConf) -> GenomicsSource:
     if conf.source == "synthetic":
         sizes = getattr(conf, "num_samples_per_set", None)
@@ -771,8 +783,14 @@ class VariantsPcaDriver:
         the per-shard stats accounting and ``poke`` the early sync fetch;
         its self time is the loop's own host work. ``ingest`` carries
         ``sites_valid`` and ``sites_capacity``, the padding of the
-        dispatched grid, and ``pop_segments``, the population segments
-        generated in one pass (0 where the thresholds are gathered).
+        dispatched grid, ``pop_segments``, the population segments
+        generated in one pass (0 where the thresholds are gathered),
+        ``gramian_bytes_per_device`` and ``gramian_copies_max``, one device's
+        accumulator tile and the most copies of it the loop can keep live
+        (``ops/devicegen.py:gramian_copies_max``), ``ring_bytes``, the ring's
+        ICI bytes (ring runs only), and ``device_peak_bytes``, the largest
+        device memory peak over the loop's local devices after the sync
+        (absent where devices report no memory stats, as the CPU's do).
         """
         from spark_examples_tpu.ops.devicegen import auto_blocks_per_dispatch
 
@@ -871,9 +889,11 @@ class VariantsPcaDriver:
             self._device_gen_acc = acc
             if use_ring:
                 # Row-sharded (padded) result; compute_pca routes to the sharded
-                # centering/eigensolve from its NamedSharding.
+                # centering/eigensolve from its NamedSharding. The result takes
+                # G's own buffer, so centering's output is the job's second
+                # row tile per device, not its third.
                 self._sched_block = acc.schedule_block()
-                result = acc.finalize_sharded()
+                result = acc.finalize_sharded(donate=True)
             else:
                 result = self._merge_host_partials(acc.finalize_device())
             from spark_examples_tpu.obs.metrics import (
@@ -909,7 +929,14 @@ class VariantsPcaDriver:
                 sites_valid=int(acc.sites_valid),
                 sites_capacity=int(acc.sites_capacity),
                 pop_segments=int(acc.pop_segments),
+                gramian_bytes_per_device=int(acc.gramian_bytes_per_device),
+                gramian_copies_max=int(acc.gramian_copies_max),
             )
+            if use_ring:
+                span.attrs["ring_bytes"] = int(acc.ring_bytes_total)
+            peak = _device_peak_bytes(acc.kept_sites.sharding.addressable_devices)
+            if peak is not None:
+                span.attrs["device_peak_bytes"] = peak
         return result
 
     def _device_gen_accumulator(

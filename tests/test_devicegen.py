@@ -709,3 +709,162 @@ def test_ring_device_ingest_end_to_end_sharded_pca():
     signs = np.sign((c_dense * c_sharded).sum(axis=0))
     signs[signs == 0] = 1
     np.testing.assert_allclose(c_dense, c_sharded * signs, atol=1e-3)
+
+
+# ----------------------------------------------------------------- the loop's memory
+
+
+def _ring64(block_size=16, blocks_per_dispatch=1):
+    """A 64-sample ring accumulator on a 1x4 mesh of the virtual CPU devices
+    and the grid range of its contig (10 dispatches of 16 sites)."""
+    from spark_examples_tpu.ops.devicegen import DeviceGenRingGramianAccumulator
+    from spark_examples_tpu.parallel.mesh import DATA_AXIS, SAMPLES_AXIS, make_mesh
+
+    source = SyntheticGenomicsSource(num_samples=64, seed=42)
+    acc = DeviceGenRingGramianAccumulator(
+        num_samples=64,
+        vs_key=source.genotype_stream_key("vs"),
+        pops=source.populations,
+        site_key=source.site_key,
+        spacing=source.variant_spacing,
+        ref_block_fraction=source.ref_block_fraction,
+        mesh=make_mesh({DATA_AXIS: 1, SAMPLES_AXIS: 4}),
+        block_size=block_size,
+        blocks_per_dispatch=blocks_per_dispatch,
+    )
+    k0 = source.site_grid_range(Contig("17", 0, 100_000))[0]
+    return acc, k0, k0 + 10 * block_size * blocks_per_dispatch
+
+
+def _budget(monkeypatch, copies, gramian_bytes):
+    """Device memory in which ``copies`` G tiles fill the loop's share."""
+    from spark_examples_tpu.ops import devicegen, gramian
+
+    budget = int((copies + 0.5) * gramian_bytes / devicegen.LOOP_HBM_FRACTION)
+    monkeypatch.setattr(gramian, "per_device_memory_bytes", lambda: budget)
+
+
+def _waits(monkeypatch):
+    """The arrays the loop blocks on, in order."""
+    waited = []
+    block = jax.block_until_ready
+
+    def recorded(x):
+        waited.append(x)
+        return block(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", recorded)
+    return waited
+
+
+@pytest.mark.parametrize("copies", [3, 4])
+def test_ring_loop_keeps_at_most_its_copies(monkeypatch, copies):
+    """Ten ring dispatches on a 1x4 mesh: the loop waits for the dispatch
+    ``depth`` back after each one, so at most ``depth`` stay queued and G's
+    live copies stay within ``gramian_copies_max`` (``depth + 2``)."""
+    acc, k0, k1 = _ring64()
+    _budget(monkeypatch, copies, acc.gramian_bytes_per_device)
+    waited = _waits(monkeypatch)
+    def g_shaped():
+        return sum(a.shape == acc.G.shape for a in jax.live_arrays())
+
+    others = g_shaped() - 1
+    live = []
+    dispatch = type(acc)._dispatch_ranges
+
+    def counted(self, *args):
+        dispatch(self, *args)
+        live.append(g_shaped() - others)
+
+    monkeypatch.setattr(type(acc), "_dispatch_ranges", counted)
+    acc.add_grid(k0, k1)
+    assert acc.dispatches >= 9
+    assert acc.depth == copies - 2
+    assert acc.gramian_copies_max == copies
+    assert len(waited) == acc.dispatches - acc.depth
+    assert max(live) <= acc.gramian_copies_max
+
+
+def test_ring_loop_bound_and_donated_finalize_keep_the_gramian(monkeypatch):
+    """The bounded loop and the finalize that takes G's own buffer give the
+    Gramian of the unbounded loop, byte for byte."""
+    free, k0, k1 = _ring64()
+    free.add_grid(k0, k1)
+    assert free.depth > free.dispatches
+    with jax.enable_x64(True):
+        expected = np.asarray(jax.device_get(free.finalize_sharded()))
+
+    bounded, _, _ = _ring64()
+    _budget(monkeypatch, 3, bounded.gramian_bytes_per_device)
+    bounded.add_grid(k0, k1)
+    assert bounded.depth == 1
+    with jax.enable_x64(True):
+        result = bounded.finalize_sharded(donate=True)
+        assert bounded.G is None
+        got = np.asarray(jax.device_get(result))
+    assert got.dtype == expected.dtype
+    np.testing.assert_array_equal(got, expected)
+
+
+def test_ring_programs_are_lowered_once_for_every_dispatch_after_them():
+    """A warm-up job (one main dispatch and one tail) prepares every
+    dispatch of the next job: the programs' outputs keep the shardings of
+    the accumulator's zeros, so the second main dispatch, fed the first
+    one's outputs, finds its program in the jit cache."""
+    step = 16 * 8
+    warm, k0, _ = _ring64(blocks_per_dispatch=8)
+    warm.add_grid(k0, k0 + step + 16)
+    main, tail = warm._update, warm._update_tail
+    sizes = (main._cache_size(), tail._cache_size())
+
+    acc, _, _ = _ring64(blocks_per_dispatch=8)
+    acc.add_grid(k0, k0 + 2 * step + 16)
+    assert acc.dispatches == 3
+    assert (acc._update, acc._update_tail) == (main, tail)
+    assert (main._cache_size(), tail._cache_size()) == sizes
+
+
+@pytest.mark.parametrize(
+    "num_samples, dispatches", [(2504, 158), (17, 49)], ids=["kg1000", "platinum"]
+)
+def test_one_chip_cells_never_wait(num_samples, dispatches):
+    """The whole-genome cells' Gramians (25 MB and 1.2 KB) get a depth past
+    their dispatches per job on a v5e chip's 15.75 GB."""
+    from spark_examples_tpu.ops.devicegen import dispatch_depth, gramian_copies_max
+
+    gramian_bytes = num_samples * num_samples * 4
+    depth = dispatch_depth(gramian_bytes, 15_750_000_000)
+    assert depth >= dispatches
+    assert gramian_copies_max(dispatches, depth) == dispatches + 1
+
+
+def test_dense_loop_adds_no_wait_where_the_depth_is_not_reached(monkeypatch):
+    waited = _waits(monkeypatch)
+    source = SyntheticGenomicsSource(num_samples=24, seed=3)
+    acc = DeviceGenGramianAccumulator(
+        num_samples=24,
+        vs_keys=[source.genotype_stream_key("vs")],
+        pops=source.populations,
+        site_key=source.site_key,
+        spacing=source.variant_spacing,
+        ref_block_fraction=source.ref_block_fraction,
+        block_size=16,
+        blocks_per_dispatch=1,
+    )
+    k0, k1 = source.site_grid_range(Contig("2", 0, 100_000))
+    acc.add_grid(k0, k1)
+    assert acc.dispatches > 9
+    assert waited == []
+    assert acc.gramian_copies_max == acc.dispatches + 1
+
+
+@pytest.mark.parametrize(
+    "gramian_bytes, device_bytes, depth",
+    [(2_501_600_256, 15_750_000_000, 1), (2_501_600_256, 4_000_000_000, 1),
+     (625_250_000, 15_750_000_000, 8), (25_080_064, 15_750_000_000, 249)],
+    ids=["50k-ring", "50k-small-chip", "25k-ring", "kg1000"],
+)
+def test_dispatch_depth(gramian_bytes, device_bytes, depth):
+    from spark_examples_tpu.ops.devicegen import dispatch_depth
+
+    assert dispatch_depth(gramian_bytes, device_bytes) == depth

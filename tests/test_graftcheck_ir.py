@@ -107,6 +107,28 @@ def test_devicegen_ring_audits_clean(data, samples):
     assert audit.facts["ring_bytes_jaxpr"] == ring_traffic_bytes(
         data * K * B, samples, padded // samples, True
     )
+    # G is not donated (the dispatch loop bounds its queued copies), and
+    # the ring program's GC005 disable says so.
+    assert not audit.facts["accumulator_donated"]
+    assert audit.facts["gc005_disable_present"]
+
+
+def test_devicegen_ring_donated_under_its_disable_flags_gi002(monkeypatch):
+    """The ring program jitted with G donated while ``_ring_update`` still
+    carries its GC005 non-donation disable: the AST and IR layers have
+    drifted, and GI002 says so."""
+    from spark_examples_tpu.ops import devicegen
+
+    built = devicegen._ring_update.__wrapped__
+
+    def donated(*args, **kwargs):
+        return jax.jit(built(*args, **kwargs).__wrapped__, donate_argnums=(0,))
+
+    monkeypatch.setattr(devicegen._ring_update, "__wrapped__", donated)
+    audit = audit_kernel(devicegen_ring_spec(1, 4, 64, 8, 2))
+    assert audit.facts["accumulator_donated"]
+    assert _rule_ids(audit) == ["GI002"]
+    assert "drifted" in audit.findings[0].detail
 
 
 def test_default_matrix_clean_and_device_free():

@@ -350,6 +350,60 @@ def test_plan_rejects_sharded_geometry_past_hbm():
     assert any(i.code == "sharded-exceeds-hbm" for i in report.issues)
 
 
+_KG50K = [
+    "--ingest", "device", "--block-size", "16384", "--num-samples", "50000",
+    "--mesh-shape", "1,4",
+]
+_AUTOSOMES = ",".join(
+    f"{name}:0:{end}"
+    for name, end in (
+        ("1", 249250621), ("2", 243199373), ("3", 198022430), ("4", 191154276),
+        ("5", 180915260), ("6", 171115067), ("7", 159138663), ("8", 146364022),
+        ("9", 141213431), ("10", 135534747), ("11", 135006516), ("12", 133851895),
+        ("13", 115169878), ("14", 107349540), ("15", 102531392), ("16", 90354753),
+        ("17", 81195210), ("18", 78077248), ("19", 59128983), ("20", 63025520),
+        ("21", 48129895), ("22", 51304566),
+    )
+)
+
+
+def test_plan_sizes_the_50k_ring_by_the_loops_copies_not_its_dispatches():
+    """50,000 samples on 1x4 through the auto strategy (the dense Gramian
+    cannot fit, so the ring is planned): chr17 and the whole genome ask the
+    same per-device bytes, three live copies of the 2.5 GB row tile (the
+    loop queues one dispatch behind the running one) and one more, inside
+    80% of a 16 GiB chip."""
+    from spark_examples_tpu.ops.gramian import _DEFAULT_DEVICE_BYTES, DENSE_HBM_FRACTION
+
+    reports = [
+        _plan(_KG50K + ["--references", refs], devices=4)
+        for refs in ("17:0:81195210", _AUTOSOMES)
+    ]
+    assert all(r.ok for r in reports), [r.format() for r in reports]
+    need = {r.geometry["ring_hbm_bytes_per_device"] for r in reports}
+    assert len(need) == 1
+    (bytes_per_device,) = need
+    tile = reports[0].geometry["sharded_tile_bytes_per_device"]
+    assert tile == 12504 * 50016 * 4
+    assert reports[0].geometry["gramian_copies_max"] == 3
+    assert bytes_per_device == 4 * tile <= DENSE_HBM_FRACTION * _DEFAULT_DEVICE_BYTES
+
+
+def test_plan_rejects_a_three_copy_50k_ring_on_a_small_chip(monkeypatch):
+    """On a 4 GB device the loop still keeps three 2.5 GB copies (it queues
+    at least one dispatch): the plan refuses what the run could not hold."""
+    from spark_examples_tpu.ops import gramian
+
+    monkeypatch.setattr(gramian, "_DEFAULT_DEVICE_BYTES", 4_000_000_000)
+    report = _plan(_KG50K + ["--references", "17:0:81195210"], devices=4)
+    assert not report.ok
+    assert report.geometry["gramian_copies_max"] == 3
+    assert any(
+        i.code == "sharded-exceeds-hbm" and "3 live copies" in i.message
+        for i in report.issues
+    )
+
+
 def test_plan_rejects_bogus_ring_pack_value():
     from spark_examples_tpu.check.plan import validate_plan
     from spark_examples_tpu.config import PcaConf
