@@ -9,8 +9,9 @@ Gramian actually rests on live one layer down, in the *traced IR*:
   data dependency or XLA serializes ICI against the MXU and the
   communication/compute overlap silently vanishes (GI001). A full ring
   pass must execute exactly ``samples_axis - 1`` permutes — the old
-  serialized loop paid one extra, returning each tile to its owner
-  (GI006).
+  serialized loop paid one extra, returning each tile to its owner — and
+  the device-generation half ring ``samples_axis // 2``
+  (``parallel/mesh.py:ring_permutes``; GI006).
 - **donation/aliasing** — the accumulator's donation contract is read off
   the traced ``jit`` eqn's ``donated_invars`` and cross-checked against
   the AST layer's justified ``# graftcheck: disable=GC005`` escape
@@ -153,7 +154,8 @@ def _is_dot_eqn(eqn: Any) -> bool:
 def _ring_bodies(jaxpr: Any) -> List[Any]:
     """Bodies of scans that contain a ``ppermute`` at their own top level —
     the ring loops (a scan whose permutes are only in NESTED scans is an
-    enclosing block loop, not a ring)."""
+    enclosing block loop, not a ring). The half ring unrolls its steps, so
+    its ring body is the block loop itself."""
     bodies = []
     for eqn, _, _ in _walk_eqns(jaxpr):
         if eqn.primitive.name != "scan":
@@ -162,6 +164,28 @@ def _ring_bodies(jaxpr: Any) -> List[Any]:
             if any(e.primitive.name == "ppermute" for e in sub.eqns):
                 bodies.append(sub)
     return bodies
+
+
+def permute_overlap(body: Any) -> Dict[int, Tuple[bool, bool]]:
+    """``{ppermute eqn index: (waits_on_dot, overlapped)}`` in one ring
+    body — the GI001 analysis. ``waits_on_dot``: a dot_general feeds the
+    permute, so the transfer waits for a matmul. ``overlapped``: some dot
+    of the body shares no dependency with the permute either way, so the
+    transfer can run behind it. In a loop ring that is the step's own dot;
+    in an unrolled ring the dot of step k+1 rightly consumes step k's
+    permute, and the dot that overlaps it is step k's."""
+    prod = _producer_map(body)
+    dots = [i for i, e in enumerate(body.eqns) if _is_dot_eqn(e)]
+    ups = {d: _upstream_eqns(body, d, prod) for d in dots}
+    out: Dict[int, Tuple[bool, bool]] = {}
+    for p, eqn in enumerate(body.eqns):
+        if eqn.primitive.name != "ppermute":
+            continue
+        p_up = _upstream_eqns(body, p, prod)
+        waits = any(d in p_up for d in dots)
+        overlapped = any(d not in p_up and p not in ups[d] for d in dots)
+        out[p] = (waits, overlapped)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -274,19 +298,15 @@ def _packed_flow(
 
 
 def _ring_wire_seeds(body: Any) -> Set[Any]:
-    """The ring body invars that (transitively) feed a ``ppermute`` —
-    the carried wire tile, wherever the builder put it in the carry."""
-    prod = _producer_map(body)
-    used: Set[Any] = set()
-    for i, eqn in enumerate(body.eqns):
-        if eqn.primitive.name != "ppermute":
-            continue
-        upstream = _upstream_eqns(body, i, prod) | {i}
-        for j in upstream:
-            for v in body.eqns[j].invars:
-                if _is_var(v):
-                    used.add(v)
-    return {v for v in body.invars if v in used}
+    """The tiles the ring body's ``ppermute``s put on the wire — the
+    carried tile of a loop ring, wherever the builder put it in the carry,
+    or the freshly packed tile of a ring that generates its columns in the
+    same body."""
+    return {
+        eqn.invars[0]
+        for eqn in body.eqns
+        if eqn.primitive.name == "ppermute" and _is_var(eqn.invars[0])
+    }
 
 
 # --------------------------------------------------------------------------
@@ -410,6 +430,10 @@ class KernelSpec:
     total_devices: int = 1
     packed: bool = False
     ring: bool = False
+    #: the half ring (``ops/gramian.py:_half_ring_tiles``): ``samples // 2``
+    #: permutes per pass instead of ``samples - 1``
+    #: (``parallel/mesh.py:ring_permutes``).
+    half_ring: bool = False
     ring_passes: int = 1
     rows_per_call: int = 0
     n_local: int = 0
@@ -509,7 +533,10 @@ def _audit_donation(spec: KernelSpec, jaxpr: Any, audit: KernelAudit) -> None:
 
 
 def _audit_ring(spec: KernelSpec, jaxpr: Any, audit: KernelAudit) -> None:
-    from spark_examples_tpu.parallel.mesh import ring_traffic_bytes
+    from spark_examples_tpu.parallel.mesh import (
+        ring_permutes,
+        ring_traffic_bytes,
+    )
 
     permute_sites = [
         (eqn, mult)
@@ -517,16 +544,18 @@ def _audit_ring(spec: KernelSpec, jaxpr: Any, audit: KernelAudit) -> None:
         if eqn.primitive.name == "ppermute"
     ]
     executions = sum(mult for _, mult in permute_sites)
-    expected = spec.ring_passes * (spec.samples_axis - 1)
+    per_pass = ring_permutes(spec.samples_axis, half=spec.half_ring)
+    expected = spec.ring_passes * per_pass
     audit.facts["permute_executions"] = executions
     audit.facts["permute_executions_expected"] = expected
     if executions != expected:
+        rule = "samples//2 (half ring)" if spec.half_ring else "(samples-1)"
         _emit(
             audit,
             "GI006",
             f"{executions} ppermute execution(s) per call; the "
-            f"double-buffered ring contract is ring_passes x (samples-1) "
-            f"= {spec.ring_passes} x {spec.samples_axis - 1} = {expected}",
+            f"double-buffered ring contract is ring_passes x {rule} "
+            f"= {spec.ring_passes} x {per_pass} = {expected}",
         )
 
     # Per-call ICI bytes straight from the IR vs the one audited formula.
@@ -538,7 +567,8 @@ def _audit_ring(spec: KernelSpec, jaxpr: Any, audit: KernelAudit) -> None:
     # device-generation dispatch), matching how the runtime feeds the
     # formula per flush/dispatch.
     formula_bytes = ring_traffic_bytes(
-        spec.rows_per_call, spec.samples_axis, spec.n_local, spec.packed
+        spec.rows_per_call, spec.samples_axis, spec.n_local, spec.packed,
+        per_pass,
     )
     audit.facts["ring_bytes_jaxpr"] = jaxpr_bytes
     audit.facts["ring_bytes_formula"] = formula_bytes
@@ -580,38 +610,31 @@ def _audit_ring(spec: KernelSpec, jaxpr: Any, audit: KernelAudit) -> None:
                     f"{spec.n_local // RING_PACK_MULTIPLE}",
                 )
 
-    # Overlap: within each ring body, this step's permute and dot must be
-    # mutually unreachable.
+    # Overlap: each permute must wait for no dot, and some dot of its ring
+    # body (its own step's) must be independent of it.
     serialized = False
     for body in _ring_bodies(jaxpr):
-        prod = _producer_map(body)
-        perm_idx = [
-            i for i, e in enumerate(body.eqns) if e.primitive.name == "ppermute"
-        ]
-        dot_idx = [i for i, e in enumerate(body.eqns) if _is_dot_eqn(e)]
-        for p in perm_idx:
-            p_up = _upstream_eqns(body, p, prod)
-            for d in dot_idx:
-                d_up = _upstream_eqns(body, d, prod)
-                if p in d_up:
-                    serialized = True
-                    _emit(
-                        audit,
-                        "GI001",
-                        "the ring step's dot_general depends on that "
-                        "step's ppermute output — the matmul waits for the "
-                        "ICI transfer every step (serialized ring; the "
-                        "permute must move NEXT step's tile)",
-                    )
-                if d in p_up:
-                    serialized = True
-                    _emit(
-                        audit,
-                        "GI001",
-                        "the ring step's ppermute depends on that step's "
-                        "dot_general output — the ICI transfer waits for "
-                        "the matmul every step (no overlap)",
-                    )
+        has_dots = any(_is_dot_eqn(e) for e in body.eqns)
+        for waits, overlapped in permute_overlap(body).values():
+            if has_dots and not overlapped:
+                serialized = True
+                _emit(
+                    audit,
+                    "GI001",
+                    "the ring step's dot_general depends on that "
+                    "step's ppermute output — the matmul waits for the "
+                    "ICI transfer every step (serialized ring; the "
+                    "permute must move NEXT step's tile)",
+                )
+            if waits:
+                serialized = True
+                _emit(
+                    audit,
+                    "GI001",
+                    "the ring step's ppermute depends on that step's "
+                    "dot_general output — the ICI transfer waits for "
+                    "the matmul every step (no overlap)",
+                )
     audit.facts["ring_overlap_independent"] = (
         bool(permute_sites) and not serialized
     )
@@ -961,11 +984,14 @@ def devicegen_ring_spec(
     """The fused generate-and-ring-accumulate dispatch,
     ``ops/devicegen.py:_ring_update`` — traced through its unmemoized
     constructor (``__wrapped__``) so the audit neither pollutes nor pins
-    the runtime's compile cache."""
-    from spark_examples_tpu.parallel.mesh import padded_cohort
+    the runtime's compile cache. On this flat mesh it runs the half ring:
+    its state is ``half_ring_steps(samples)`` step tiles, the first of them
+    the audited accumulator invar."""
+    from spark_examples_tpu.parallel.mesh import half_ring_steps, padded_cohort
 
     padded = padded_cohort(num_samples, samples, pack=pack)
     n_local = padded // samples
+    steps = half_ring_steps(samples)
 
     def build() -> Tuple[Callable[..., Any], Tuple[Any, ...]]:
         import jax
@@ -994,12 +1020,12 @@ def devicegen_ring_spec(
             None,
             pack,
         )
-        G = jax.ShapeDtypeStruct((data, padded, padded), jnp.int32)
+        tiles = (jax.ShapeDtypeStruct((data, padded, n_local), jnp.int32),) * steps
         rows = jax.ShapeDtypeStruct((data, 1), jnp.int64)
         kept = jax.ShapeDtypeStruct((data,), jnp.int64)
         offsets = jax.ShapeDtypeStruct((data,), jnp.int64)
         valids = jax.ShapeDtypeStruct((data,), jnp.int64)
-        return update, (G, rows, kept, offsets, valids)
+        return update, (tiles, rows, kept, offsets, valids)
 
     return KernelSpec(
         name=(
@@ -1012,6 +1038,7 @@ def devicegen_ring_spec(
         total_devices=data * samples,
         packed=pack,
         ring=True,
+        half_ring=True,
         ring_passes=blocks_per_dispatch,
         rows_per_call=data * blocks_per_dispatch * block_size,
         n_local=n_local,

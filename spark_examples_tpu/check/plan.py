@@ -397,11 +397,24 @@ def _eval_stacked_update(
     )
 
 
-def _ring_copies(conf: PcaConf, tile_bytes: int, device_bytes: int) -> int:
-    """Copies of one device's G row tile the run's update loop can keep
-    live in a job long enough to fill its queue, from the rule that loop
-    runs (``ops/devicegen.py:gramian_copies_max``): the device-generation
-    ring waits for the dispatch
+def _ring_state_bytes(conf: PcaConf, samples: int, n_local: int, padded: int) -> int:
+    """Bytes of one device's ring accumulator state: the device-generation
+    flat ring carries the half ring's ``half_ring_steps`` step tiles,
+    ``(n_local, n_local)`` each; the host-fed ring and the two-level
+    schedule carry the ``(n_local, padded)`` row tile."""
+    from spark_examples_tpu.parallel.mesh import half_ring_steps
+
+    if conf.ingest == "device" and getattr(conf, "reduce_schedule", "auto") != "hier":
+        return half_ring_steps(samples) * n_local * n_local * 4
+    return n_local * padded * 4
+
+
+def _ring_copies(conf: PcaConf, state_bytes: int, device_bytes: int) -> int:
+    """Copies of one device's accumulator state
+    (:func:`_ring_state_bytes`) the run's update loop can keep live in a
+    job long enough to fill its queue, from the rule that loop runs
+    (``ops/devicegen.py:gramian_copies_max``): the device-generation ring
+    waits for the dispatch
     :func:`~spark_examples_tpu.ops.devicegen.dispatch_depth` back, the
     host-fed ring for each flush it has just handed over (depth 0)."""
     from spark_examples_tpu.ops.devicegen import (
@@ -409,7 +422,7 @@ def _ring_copies(conf: PcaConf, tile_bytes: int, device_bytes: int) -> int:
         gramian_copies_max,
     )
 
-    depth = dispatch_depth(tile_bytes, device_bytes) if conf.ingest == "device" else 0
+    depth = dispatch_depth(state_bytes, device_bytes) if conf.ingest == "device" else 0
     return gramian_copies_max(depth + 1, depth)
 
 
@@ -479,16 +492,18 @@ def _eval_sharded_update(
         data * B, samples, n_local, pack
     )
     # Sharded HBM feasibility against the default budget (the validator
-    # never queries devices): per device, the copies of the local
-    # (padded/samples, padded) accumulator tile the update loop can keep,
-    # and one more tile for the operands or the finalize's centred copy.
+    # never queries devices): per device, the copies of the accumulator
+    # state the update loop can keep, and one (padded/samples, padded) row
+    # tile for the operands or the finalize's centred copy.
     # It binds wherever the run resolves the sharded strategy: explicitly,
     # or by the auto rule when the dense Gramian does not fit.
     accum_bytes = 4
     tile_bytes = n_local * padded * accum_bytes
-    copies = _ring_copies(conf, tile_bytes, _DEFAULT_DEVICE_BYTES)
-    need = (copies + 1) * tile_bytes
+    state_bytes = _ring_state_bytes(conf, samples, n_local, padded)
+    copies = _ring_copies(conf, state_bytes, _DEFAULT_DEVICE_BYTES)
+    need = copies * state_bytes + tile_bytes
     report.geometry["sharded_tile_bytes_per_device"] = tile_bytes
+    report.geometry["ring_state_bytes_per_device"] = state_bytes
     report.geometry["gramian_copies_max"] = copies
     report.geometry["ring_hbm_bytes_per_device"] = need
     budget = DENSE_HBM_FRACTION * _DEFAULT_DEVICE_BYTES
@@ -501,8 +516,9 @@ def _eval_sharded_update(
             "sharded-exceeds-hbm",
             f"the sharded strategy with N={N} over samples={samples} "
             f"needs ~{need / (1 << 30):.1f} GiB per device ({copies} "
-            f"live copies of a {tile_bytes / (1 << 30):.2f} GiB Gramian "
-            f"tile and one more tile), past {DENSE_HBM_FRACTION:.0%} of "
+            f"live copies of a {state_bytes / (1 << 30):.2f} GiB "
+            f"accumulator state and one {tile_bytes / (1 << 30):.2f} GiB "
+            f"Gramian row tile), past {DENSE_HBM_FRACTION:.0%} of "
             f"the {_DEFAULT_DEVICE_BYTES / (1 << 30):g} GiB default "
             "budget; widen the samples axis",
         )

@@ -236,6 +236,7 @@ class DusEvent:
 #: Layout/movement ops: one data operand in, same values out.
 _PASSTHROUGH = {
     "slice",
+    "split",
     "squeeze",
     "reshape",
     "broadcast_in_dim",
@@ -1182,6 +1183,10 @@ class RangeKernelSpec:
     #: Which invar is the accumulator (None = the kernel has none: delta
     #: tracking and the GR005 trigger check are skipped).
     acc_invar: Optional[int] = 0
+    #: How many consecutive invars from ``acc_invar`` (and outputs) hold
+    #: the accumulator: the half ring's step tiles, each holding other
+    #: entries, so an entry's increment is the largest tile's.
+    acc_tiles: int = 1
     rows_per_flush: int = 0
     max_count: int = 1
     operand_window_dtype: str = "bfloat16"
@@ -1298,7 +1303,10 @@ def audit_range_kernel(
             spec.input_contracts[i] if i < len(spec.input_contracts) else None
         )
         val = contract_val(contract)
-        if spec.acc_invar is not None and i == spec.acc_invar:
+        if (
+            spec.acc_invar is not None
+            and spec.acc_invar <= i < spec.acc_invar + spec.acc_tiles
+        ):
             # The accumulator is abstracted as zero with delta (0,0): every
             # claim about it is RELATIVE (the per-call per-entry increment);
             # its absolute magnitude across a run is the geometry arithmetic
@@ -1399,14 +1407,13 @@ def audit_range_kernel(
 
     # ---- per-dispatch entry increment + GR005 -------------------------
     if spec.acc_invar is not None:
-        acc_out_delta = None
-        for out in outs:
-            if out.delta is not None:
-                acc_out_delta = out.delta
-                break
+        acc_out_deltas = [out.delta for out in outs if out.delta is not None][
+            : spec.acc_tiles
+        ]
         conservative = (
-            acc_out_delta[1]
-            if acc_out_delta is not None and math.isfinite(acc_out_delta[1])
+            max(d[1] for d in acc_out_deltas)
+            if acc_out_deltas
+            and all(math.isfinite(d[1]) for d in acc_out_deltas)
             else None
         )
         refined = _refined_increment(interp)
@@ -1626,21 +1633,28 @@ def devicegen_range_spec(
     genotype operands are GENERATED on device — their {0,1} range is not a
     declared input contract but the comparison lattice's own inference
     (``Interpreter``: a compare yields [0, 1] integer), so the dot
-    operands arrive contracted without any input declaration and GR005's
-    one-partial-per-entry-per-pass proof runs on the same dus pattern as
-    the host-fed ring. The scalar invars (row counters, kept-site counts,
+    operands arrive contracted without any input declaration. The flat
+    ring is the half ring: its state is ⌊D/2⌋+1 step tiles, each taking
+    one plain dot partial per pass, so GR005 reads the largest tile's
+    increment. The scalar invars (row counters, kept-site counts,
     dispatch offsets, valid-site counts) carry the SITE_INDEX contract —
     all are bounded by the declared production geometry."""
     from spark_examples_tpu.check.ir import devicegen_ring_spec
-    from spark_examples_tpu.parallel.mesh import DATA_AXIS, SAMPLES_AXIS
+    from spark_examples_tpu.parallel.mesh import (
+        DATA_AXIS,
+        SAMPLES_AXIS,
+        half_ring_steps,
+    )
 
     ir_spec = devicegen_ring_spec(
         data, samples, num_samples, block_size, blocks_per_dispatch, pack
     )
+    steps = half_ring_steps(samples)
     return RangeKernelSpec(
         name=f"ranges:{ir_spec.name}",
         build=ir_spec.build,
-        input_contracts=(None, SITE_INDEX, SITE_INDEX, SITE_INDEX, SITE_INDEX),
+        input_contracts=(None,) * steps + (SITE_INDEX,) * 4,
+        acc_tiles=steps,
         axis_sizes={DATA_AXIS: data, SAMPLES_AXIS: samples},
         rows_per_flush=data * blocks_per_dispatch * block_size,
         max_count=HAS_VARIATION.hi,

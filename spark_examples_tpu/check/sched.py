@@ -54,15 +54,13 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from spark_examples_tpu.check.ir import (
     KernelSpec,
     _aval_nbytes,
-    _is_dot_eqn,
-    _producer_map,
     _ring_bodies,
-    _upstream_eqns,
     _walk_eqns,
     audit_kernel,
     devicegen_hier_spec,
     devicegen_ring_spec,
     hier_kernel_spec,
+    permute_overlap,
     ring_kernel_spec,
     trace_kernel,
 )
@@ -73,7 +71,7 @@ from spark_examples_tpu.parallel.mesh import (
     flat_traffic_split,
     hierarchical_traffic_bytes,
     resolve_reduce_schedule,
-    ring_traffic_bytes,
+    ring_permutes,
 )
 
 #: The shipped topology matrix: single-host shapes (where flat is the
@@ -170,24 +168,13 @@ class CollectiveSchedule:
 
 
 def _overlapped_permutes(jaxpr: Any) -> Dict[int, bool]:
-    """``id(ppermute eqn) -> proven overlap-independent of every dot in
-    its ring body`` — the per-site form of the GI001 analysis."""
+    """``id(ppermute eqn) -> proven to wait for no dot and to run beside
+    one dot of its ring body`` — the per-site form of the GI001 analysis
+    (``check/ir.py:permute_overlap``)."""
     flags: Dict[int, bool] = {}
     for body in _ring_bodies(jaxpr):
-        prod = _producer_map(body)
-        perm_idx = [
-            i for i, e in enumerate(body.eqns)
-            if e.primitive.name == "ppermute"
-        ]
-        dot_idx = [i for i, e in enumerate(body.eqns) if _is_dot_eqn(e)]
-        for p in perm_idx:
-            p_up = _upstream_eqns(body, p, prod)
-            ok = True
-            for d in dot_idx:
-                d_up = _upstream_eqns(body, d, prod)
-                if p in d_up or d in p_up:
-                    ok = False
-            flags[id(body.eqns[p])] = ok and bool(dot_idx)
+        for p, (waits, overlapped) in permute_overlap(body).items():
+            flags[id(body.eqns[p])] = overlapped and not waits
     return flags
 
 
@@ -408,8 +395,10 @@ def audit_schedule(
         )
         expect = {"ici": formula.ici_bytes, "dcn": formula.dcn_bytes}
     else:
+        # The device-generation kernel's flat ring is the half ring.
         split = flat_traffic_split(
-            sched.rows_per_call, topology, spec.n_local, spec.packed
+            sched.rows_per_call, topology, spec.n_local, spec.packed,
+            ring_permutes(topology.devices, half=spec.half_ring),
         )
         expect = {"ici": split.ici_bytes, "dcn": split.dcn_bytes}
     audit.facts["formula_ici_bytes"] = expect["ici"]

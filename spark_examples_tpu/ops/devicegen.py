@@ -420,9 +420,9 @@ def _note_dispatch(update, args) -> None:
     if entry is None or entry[0] is not update:
         _DISPATCHED[module] = (
             update,
-            tuple(
-                jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
-                for a in args
+            jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+                tuple(args),
             ),
         )
 
@@ -736,8 +736,12 @@ class _GridDispatchAccumulator:
     dispatch_ns = 0
     #: the run's span recorder; the early sync fetch is its ``poke`` span.
     spans = None
-    #: bytes of one device's shard of G, set where G is made.
+    #: bytes of one device's shard of the finished G, set where G is made.
     gramian_bytes_per_device = 0
+    #: bytes of one device's accumulator state, of which each queued
+    #: dispatch holds its own copy: G's shard, or the half ring's step
+    #: tiles (:class:`DeviceGenRingGramianAccumulator`).
+    state_bytes_per_device = 0
     #: :func:`dispatch_depth` of this loop, set at its first dispatch.
     depth = None
 
@@ -751,15 +755,15 @@ class _GridDispatchAccumulator:
 
     def _bound_queue(self) -> None:
         """After a dispatch: wait for the one ``depth`` dispatches back, so
-        no more than ``depth`` stay queued and G's live copies stay within
-        :func:`gramian_copies_max`. The loop keeps each dispatch's
+        no more than ``depth`` stay queued and the state's live copies stay
+        within :func:`gramian_copies_max`. The loop keeps each dispatch's
         ``kept_sites`` output, a scalar per slice that is ready once that
         dispatch has run, never its G."""
         if self.depth is None:
             from spark_examples_tpu.ops.gramian import per_device_memory_bytes
 
             self.depth = dispatch_depth(
-                self.gramian_bytes_per_device, per_device_memory_bytes()
+                self.state_bytes_per_device, per_device_memory_bytes()
             )
             self._in_flight = collections.deque()
         self._in_flight.append(self.kept_sites)
@@ -1070,6 +1074,7 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
                 )
                 self._update = _fused_update_mesh(*update_key, mesh)
         self.gramian_bytes_per_device = _shard_bytes(self.G)
+        self.state_bytes_per_device = self.gramian_bytes_per_device
         # Tail program: a ~K/8-length variant of the same scanned update for
         # contig remainders. Large dispatch groups amortize per-dispatch
         # overhead, but a whole-genome run has 22 contig tails — padding
@@ -1208,8 +1213,12 @@ def _ring_update(
     """Memoized scanned generate→ring-accumulate program for one static
     configuration (warmup and measured accumulators share one compiled
     program, like :func:`_fused_update`). Signature of the returned jit:
-    ``(G, variant_rows, kept_sites, offsets, valids)``. G is not donated,
-    so every queued dispatch holds its own output row tile; the dispatch
+    ``(G, variant_rows, kept_sites, offsets, valids)``, where G is the
+    state of :class:`DeviceGenRingGramianAccumulator`: on a flat mesh the
+    half ring's S step tiles (``ops/gramian.py:_half_ring_tiles``), a
+    tuple of ``(data, padded, n_local)`` arrays; on a hierarchical mesh
+    the ``(data, padded, padded)`` row-tiled G. G is not donated, so every
+    queued dispatch holds its own output state; the dispatch
     loop bounds how many are queued (:func:`dispatch_depth`). ``n_pops`` is the
     source's population count (see :func:`_fused_update`). ``set_sizes``
     makes the column space a multi-set concatenation
@@ -1239,6 +1248,7 @@ def _ring_update(
         DATA_AXIS,
         HOST_AXIS,
         SAMPLES_AXIS,
+        half_ring_steps,
     )
 
     operand_dtype = np.dtype(operand_name)
@@ -1273,7 +1283,8 @@ def _ring_update(
         pops_all = jnp.asarray(pops_padded)
 
         def devicegen_ring_update(g, rows, kept, offset, n_valid):
-            # g: (1, n_local, padded); offset/n_valid/kept: (1,);
+            # g: the S step tiles, each (1, n_local, n_local), or under hier
+            # the (1, n_local, padded) row tile; offset/n_valid/kept: (1,);
             # rows: (1, n_sets)
             s_idx = jax.lax.axis_index(SAMPLES_AXIS)
             if hier:
@@ -1364,31 +1375,48 @@ def _ring_update(
                             operand_dtype, packed=pack,
                         )
                     else:
-                        g_l = _ring_tiles(
-                            g_l, x_cols, SAMPLES_AXIS, operand_dtype, packed=pack
+                        # The half ring's tiles, stacked by rows for the one
+                        # ring entry point and split again: the compiler
+                        # folds both away, so each tile stays its own
+                        # buffer and each dot adds straight into it.
+                        g_l = tuple(
+                            jnp.split(
+                                _ring_tiles(
+                                    jnp.concatenate(g_l), x_cols, SAMPLES_AXIS,
+                                    operand_dtype, packed=pack,
+                                ),
+                                len(g_l),
+                            )
                         )
                 return (g_l, rows_l, kept_l), None
 
+            g_in = g[0] if hier else tuple(t[0] for t in g)
             (g_l, rows_l, kept_l), _ = jax.lax.scan(
-                body, (g[0], rows[0], kept[0]), block_idx
+                body, (g_in, rows[0], kept[0]), block_idx
             )
-            return g_l[None], rows_l[None], kept_l[None]
+            g_out = g_l[None] if hier else tuple(t[None] for t in g_l)
+            return g_out, rows_l[None], kept_l[None]
 
         if tail:
             devicegen_ring_update.__name__ = "devicegen_ring_update_tail"
             devicegen_ring_update.__qualname__ = "devicegen_ring_update_tail"
+        # Each step tile shards its rows like the row tile: device i holds
+        # its own (n_local, n_local) block of every tile.
+        state_spec = g_spec if hier else (g_spec,) * half_ring_steps(inner_devices)
         # The outputs keep the shardings the accumulator made its zeros
         # with: left to the compiler, a unit data axis comes back as
         # ``P()``, and the next dispatch would lower the program again.
-        out_shardings = tuple(
-            NamedSharding(mesh, spec) for spec in (g_spec, r_spec, s_spec)
+        out_shardings = jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec),
+            (state_spec, r_spec, s_spec),
+            is_leaf=lambda x: isinstance(x, P),
         )
         return jax.jit(  # graftcheck: disable=GC005 -- G is not donated: a caller may hold an earlier G, which donation would delete; the dispatch loop bounds the queued copies instead (dispatch_depth); graftcheck ir cross-checks this disable against the traced donated_invars (GI002)
             shard_map(
                 devicegen_ring_update,
                 mesh=mesh,
-                in_specs=(g_spec, r_spec, s_spec, s_spec, s_spec),
-                out_specs=(g_spec, r_spec, s_spec),
+                in_specs=(state_spec, r_spec, s_spec, s_spec, s_spec),
+                out_specs=(state_spec, r_spec, s_spec),
                 # kept/rows are samples-replicated by construction
                 # (identical metadata / psum'd flags on every slice).
                 check_vma=False,
@@ -1426,13 +1454,49 @@ def _single_slice_result(mesh):
     )
 
 
+@functools.lru_cache(maxsize=8)
+def _half_ring_result(mesh):
+    """The half ring's step tiles, each ``(data, P, n_local)`` →
+    ``(P, P)`` row-sharded over ``samples``: each summed over the data axis
+    (``ops/gramian.py:data_axis_sum``), then each device's row tile
+    assembled, the blocks past the half mirrored in from the devices that
+    hold their transposes (``ops/gramian.py:assemble_half_ring``). Trace
+    it under x64, so that a data axis promotes the sum to int64. The tiles
+    are not donated: no output has their shape, so XLA could not reuse
+    their buffers; a caller that drops them frees them once this has run."""
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spark_examples_tpu.ops.gramian import assemble_half_ring, data_axis_sum
+    from spark_examples_tpu.parallel.mesh import SAMPLES_AXIS
+
+    def assemble(*tiles):
+        return assemble_half_ring(tiles, SAMPLES_AXIS)
+
+    def devicegen_ring_result(tiles):
+        return shard_map(
+            assemble,
+            mesh=mesh,
+            in_specs=(P(SAMPLES_AXIS, None),) * len(tiles),
+            out_specs=P(SAMPLES_AXIS, None),
+        )(*(data_axis_sum(t) for t in tiles))
+
+    return jax.jit(
+        devicegen_ring_result,
+        out_shardings=NamedSharding(mesh, P(SAMPLES_AXIS, None)),
+    )
+
+
 class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
     """Sharded large-N device ingest: the composition of on-device
     generation with the ring-exchange Gramian.
 
     Each ``samples``-axis slice generates ONLY its own sample-column block
-    of the cohort matrix (``generate_column_block``) and the ring exchange
-    (``ops/gramian.py:_ring_tiles``) accumulates row tiles — so for a 50K+
+    of the cohort matrix (``generate_column_block``) and the half ring
+    (``ops/gramian.py:_half_ring_tiles``) accumulates the ⌊D/2⌋+1 blocks
+    of its row tile that symmetry needs, one step tile each; the finalize
+    mirrors the rest in (the two-level schedule keeps the whole row tile
+    and the full ring, ``_hier_ring_tiles``) — so for a 50K+
     cohort (the reference's ~20 GB in-memory warning,
     ``VariantsPca.scala:216-217``) no device ever materializes the full
     N×N, no host→device data traffic exists at all, and the optional
@@ -1546,6 +1610,9 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
             if self.reduce_schedule == "hier"
             else None
         )
+        # The flat ring runs the half ring over step tiles; the two-level
+        # schedule keeps the row tile and the full ring.
+        self.half_ring = self._hier_mesh is None
         # Packed wire format pads the column space to 8× the samples axis
         # (pack-width invariant); pad columns generate all-zero and finalize
         # trims them, exactly like the plain samples-axis padding.
@@ -1568,15 +1635,21 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
         g_spec = P(data_axis, SAMPLES_AXIS, None)
         self._scalar_sharding = NamedSharding(mesh, P(data_axis))
 
+        g_sharding = NamedSharding(mesh, g_spec)
+        accum_name = np.dtype(accum_dtype).name
         with jax.enable_x64(True):
             # Zeroed on the devices: a host zeros array would be the whole
             # (padded, padded) Gramian, 10 GB at 50,000 samples, sent over
             # PCIe at every job (20.8 s of a 27.5 s job on four v5e chips).
-            self.G = _device_zeros(
-                (D, self.padded, self.padded),
-                np.dtype(accum_dtype).name,
-                NamedSharding(mesh, g_spec),
-            )()
+            if self.half_ring:
+                zeros = _device_zeros(
+                    (D, self.padded, self.n_local), accum_name, g_sharding
+                )
+                self.G = tuple(zeros() for _ in range(self.ring_dots_per_block))
+            else:
+                self.G = _device_zeros(
+                    (D, self.padded, self.padded), accum_name, g_sharding
+                )()
             self.kept_sites = device_put_global(
                 np.zeros((D,), np.int64), self._scalar_sharding
             )
@@ -1584,7 +1657,12 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
                 np.zeros((D, self.n_sets), np.int64),
                 NamedSharding(mesh, P(data_axis, None)),
             )
-        self.gramian_bytes_per_device = _shard_bytes(self.G)
+        self.gramian_bytes_per_device = (
+            self.n_local * self.padded * np.dtype(accum_dtype).itemsize
+        )
+        self.state_bytes_per_device = sum(
+            _shard_bytes(t) for t in jax.tree.leaves(self.G)
+        )
         self._update_key = (
             vs_keys,
             pops_padded.tobytes(),
@@ -1617,16 +1695,40 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
         return _ring_update(*key, tail=True)
 
     @property
+    def ring_dots_per_block(self) -> int:
+        """Int8 dots per block on each device: ⌊D/2⌋+1 on the half ring,
+        D on the full ring of the two-level schedule."""
+        from spark_examples_tpu.parallel.mesh import half_ring_steps
+
+        if self.half_ring:
+            return half_ring_steps(self.samples_parallel)
+        return self.samples_parallel
+
+    @property
+    def ring_mirrored_tiles(self) -> int:
+        """Blocks per device that the finalize takes from another device
+        and transposes: D-1-⌊D/2⌋ on the half ring, none on the full."""
+        return self.samples_parallel - self.ring_dots_per_block
+
+    @property
     def ring_bytes_total(self) -> int:
         """Total ICI bytes the ring exchanges have moved so far: every
         dispatched site (padding included — padded rows ride the ring too)
-        costs one (samples-1)-step circulation of its row's column tiles
-        (``parallel/mesh.py:ring_traffic_bytes``). Deterministic host-side
-        arithmetic, published as ``gramian_ring_bytes`` by the driver."""
-        from spark_examples_tpu.parallel.mesh import ring_traffic_bytes
+        costs one circulation of its row's column tiles, ``ring_permutes``
+        steps (``parallel/mesh.py:ring_traffic_bytes``). Deterministic
+        host-side arithmetic, published as ``gramian_ring_bytes`` by the
+        driver."""
+        from spark_examples_tpu.parallel.mesh import (
+            ring_permutes,
+            ring_traffic_bytes,
+        )
 
         return ring_traffic_bytes(
-            self.sites_capacity, self.samples_parallel, self.n_local, self.pack
+            self.sites_capacity,
+            self.samples_parallel,
+            self.n_local,
+            self.pack,
+            ring_permutes(self.samples_parallel, half=self.half_ring),
         )
 
     def schedule_block(self) -> dict:
@@ -1678,9 +1780,12 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
         The cross-data-slice sum promotes integer accumulators to int64
         (``ops/gramian.py:data_axis_sum`` — the per-slice int32 accumulators
         are each bounded by their own kept sites, but the total across
-        slices is not). With ``donate`` the accumulator is spent: it lets
-        go of G, and a single data slice's G becomes the result in its own
-        buffer, so a job never holds its Gramian twice on a device."""
+        slices is not). The half ring's step tiles are assembled into the
+        row tiles here (:func:`_half_ring_result`). With ``donate`` the
+        accumulator is spent: it lets go of its state, so the step tiles
+        are freed once the result is written, and a single data slice's row
+        tile becomes the result in its own buffer; a job never holds its
+        Gramian twice on a device."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from spark_examples_tpu.ops.gramian import data_axis_sum
@@ -1689,8 +1794,11 @@ class DeviceGenRingGramianAccumulator(_GridDispatchAccumulator):
         G = self.G
         if donate:
             self.G = None
-            if G.shape[0] == 1:
-                return _single_slice_result(self.mesh)(G)
+        if self.half_ring:
+            with jax.enable_x64(True):
+                return _half_ring_result(self.mesh)(G)
+        if donate and G.shape[0] == 1:
+            return _single_slice_result(self.mesh)(G)
         return data_axis_sum(
             G, out_shardings=NamedSharding(self.mesh, P(SAMPLES_AXIS, None))
         )

@@ -76,6 +76,7 @@ from spark_examples_tpu.parallel.mesh import (
     HOST_AXIS,
     SAMPLES_AXIS,
     device_put_global,
+    half_ring_steps,
     hierarchical_mesh,
     hierarchical_traffic_bytes,
     padded_cohort,
@@ -622,7 +623,11 @@ class GramianAccumulator:
 def _ring_tiles(G_local, X_cols, samples_axis: str, operand_dtype, packed=False):
     """One block's ring update, executed per device inside shard_map.
 
-    ``G_local``: (N_local, N) — this device's row tile of the Gramian.
+    ``G_local``: this device's accumulator state — its ``(N_local, N)``
+    row tile of the Gramian (the full ring, below), or the half ring's
+    step tiles stacked by rows, ``(S·N_local, N_local)`` with S =
+    ⌊D/2⌋+1, which the device-generation ring carries and
+    :func:`_half_ring_tiles` updates.
     ``X_cols``: this block's columns for this device's samples — ``(B,
     N_local)`` {0,1}/count uint8, or ``(B, N_local/8)`` bit-packed uint8
     when ``packed`` (np.packbits big-endian; ``N_local % 8 == 0``, the
@@ -639,8 +644,12 @@ def _ring_tiles(G_local, X_cols, samples_axis: str, operand_dtype, packed=False)
     returning the tile to its owner).
     """
     D = axis_size(samples_axis)
-    i = lax.axis_index(samples_axis)
     n_local = X_cols.shape[1] * 8 if packed else X_cols.shape[1]
+    if D > 1 and G_local.shape == (half_ring_steps(D) * n_local, n_local):
+        return _half_ring_tiles(
+            G_local, X_cols, samples_axis, operand_dtype, packed=packed
+        )
+    i = lax.axis_index(samples_axis)
 
     def unpack(tile):
         return _unpack_bits(tile, n_local) if packed else tile
@@ -683,6 +692,83 @@ def _ring_tiles(G_local, X_cols, samples_axis: str, operand_dtype, packed=False)
 
     G_local, last = lax.fori_loop(0, D - 1, body, (G_local, X_cols))
     return dot_into(G_local, last, D - 1)
+
+
+def _half_ring_tiles(G_local, X_cols, samples_axis: str, operand_dtype, packed=False):
+    """One block's HALF ring update, executed per device inside shard_map
+    (through :func:`_ring_tiles`, which the device-generation ring calls).
+
+    ``G_local``: this device i's S = ``half_ring_steps(D)`` step tiles
+    stacked by rows, ``(S·N_local, N_local)``; rows ``[k·N_local,
+    (k+1)·N_local)`` hold G's block (i, (i+k) mod D). ``X_cols`` as in
+    :func:`_ring_tiles`. G = XᵀX is symmetric, so block (i, j) past the
+    half is the transpose of block (j, i), which device j holds as its
+    tile ``D - k``: the ring stops after step ⌊D/2⌋ — ⌊D/2⌋ permutes and
+    ⌊D/2⌋+1 dots per block against the full ring's D-1 and D — and
+    :func:`assemble_half_ring` mirrors the rest in once, at finalize. For
+    even D, step D/2's block is computed on both its devices.
+
+    The steps are unrolled, so each step's tile is static and its dot adds
+    straight into it: where the caller keeps the tiles as separate buffers
+    and stacks them only to call this (``ops/devicegen.py:_ring_update``),
+    the compiler folds the stacking away, and each step is one dot-and-add
+    whose output is its tile's own buffer. The full ring's device-dependent
+    column offset costs a slice read and a slice write of the row tile per
+    step instead. Double-buffered like :func:`_ring_tiles`: step k+1's
+    ``ppermute`` is issued before step k's dot, which does not depend on
+    it.
+    """
+    D = axis_size(samples_axis)
+    n_local = X_cols.shape[1] * 8 if packed else X_cols.shape[1]
+
+    def unpack(tile):
+        return _unpack_bits(tile, n_local) if packed else tile
+
+    x_mine_t = unpack(X_cols).astype(operand_dtype).T  # (N_local, B)
+    if packed:
+        # One materialization feeding every step's dot (see _ring_tiles).
+        x_mine_t = lax.optimization_barrier(x_mine_t)
+    perm = [((p + 1) % D, p) for p in range(D)]
+    tiles = jnp.split(G_local, half_ring_steps(D))
+    cur, out = X_cols, []
+    for k, tile in enumerate(tiles):
+        nxt = None
+        if k + 1 < len(tiles):
+            with jax.named_scope("ring_exchange"):
+                nxt = lax.ppermute(cur, samples_axis, perm)
+        out.append(
+            tile
+            + jnp.matmul(
+                x_mine_t, unpack(cur).astype(operand_dtype),
+                preferred_element_type=G_local.dtype,
+            )
+        )
+        cur = nxt
+    return jnp.concatenate(out)
+
+
+def assemble_half_ring(tiles, samples_axis: str) -> jax.Array:
+    """This device's ``(N_local, D·N_local)`` row tile of G from its half
+    ring step tiles (:func:`_half_ring_tiles`), each ``(N_local,
+    N_local)``, inside shard_map: tile k goes to column block (i+k) mod D,
+    and each block (i, (i+k) mod D) for k past ⌊D/2⌋ is device (i+k) mod
+    D's tile ``D - k``, taken with one ``ppermute`` and transposed.
+    ``D - 1 - ⌊D/2⌋`` exchanges per device, once per job."""
+    D = axis_size(samples_axis)
+    i = lax.axis_index(samples_axis)
+    blocks = list(tiles)
+    for k in range(len(blocks), D):
+        # Device p receives from device (p + k) mod D.
+        perm = [((p + k) % D, p) for p in range(D)]
+        with jax.named_scope("ring_mirror"):
+            blocks.append(lax.ppermute(blocks[D - k], samples_axis, perm).T)
+    row = jnp.zeros((tiles[0].shape[0], D * tiles[0].shape[0]), tiles[0].dtype)
+    zero = jnp.int32(0)
+    for k, block in enumerate(blocks):
+        # range: the column block index is < D, its offset < padded << 2^31.
+        col = (((i + k) % D) * block.shape[0]).astype(jnp.int32)
+        row = lax.dynamic_update_slice(row, block, (zero, col))
+    return row
 
 
 def _hier_ring_tiles(
@@ -1231,6 +1317,7 @@ def gramian_reference(rows: np.ndarray) -> np.ndarray:
 __all__ = [
     "GramianAccumulator",
     "ShardedGramianAccumulator",
+    "assemble_half_ring",
     "build_hierarchical_update",
     "build_sharded_update",
     "data_axis_sum",

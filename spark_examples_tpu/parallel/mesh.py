@@ -89,13 +89,35 @@ def padded_cohort(num_columns: int, samples_parallel: int, pack: bool = True) ->
     return -(-int(num_columns) // multiple) * multiple
 
 
+def ring_permutes(samples_parallel: int, half: bool = False) -> int:
+    """Tile ``ppermute``s per device in one ring pass: ``S - 1`` for the
+    full ring, which brings every device every other device's column tile,
+    and ``S // 2`` for the half ring (``ops/gramian.py:_half_ring_tiles``),
+    which stops once symmetry gives the rest: G's block (i, j) is the
+    transpose of block (j, i). The one count behind the half ring's step
+    loop, ``ring_traffic_bytes`` and the IR audit's GI005/GI006."""
+    s = int(samples_parallel)
+    return s // 2 if half else s - 1
+
+
+def half_ring_steps(samples_parallel: int) -> int:
+    """Ring steps, so dots and step tiles per device, of the half ring:
+    the device's own tile and one per permute."""
+    return ring_permutes(samples_parallel, half=True) + 1
+
+
 def ring_traffic_bytes(
-    rows: int, samples_parallel: int, n_local: int, packed: bool
+    rows: int,
+    samples_parallel: int,
+    n_local: int,
+    packed: bool,
+    permutes: Optional[int] = None,
 ) -> int:
     """Total ICI bytes one ring pass moves for ``rows`` variant rows.
 
     Each of the ``samples_parallel`` devices sends its ``(rows, width)``
-    column tile ``samples_parallel - 1`` times around the ring; ``width`` is
+    column tile ``permutes`` times around the ring (:func:`ring_permutes`;
+    ``samples_parallel - 1`` when not given, the full ring); ``width`` is
     ``n_local`` bytes unpacked or ``n_local / 8`` packed (``n_local % 8 == 0``
     under the pack-width invariant — :func:`padded_cohort`). ``rows`` summed
     over data-parallel slices gives the whole-mesh total (each slice runs its
@@ -110,7 +132,9 @@ def ring_traffic_bytes(
     width = (
         int(n_local) // RING_PACK_MULTIPLE if packed else int(n_local)
     )
-    return int(rows) * int(samples_parallel) * (int(samples_parallel) - 1) * width
+    if permutes is None:
+        permutes = ring_permutes(samples_parallel)
+    return int(rows) * int(samples_parallel) * int(permutes) * width
 
 
 # --------------------------------------------------------------------------
@@ -225,7 +249,11 @@ def hierarchical_traffic_bytes(
 
 
 def flat_traffic_split(
-    rows: int, topology: Topology, n_local: int, packed: bool
+    rows: int,
+    topology: Topology,
+    n_local: int,
+    packed: bool,
+    permutes: Optional[int] = None,
 ) -> LevelTraffic:
     """The flat ring's provable per-level split on ``topology``.
 
@@ -238,7 +266,7 @@ def flat_traffic_split(
     schedule fixes by construction: its inner axis is intra-host by the
     host-major mesh factorization). On one host everything is ICI."""
     total = ring_traffic_bytes(
-        rows, topology.devices, n_local, packed
+        rows, topology.devices, n_local, packed, permutes
     )
     if topology.hosts == 1:
         return LevelTraffic(ici_bytes=total, dcn_bytes=0)
@@ -783,6 +811,8 @@ __all__ = [
     "Topology",
     "parse_topology",
     "padded_cohort",
+    "ring_permutes",
+    "half_ring_steps",
     "ring_traffic_bytes",
     "hierarchical_traffic_bytes",
     "flat_traffic_split",

@@ -777,7 +777,10 @@ class VariantsPcaDriver:
 
         The ``ingest`` span covers the call; its children are
         ``accumulator-init`` (G and the counters), ``enqueue`` (the contig
-        loop) and ``sync`` (the closing fetch). Inside ``enqueue``, the
+        loop), on ring runs ``finalize`` (the row tiles' assembly; attribute
+        ``ring_mirrored_tiles``, the blocks per device taken from another
+        device and transposed) and ``sync`` (the closing fetch). Inside
+        ``enqueue``, the
         ``dispatch`` aggregate is the host time spent handing dispatches to
         the runtime (where the host waits on a full device queue), ``stats``
         the per-shard stats accounting and ``poke`` the early sync fetch;
@@ -785,10 +788,14 @@ class VariantsPcaDriver:
         ``sites_valid`` and ``sites_capacity``, the padding of the
         dispatched grid, ``pop_segments``, the population segments
         generated in one pass (0 where the thresholds are gathered),
-        ``gramian_bytes_per_device`` and ``gramian_copies_max``, one device's
-        accumulator tile and the most copies of it the loop can keep live
-        (``ops/devicegen.py:gramian_copies_max``), ``ring_bytes``, the ring's
-        ICI bytes (ring runs only), and ``device_peak_bytes``, the largest
+        ``gramian_bytes_per_device``, one device's tile of the finished G,
+        ``gramian_copies_max``, the most copies of the accumulator state the
+        loop can keep live (``ops/devicegen.py:gramian_copies_max``); on
+        ring runs only ``ring_bytes``, the ring's ICI bytes,
+        ``ring_dots_per_block``, the int8 dots per block and device (⌊D/2⌋+1
+        on the half ring), and ``state_bytes_per_device``, one copy of the
+        state (the half ring's step tiles); and ``device_peak_bytes``, the
+        largest
         device memory peak over the loop's local devices after the sync
         (absent where devices report no memory stats, as the CPU's do).
         """
@@ -889,11 +896,14 @@ class VariantsPcaDriver:
             self._device_gen_acc = acc
             if use_ring:
                 # Row-sharded (padded) result; compute_pca routes to the sharded
-                # centering/eigensolve from its NamedSharding. The result takes
-                # G's own buffer, so centering's output is the job's second
-                # row tile per device, not its third.
+                # centering/eigensolve from its NamedSharding. The accumulator
+                # lets go of its state here (the half ring's step tiles, or
+                # the row tile the result then takes), so centering's output
+                # is the job's second row tile per device, not its third.
                 self._sched_block = acc.schedule_block()
-                result = acc.finalize_sharded(donate=True)
+                with self.spans.span("finalize") as finalize:
+                    result = acc.finalize_sharded(donate=True)
+                finalize.attrs["ring_mirrored_tiles"] = int(acc.ring_mirrored_tiles)
             else:
                 result = self._merge_host_partials(acc.finalize_device())
             from spark_examples_tpu.obs.metrics import (
@@ -933,7 +943,11 @@ class VariantsPcaDriver:
                 gramian_copies_max=int(acc.gramian_copies_max),
             )
             if use_ring:
-                span.attrs["ring_bytes"] = int(acc.ring_bytes_total)
+                span.attrs.update(
+                    ring_bytes=int(acc.ring_bytes_total),
+                    ring_dots_per_block=int(acc.ring_dots_per_block),
+                    state_bytes_per_device=int(acc.state_bytes_per_device),
+                )
             peak = _device_peak_bytes(acc.kept_sites.sharding.addressable_devices)
             if peak is not None:
                 span.attrs["device_peak_bytes"] = peak

@@ -654,6 +654,71 @@ def test_ring_device_ingest_matches_host(mesh_shape):
     assert kept == plan_sites
 
 
+@pytest.mark.parametrize(
+    "data, samples, pack, donate",
+    [
+        (1, 2, "on", False),
+        (1, 3, "on", False),
+        (1, 3, "off", True),
+        (1, 4, "on", True),
+        (1, 4, "off", False),
+        (1, 8, "on", False),
+        (2, 3, "on", True),
+        (2, 4, "off", True),
+    ],
+    ids=["s2", "s3", "s3-unpacked-donated", "s4-donated", "s4-unpacked", "s8",
+         "d2s3-donated", "d2s4-unpacked-donated"],
+)
+def test_half_ring_finalize_equals_reference_and_full_ring(data, samples, pack, donate):
+    """The half ring's finalized Gramian, main and tail programs both run,
+    equals the NumPy reference and the host-fed full ring's result bit for
+    bit: ⌊D/2⌋+1 dots per block and D-1-⌊D/2⌋ blocks mirrored at finalize,
+    with no duplicated step at odd D."""
+    from spark_examples_tpu.ops.devicegen import DeviceGenRingGramianAccumulator
+    from spark_examples_tpu.ops.gramian import ShardedGramianAccumulator
+    from spark_examples_tpu.parallel.mesh import DATA_AXIS, SAMPLES_AXIS, make_mesh
+
+    mesh = make_mesh({DATA_AXIS: data, SAMPLES_AXIS: samples})
+    source = SyntheticGenomicsSource(num_samples=19, seed=23)
+    contig = Contig("9", 3_000, 83_000)
+    host_rows = np.concatenate(
+        [b["has_variation"] for b in _host_blocks(source, "vs", contig)]
+    )
+    acc = DeviceGenRingGramianAccumulator(
+        num_samples=19,
+        vs_key=source.genotype_stream_key("vs"),
+        pops=source.populations,
+        site_key=source.site_key,
+        spacing=source.variant_spacing,
+        ref_block_fraction=source.ref_block_fraction,
+        mesh=mesh,
+        block_size=16,
+        blocks_per_dispatch=8,
+        pack_bits=pack,
+    )
+    assert acc.half_ring
+    assert acc.ring_dots_per_block == samples // 2 + 1
+    assert [t.shape for t in acc.G] == [(data, acc.padded, acc.n_local)] * len(acc.G)
+    assert len(acc.G) == acc.ring_dots_per_block
+    assert acc.ring_mirrored_tiles == samples - 1 - samples // 2
+    k0, k1 = source.site_grid_range(contig)
+    assert (k1 - k0) % acc.sites_per_dispatch
+    acc.add_grid(k0, k1)
+    assert acc._update_tail is not None
+    with jax.enable_x64(True):
+        result = acc.finalize_sharded(donate=donate)
+        got = np.asarray(jax.device_get(result))[:19, :19]
+    assert result.shape == (acc.padded, acc.padded)
+    assert (acc.G is None) == donate
+
+    full = ShardedGramianAccumulator(
+        19, mesh, block_size=16, exact_int=True, pack_bits=pack
+    )
+    full.add_rows(host_rows)
+    np.testing.assert_array_equal(got, gramian_reference(host_rows))
+    np.testing.assert_array_equal(got, full.finalize())
+
+
 def test_ring_device_ingest_end_to_end_sharded_pca():
     """Ring device ingest feeds the sharded centering + eigensolve without
     gathering N x N; result matches the dense single-device pipeline."""
@@ -760,21 +825,26 @@ def _waits(monkeypatch):
 @pytest.mark.parametrize("copies", [3, 4])
 def test_ring_loop_keeps_at_most_its_copies(monkeypatch, copies):
     """Ten ring dispatches on a 1x4 mesh: the loop waits for the dispatch
-    ``depth`` back after each one, so at most ``depth`` stay queued and G's
-    live copies stay within ``gramian_copies_max`` (``depth + 2``)."""
+    ``depth`` back after each one, so at most ``depth`` stay queued and the
+    live copies of its state, the half ring's three step tiles, stay
+    within ``gramian_copies_max`` (``depth + 2``)."""
     acc, k0, k1 = _ring64()
-    _budget(monkeypatch, copies, acc.gramian_bytes_per_device)
+    steps = len(acc.G)
+    assert acc.state_bytes_per_device == steps * 16 * 16 * 4 == 3 * 1024
+    _budget(monkeypatch, copies, acc.state_bytes_per_device)
     waited = _waits(monkeypatch)
-    def g_shaped():
-        return sum(a.shape == acc.G.shape for a in jax.live_arrays())
+    tile = acc.G[0].shape
 
-    others = g_shaped() - 1
+    def tiles():
+        return sum(a.shape == tile for a in jax.live_arrays())
+
+    others = tiles() - steps
     live = []
     dispatch = type(acc)._dispatch_ranges
 
     def counted(self, *args):
         dispatch(self, *args)
-        live.append(g_shaped() - others)
+        live.append((tiles() - others) / steps)
 
     monkeypatch.setattr(type(acc), "_dispatch_ranges", counted)
     acc.add_grid(k0, k1)
@@ -795,7 +865,7 @@ def test_ring_loop_bound_and_donated_finalize_keep_the_gramian(monkeypatch):
         expected = np.asarray(jax.device_get(free.finalize_sharded()))
 
     bounded, _, _ = _ring64()
-    _budget(monkeypatch, 3, bounded.gramian_bytes_per_device)
+    _budget(monkeypatch, 3, bounded.state_bytes_per_device)
     bounded.add_grid(k0, k1)
     assert bounded.depth == 1
     with jax.enable_x64(True):

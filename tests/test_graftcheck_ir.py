@@ -99,13 +99,14 @@ def test_devicegen_ring_audits_clean(data, samples):
     K, B = 2, 8
     audit = audit_kernel(devicegen_ring_spec(data, samples, 64, B, K))
     assert audit.ok, "\n".join(f.format() for f in audit.findings)
-    # K ring passes per dispatch: K x (S-1) permutes, and the traced bytes
-    # equal the accumulator's own per-dispatch accounting
+    # K half-ring passes per dispatch: K x S//2 permutes, and the traced
+    # bytes equal the accumulator's own per-dispatch accounting
     # (DeviceGenRingGramianAccumulator.ring_bytes_total's formula).
-    assert audit.facts["permute_executions"] == K * (samples - 1)
+    assert audit.facts["permute_executions"] == K * (samples // 2)
+    assert audit.facts["ring_overlap_independent"]
     padded = padded_cohort(64, samples, pack=True)
     assert audit.facts["ring_bytes_jaxpr"] == ring_traffic_bytes(
-        data * K * B, samples, padded // samples, True
+        data * K * B, samples, padded // samples, True, samples // 2
     )
     # G is not donated (the dispatch loop bounds its queued copies), and
     # the ring program's GC005 disable says so.

@@ -196,6 +196,21 @@ def test_ring_disjoint_slice_refinement_engages():
     assert audit.facts["dot_partial_bound"] == 8.0
 
 
+@pytest.mark.parametrize("samples", [2, 3, 4, 8])
+def test_half_ring_tiles_each_take_one_partial_per_pass(samples):
+    # The device-generation half ring adds one dot partial per pass into
+    # each of its ⌊D/2⌋+1 step tiles, which hold disjoint entries: the
+    # per-dispatch entry increment is K x B, the largest tile's, and the
+    # tiles' stacking for the ring entry point keeps their deltas.
+    from spark_examples_tpu.check.ranges import devicegen_range_spec
+
+    audit = audit_range_kernel(devicegen_range_spec(1, samples, 64, 8, 2))
+    assert audit.ok, [f.format() for f in audit.findings]
+    assert audit.facts["entry_increment"] == 2 * 8
+    assert audit.facts["entry_increment_conservative"] == 2 * 8
+    assert "unhandled_primitives" not in audit.facts
+
+
 def test_counts_ring_kernel_audited_under_join_contract():
     # Same-set-join flushes ride the UNPACKED ring kernel regardless of
     # --ring-pack-bits; the count contract must be proven on that path.

@@ -161,18 +161,31 @@ class TestSchedMatrix:
 
     def test_flat_simulation_matches_formula_exactly(self):
         # GS002's clean side, asserted directly: the simulated flat
-        # schedule reproduces ring_traffic_bytes byte for byte.
+        # schedule reproduces ring_traffic_bytes byte for byte — the full
+        # ring's S-1 permutes for the host-fed kernel, the half ring's S//2
+        # for the device-generation kernel (2 ring passes per call).
         for hosts, per_host in DEFAULT_TOPOLOGIES:
             topo = Topology(hosts, per_host)
-            audit = audit_schedule(topo, "flat", selected=False)
-            assert audit.ok, [f.format() for f in audit.findings]
-            total = audit.facts["ici_bytes"] + audit.facts["dcn_bytes"]
-            assert total == ring_traffic_bytes(
-                audit.facts["rows_per_call"],
-                topo.devices,
-                schedule_kernel_spec(topo, "flat", 64, 8).n_local,
-                True,
-            )
+            for kernel, permutes, passes in (
+                ("gramian", topo.devices - 1, 1),
+                ("devicegen", topo.devices // 2, 2),
+            ):
+                audit = audit_schedule(
+                    topo, "flat", selected=False, kernel=kernel
+                )
+                assert audit.ok, [f.format() for f in audit.findings]
+                total = audit.facts["ici_bytes"] + audit.facts["dcn_bytes"]
+                assert total == ring_traffic_bytes(
+                    audit.facts["rows_per_call"],
+                    topo.devices,
+                    schedule_kernel_spec(
+                        topo, "flat", 64, 8, kernel=kernel
+                    ).n_local,
+                    True,
+                    permutes,
+                )
+                steps = audit.facts["ici_steps"] + audit.facts["dcn_steps"]
+                assert steps == passes * permutes
 
     def test_hier_per_level_bytes_and_steps(self):
         topo = Topology(4, 8)

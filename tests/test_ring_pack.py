@@ -235,8 +235,9 @@ def test_ring_bytes_counter_matches_formula_and_shows_8x():
 
 def test_devicegen_ring_bytes_accounts_ragged_final_byte():
     """Device-generation ring traffic: padded vs valid capacity tracked,
-    and the packed/unpacked byte ratio reflects the pack-width padding of
-    a ragged cohort (21 -> widths 8 packed-padded vs 6 unpacked)."""
+    two half-ring permutes per site on four devices, and the
+    packed/unpacked byte ratio reflects the pack-width padding of a ragged
+    cohort (21 -> widths 8 packed-padded vs 6 unpacked)."""
     from spark_examples_tpu.sharding.contig import Contig
     from spark_examples_tpu.sources.synthetic import SyntheticGenomicsSource
 
@@ -251,7 +252,8 @@ def test_devicegen_ring_bytes_accounts_ragged_final_byte():
         assert acc.sites_capacity >= acc.sites_valid == k1 - k0
         byte_totals[mode] = acc.ring_bytes_total
         expected = ring_traffic_bytes(
-            acc.sites_capacity, 4, acc.n_local, packed=(mode == "on")
+            acc.sites_capacity, 4, acc.n_local, packed=(mode == "on"),
+            permutes=2,
         )
         assert acc.ring_bytes_total == expected
     # Ragged cohort: unpacked n_local=6 (padded 24), packed n_local=8
@@ -294,6 +296,66 @@ def test_driver_publishes_ring_bytes_for_device_ingest(tmp_path):
         assert manifest_metric_value(doc, DEVICEGEN_SITES_CAPACITY) > 0
     assert lines["on"] == lines["off"]
     assert values["off"] == 8 * values["on"] > 0
+
+
+@pytest.mark.parametrize(
+    "samples, mode",
+    [(2, "on"), (3, "on"), (3, "off"), (4, "on"), (4, "off"), (8, "on")],
+    ids=["s2", "s3", "s3-unpacked", "s4", "s4-unpacked", "s8"],
+)
+def test_driver_half_ring_attrs_and_gramian(samples, mode):
+    """A device-generation job through the driver on a 1xD ring: the
+    ``ingest`` span counts ⌊D/2⌋+1 dots per block, the half ring's state
+    and ⌊D/2⌋ permutes of ring bytes; its ``finalize`` child counts
+    D-1-⌊D/2⌋ mirrored blocks; and the donated finalize's Gramian equals
+    the host-fed full ring's and the reference, bit for bit."""
+    from spark_examples_tpu.config import PcaConf
+    from spark_examples_tpu.pipeline.pca_driver import VariantsPcaDriver
+    from spark_examples_tpu.sources.synthetic import SyntheticGenomicsSource
+
+    n = 22
+    source = SyntheticGenomicsSource(num_samples=n, seed=31)
+    conf = PcaConf.parse(
+        ["--ingest", "device", "--num-samples", str(n), "--block-size", "64",
+         "--references", "2:0:150000", "--mesh-shape", f"1,{samples}",
+         "--similarity-strategy", "sharded", "--ring-pack-bits", mode]
+    )
+    driver = VariantsPcaDriver(conf, source, devices=jax.devices()[:samples])
+    contigs = conf.get_contigs(source, conf.variant_set_id)
+    with jax.enable_x64(True):
+        got = np.asarray(jax.device_get(driver.get_similarity_device_gen(contigs)))
+
+    ingest = driver.spans.find("ingest")
+    finalize = driver.spans.find("ingest/finalize")
+    steps = samples // 2 + 1
+    padded = padded_cohort(n, samples, pack=(mode == "on"))
+    n_local = padded // samples
+    assert ingest.attrs["ring_dots_per_block"] == steps
+    assert finalize.attrs["ring_mirrored_tiles"] == samples - steps
+    assert ingest.attrs["state_bytes_per_device"] == steps * n_local * n_local * 4
+    assert ingest.attrs["gramian_bytes_per_device"] == n_local * padded * 4
+    assert ingest.attrs["ring_bytes"] == ring_traffic_bytes(
+        ingest.attrs["sites_capacity"], samples, n_local, mode == "on",
+        samples // 2,
+    )
+
+    rows = np.concatenate(
+        [
+            block["has_variation"]
+            for contig in contigs
+            for block in source.genotype_blocks(
+                conf.variant_set_id[0], contig, block_size=512,
+                min_allele_frequency=conf.min_allele_frequency,
+            )
+        ]
+    )
+    full = ShardedGramianAccumulator(
+        n, make_mesh({SAMPLES_AXIS: samples}), block_size=64, exact_int=True,
+        pack_bits=mode,
+    )
+    full.add_rows(rows)
+    np.testing.assert_array_equal(got[:n, :n], full.finalize())
+    np.testing.assert_array_equal(got[:n, :n], gramian_reference(rows))
 
 
 # ------------------------------------------------------------ plan checks
@@ -370,9 +432,9 @@ _AUTOSOMES = ",".join(
 def test_plan_sizes_the_50k_ring_by_the_loops_copies_not_its_dispatches():
     """50,000 samples on 1x4 through the auto strategy (the dense Gramian
     cannot fit, so the ring is planned): chr17 and the whole genome ask the
-    same per-device bytes, three live copies of the 2.5 GB row tile (the
-    loop queues one dispatch behind the running one) and one more, inside
-    80% of a 16 GiB chip."""
+    same per-device bytes, three live copies of the half ring's state,
+    three 0.63 GB step tiles (the loop queues one dispatch behind the
+    running one), and one 2.5 GB row tile, inside 80% of a 16 GiB chip."""
     from spark_examples_tpu.ops.gramian import _DEFAULT_DEVICE_BYTES, DENSE_HBM_FRACTION
 
     reports = [
@@ -385,13 +447,16 @@ def test_plan_sizes_the_50k_ring_by_the_loops_copies_not_its_dispatches():
     (bytes_per_device,) = need
     tile = reports[0].geometry["sharded_tile_bytes_per_device"]
     assert tile == 12504 * 50016 * 4
+    state = reports[0].geometry["ring_state_bytes_per_device"]
+    assert state == 3 * 12504 * 12504 * 4
     assert reports[0].geometry["gramian_copies_max"] == 3
-    assert bytes_per_device == 4 * tile <= DENSE_HBM_FRACTION * _DEFAULT_DEVICE_BYTES
+    assert bytes_per_device == 3 * state + tile <= DENSE_HBM_FRACTION * _DEFAULT_DEVICE_BYTES
 
 
 def test_plan_rejects_a_three_copy_50k_ring_on_a_small_chip(monkeypatch):
-    """On a 4 GB device the loop still keeps three 2.5 GB copies (it queues
-    at least one dispatch): the plan refuses what the run could not hold."""
+    """On a 4 GB device the loop still keeps three 1.9 GB copies of the
+    half ring's state (it queues at least one dispatch): the plan refuses
+    what the run could not hold."""
     from spark_examples_tpu.ops import gramian
 
     monkeypatch.setattr(gramian, "_DEFAULT_DEVICE_BYTES", 4_000_000_000)

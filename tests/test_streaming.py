@@ -479,7 +479,13 @@ def test_cli_streamed_run_memory_is_bounded(tmp_path):
         "--block-size", "64",
     ]
 
-    streamed_argv = argv + ["--stream-chunk-bytes", str(chunk)]
+    # The parse pool keeps up to workers + 2 chunks in flight, and the
+    # default worker count follows the host's cores (up to 8: ten 64 KiB
+    # chunks, half this file). Pin the count so the bound measures the
+    # streaming window, not the host.
+    streamed_argv = argv + [
+        "--stream-chunk-bytes", str(chunk), "--ingest-workers", "2",
+    ]
     # Warm pass: jit tracing allocates ~20 MB of one-time Python objects
     # that tracemalloc would otherwise attribute to the measured run; the
     # second identical run reuses the compiled programs, so its peak is the
