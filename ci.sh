@@ -87,7 +87,7 @@
 #      replays the job journal, finishes the queued job, fails the
 #      mid-device job with a structured daemon-restarted error, and
 #      serves a repeat-geometry job warm from the run-dir persistent
-#      state. Then the serve-load harness (bench.py --config serve-load)
+#      state. Then the serve-load harness (serve_load.py)
 #      drives mixed traffic through the HTTP API and asserts small-job
 #      P99 under concurrent large-job load stays within ~2x its unloaded
 #      P99 and below the large job's wall-clock.
@@ -1001,7 +1001,7 @@ if [ "$sc_rc" -eq 0 ]; then
   # own wall-clock (the head-block detector).
   env JAX_PLATFORMS=cpu SPARK_EXAMPLES_TPU_NO_CACHE=1 \
       XLA_FLAGS="--xla_force_host_platform_device_count=4" \
-    python bench.py --config serve-load > "$SC_TMP/load.json" \
+    python serve_load.py > "$SC_TMP/load.json" \
       2> "$SC_TMP/load.err" || sc_rc=$?
   if [ "$sc_rc" -eq 0 ]; then
     env JAX_PLATFORMS=cpu python - "$SC_TMP/load.json" <<'PYEOF' || sc_rc=$?
@@ -1030,7 +1030,7 @@ if not (fs.get("calibration") or {}).get("samples"):
     print(f"/v1/fleet/stats calibration fold empty: {fs}"); sys.exit(1)
 # Fused-batch phase: the one-program group must be byte-identical to
 # the same jobs back to back and at least 2x their group throughput
-# (the acceptance bound; BENCH_r07 records ~5.8x on this host).
+# (the acceptance bound).
 fb = d["fused_batch"]
 if not fb["byte_identical"]:
     print("fused-batch phase lost byte parity"); sys.exit(1)

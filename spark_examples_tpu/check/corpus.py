@@ -96,7 +96,7 @@ def _edge_documents() -> List[str]:
         "##meta only, no header\n",
         # Headerless (data before #CHROM): empty cohort, still parsed.
         "17\t100\t.\tA\tG\t.\t.\tAF=0.5\n",
-        # Single-'#' comment before the header (the ADVICE.md regression).
+        # Single-'#' comment before the header (a past parser regression).
         "# a bare comment\n#CHROM\tPOS\tID\tREF\tALT\tQUAL\tFILTER\tINFO"
         "\tFORMAT\tS0\n17\t100\t.\tA\tG\t.\t.\t.\tGT\t0|1\n",
         # Malformed: < 8 fields — the parser must report, not overrun.

@@ -1527,7 +1527,7 @@ def predict_job_cost(
 ):
     """One job's admission-time :class:`~spark_examples_tpu.obs.costmodel.
     CostPrediction`, assembled from the SAME geometry facts the plan
-    validator proves — plan, serve admission, and bench share this ONE
+    validator proves — plan and serve admission share this ONE
     estimator, so a prediction printed by ``graftcheck plan`` and one
     stamped on a served job can never disagree.
 

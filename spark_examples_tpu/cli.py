@@ -75,7 +75,7 @@ emits a stderr progress line every N seconds (sites/sec, partition ETA,
 prefetch queue, dispatch depth, device memory); ``--metrics-json PATH``
 writes the schema-versioned run manifest (config echo, stage spans, all
 metrics, I/O stats, overlap accounting, prover-conformance pairs) that
-``bench.py`` and CI consume; ``--profile-dir`` adds the ``jax.profiler``
+CI's manifest checks consume; ``--profile-dir`` adds the ``jax.profiler``
 device trace:
 
     python -m spark_examples_tpu variants-pca --all-references \\

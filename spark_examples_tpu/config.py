@@ -164,8 +164,8 @@ def _build_base_parser(parser: argparse.ArgumentParser) -> argparse.ArgumentPars
             "Write the schema-versioned end-of-run manifest here: config "
             "echo, hierarchical stage spans, every registry metric, I/O "
             "stats, and ingest-overlap accounting (obs/manifest.py). The "
-            "numbers match the printed epilogue exactly; bench.py and CI "
-            "consume this instead of scraping stdout."
+            "numbers match the printed epilogue exactly; CI consumes this "
+            "instead of scraping stdout."
         ),
     )
     parser.add_argument(

@@ -2,7 +2,7 @@
 
 The reference delegated all observability to the Spark web UI and log4j
 (SURVEY.md §5); our earlier stand-ins were print-only strings scattered
-across four modules — invisible to ``bench.py``, CI, and multi-host runs.
+across four modules — invisible to CI and to multi-host runs.
 This package is the structured replacement:
 
 - :mod:`metrics <spark_examples_tpu.obs.metrics>` — a thread-safe registry
@@ -17,7 +17,8 @@ This package is the structured replacement:
   progress line for long runs (``--heartbeat-seconds``).
 - :mod:`manifest <spark_examples_tpu.obs.manifest>` — the schema-versioned
   end-of-run machine-readable manifest (``--metrics-json``), consumed by
-  ``bench.py`` and aggregated across processes under ``jax.distributed``.
+  CI's manifest checks and aggregated across processes under
+  ``jax.distributed``.
 
 Naming scheme (see DESIGN.md §9): ``<subsystem>_<what>[_<unit>]``;
 counters end in ``_total``, durations in ``_seconds``. Subsystem prefixes:

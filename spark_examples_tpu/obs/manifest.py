@@ -2,7 +2,7 @@
 
 The end-of-run epilogue used to be four print-only reports (I/O stats,
 stage timings, the overlap line, the TSV); machines re-ran the pipeline
-and hand-rolled their own dicts (``bench.py``). The manifest is the
+and hand-rolled their own dicts. The manifest is the
 structured superset: one schema-versioned JSON document with the config
 echo, the hierarchical span tree, every registry metric, the I/O stats
 block (numerically identical to the printed report — both read the same
@@ -17,7 +17,7 @@ Version history: v2 added the required ``host_memory`` block —
 ``peak_rss_bytes`` (measured OS high-water mark) next to
 ``static_bound_bytes`` (``parallel/mesh.py:host_peak_bytes``, null when
 the configured ingest path is O(file)), the pair ``graftcheck hostmem``
-cross-validates and ``bench.py`` reports as host-memory headroom. Still
+cross-validates: the host-memory headroom. Still
 v2 (additive): the optional ``gramian_exactness`` block — ``entry_max``
 (measured max |accumulator entry|, ``--check-ranges`` debug sampling)
 next to ``static_entry_bound`` (the conversion trigger's own projection,
@@ -45,8 +45,8 @@ measured_ring_bytes, predicted_ici_bytes, predicted_dcn_bytes}`` —
 present on sharded-strategy runs: which reduction schedule ran
 (``--reduce-schedule``: ``flat`` or ``hier``), its host-major topology
 factorization, and the STATIC ring-byte projection next to the
-per-flush-accounted total — the predicted-vs-measured pair ``bench.py``
-reports so BENCH rounds catch formula drift (``graftcheck sched`` proves
+per-flush-accounted total — the predicted-vs-measured pair that
+catches formula drift (``graftcheck sched`` proves
 the same formulas against the traced kernel jaxprs). Null on dense/host
 runs. Still v2 (additive): the optional ``conformance`` block —
 ``{prover: {measured, proven, ok} | null}`` for ``hostmem`` (peak RSS vs
@@ -116,9 +116,9 @@ def _json_safe(value):
 
 
 def _compile_cache_block() -> Optional[Dict]:
-    """Persistent compile-cache attribution (cold vs warm), mirroring
-    ``bench.py``'s reading of the config value ``utils/cache.py`` sets —
-    plus the process-wide warm-geometry ledger counts (v2-additive:
+    """Persistent compile-cache attribution (cold vs warm), read from the
+    config value ``utils/cache.py`` sets — plus the process-wide
+    warm-geometry ledger counts (v2-additive:
     ``geometry_hits``/``geometry_misses``), so a served run's manifest
     records whether its geometry was already compiled in this process."""
     from spark_examples_tpu.utils.cache import compile_cache_stats
@@ -306,7 +306,7 @@ def validate_manifest(doc) -> List[str]:
 
     Checks schema identity/version, required top-level keys, the span tree
     shape (recursively), the metrics export shape, and the I/O stats block
-    fields — the contract ``bench.py`` and the CI smoke stage consume."""
+    fields — the contract the CI smoke stage consumes."""
     errors: List[str] = []
     if not isinstance(doc, Mapping):
         return ["manifest is not a JSON object"]
@@ -615,7 +615,7 @@ def manifest_metric_value(
     doc: Mapping, name: str, labels: Optional[Mapping[str, str]] = None, default=None
 ):
     """Read one metric series out of a manifest (the consumer-side mirror
-    of ``MetricsRegistry.value`` — what ``bench.py`` uses)."""
+    of ``MetricsRegistry.value``)."""
     family = (doc.get("metrics") or {}).get(name)
     if not family:
         return default

@@ -2,10 +2,9 @@
 
 One :class:`MetricsRegistry` per run (the driver owns it), so concurrent
 runs and tests never cross-contaminate; components that run standalone
-(``bench.py`` component benchmarks, the public API) create private
-registries. All mutation is lock-protected per metric child — ingest worker
-threads, the prefetch producer, and the driver thread all write
-concurrently.
+(the public API) create private registries. All mutation is
+lock-protected per metric child — ingest worker threads, the prefetch
+producer, and the driver thread all write concurrently.
 
 Two exports, one data model:
 
@@ -368,7 +367,7 @@ def well_known_counter(registry: "MetricsRegistry", name: str):
     """Register (idempotently) a well-known counter with its canonical help
     text — one spelling shared by every producer (``ops/gramian.py``'s
     flush telemetry and the driver's device-ingest epilogue), the heartbeat,
-    bench.py, and CI's manifest assertions."""
+    and CI's manifest assertions."""
     return registry.counter(name, _WELL_KNOWN_COUNTER_HELP[name])
 
 

@@ -19,7 +19,7 @@ whole ingest onto the TPU:
 - there is no per-site host→device traffic at all, so throughput is pure
   device compute, independent of host→device bandwidth; fused scanning
   keeps dispatches in the hundreds for a whole-genome run (158 at the
-  bench.py whole-genome geometry).
+  2,504-sample whole-genome geometry).
 
 Exactness of the host↔device correspondence is trivial by construction:
 both sides draw the same uint32 allele pair (``_allele_pair`` here,
@@ -33,7 +33,6 @@ rule every host path uses (``sources/synthetic.py:af_passes``).
 from __future__ import annotations
 
 import collections
-import contextlib
 import functools
 import re
 import time
@@ -166,7 +165,7 @@ def _pop_segments(pops_np: np.ndarray) -> Optional[list]:
     if len(pops_np) < 128 * (len(boundaries) + 1):
         # Narrow segments waste VPU lanes (each pads to the 128-lane
         # register width): a 17-sample deep-call cohort is ~2.5× FASTER
-        # through the single gathered compare (measured, BENCH_r04 platinum).
+        # through the single gathered compare (a pre-chip claim).
         return None
     starts = np.concatenate([[0], boundaries])
     stops = np.concatenate([boundaries, [len(pops_np)]])
@@ -709,21 +708,17 @@ def _fused_update_mesh(
 class _GridDispatchAccumulator:
     """Shared dispatch machinery for the device-generation accumulators:
     validated (grid_offset, n_valid) group dispatch, data-axis round-robin,
-    and the early sync fetch (:meth:`poke`). Subclasses provide ``_update`` with signature
-    ``(G, variant_rows, kept_sites, offsets, valids)`` plus the
-    ``data_parallel`` / ``sites_per_dispatch`` / ``_scalar_sharding``
+    and the bound on queued dispatches. Subclasses provide ``_update``
+    with signature ``(G, variant_rows, kept_sites, offsets, valids)`` plus
+    the ``data_parallel`` / ``sites_per_dispatch`` / ``_scalar_sharding``
     attributes."""
-
-    #: whether the early sync fetch has fired for this accumulator (at most
-    #: once; see :meth:`poke` and the dispatch-loop gating).
-    _poked = False
 
     #: dispatched site-grid CAPACITY (summed over data slices — every slice
     #: executes the full scan, padding included) vs the VALID sites inside
-    #: it. Their gap is the dispatch padding waste (``bench.py`` reports the
-    #: fraction per config; at small regions the fixed tail-group padding
-    #: dominates wall-clock), and capacity × per-site ring traffic gives
-    #: ``ring_bytes_total`` for the ring accumulator.
+    #: it. Their gap is the dispatch padding waste (the benchmark's
+    #: ``dispatch_padding_share``; at small regions the fixed tail-group
+    #: padding dominates wall-clock), and capacity × per-site ring traffic
+    #: gives ``ring_bytes_total`` for the ring accumulator.
     sites_capacity = 0
     sites_valid = 0
     #: population segments whose columns the update generates in one pass
@@ -734,8 +729,6 @@ class _GridDispatchAccumulator:
     #: uploads and the program call, where the host waits while the
     #: device's queue is full.
     dispatch_ns = 0
-    #: the run's span recorder; the early sync fetch is its ``poke`` span.
-    spans = None
     #: bytes of one device's shard of the finished G, set where G is made.
     gramian_bytes_per_device = 0
     #: bytes of one device's accumulator state, of which each queued
@@ -778,13 +771,6 @@ class _GridDispatchAccumulator:
             self._update, self.sites_per_dispatch, grid_offsets, n_valids
         )
 
-    def _maybe_poke(self) -> None:
-        """Poke once, at the moment a SECOND dispatch is about to be issued
-        — in this grid walk or a later one. A single-dispatch run never
-        pokes (the terminal fetch waits for the lone dispatch either way)."""
-        if self.dispatches == 1 and not self._poked:
-            self.poke()
-
     def _dispatch_ranges(self, update, cap, grid_offsets, n_valids) -> None:
         D = self.data_parallel
         grid_offsets = np.asarray(grid_offsets, dtype=np.int64)
@@ -797,7 +783,6 @@ class _GridDispatchAccumulator:
             # Negative grid indices would wrap to garbage uint64 positions on
             # device and silently corrupt the Gramian.
             raise ValueError("grid_offsets must be non-negative")
-        self._maybe_poke()
         start = time.perf_counter_ns()
         with jax.enable_x64(True):
             args = (
@@ -879,28 +864,6 @@ class _GridDispatchAccumulator:
             list(range(rem_start, last_index, tail_sites)),
             last_index,
         )
-
-    def poke(self) -> None:
-        """One scalar sync fetch after the first dispatch: the host waits
-        for that dispatch to execute before queueing the rest.
-
-        On a locally attached v5e this changes nothing measurable: the
-        whole-genome wall clock was 2.5769 s with it and 2.5745 s without
-        (medians of 3, PERF.md, PR 21) — a candidate for deletion (ROADMAP
-        S0). Fetches a process-local shard, not the global value: in a
-        multi-controller run the counter spans non-addressable devices and
-        ``device_get`` would raise.
-        """
-        from spark_examples_tpu.parallel.mesh import local_shard
-
-        span = (
-            self.spans.span("poke")
-            if self.spans is not None
-            else contextlib.nullcontext()
-        )
-        with span, jax.enable_x64(True):
-            local_shard(self.kept_sites)
-        self._poked = True
 
     def sync(self) -> None:
         """Block until the whole ingest chain has executed: one synchronous
@@ -1120,7 +1083,6 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
     def _dispatch_single(
         self, update, grid_offset: int, n_valid: int, cap: Optional[int] = None
     ) -> None:
-        self._maybe_poke()
         start = time.perf_counter_ns()
         with jax.enable_x64(True):
             args = (

@@ -1076,9 +1076,9 @@ class ShardedGramianAccumulator:
         """The run manifest's ``schedule`` block: which reduction schedule
         ran, its topology factorization, the STATIC per-flush projection of
         ring bytes next to the per-flush-accounted total — the
-        predicted-vs-measured pair ``bench.py`` reports so BENCH rounds
-        catch formula drift (a counts-fallback flush or a wire-format
-        change moves ``measured`` away from ``predicted``)."""
+        predicted-vs-measured pair that catches formula drift (a
+        counts-fallback flush or a wire-format change moves ``measured``
+        away from ``predicted``)."""
         capacity_rows = self.data_parallel * self.block_size
         per_flush = ring_traffic_bytes(
             capacity_rows, self.samples_parallel, self.n_local, self.pack

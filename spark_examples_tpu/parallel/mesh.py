@@ -602,14 +602,6 @@ def device_put_global(x, sharding):
     )
 
 
-def local_shard(x) -> np.ndarray:
-    """One addressable shard of a global array — a process-local synchronous
-    fetch that works in single- and multi-controller modes alike (used for
-    the early sync fetch, where only the sync matters, not the value)."""
-    shards = x.addressable_shards
-    return np.asarray(shards[0].data) if shards else np.asarray(x)
-
-
 def make_mesh(
     shape: Dict[str, int],
     devices: Optional[Sequence[jax.Device]] = None,
@@ -822,7 +814,6 @@ __all__ = [
     "host_peak_bytes",
     "distributed_init",
     "host_value",
-    "local_shard",
     "packed_host_fetch",
     "make_mesh",
     "default_mesh",

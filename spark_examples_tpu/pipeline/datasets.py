@@ -78,10 +78,10 @@ class PrefetchIterator:
     the parse instead of letting parsed blocks pile up in host memory.
     Exceptions from the source iterator re-raise at the consuming position.
     Overlap accounting (:meth:`overlap_stats` — producer-busy,
-    producer-blocked, consumer-wait seconds) feeds the run manifest, the
-    ingest-overlap report in ``bench.py``, and ``--profile-dir`` stage
-    timings: producer-blocked time means the device is the bottleneck,
-    consumer-wait time means parse is. ``registry`` (the run's
+    producer-blocked, consumer-wait seconds) feeds the run manifest and
+    ``--profile-dir`` stage timings: producer-blocked time means the
+    device is the bottleneck, consumer-wait time means parse is.
+    ``registry`` (the run's
     :class:`~spark_examples_tpu.obs.metrics.MetricsRegistry`, optional)
     gets a live ``prefetch_queue_occupancy`` gauge for the heartbeat and
     the final overlap gauges on :meth:`close`; ``spans`` (the run's

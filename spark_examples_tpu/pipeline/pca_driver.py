@@ -98,7 +98,7 @@ def _fetch_components_and_nonzero(device_components, nz, mesh):
     below f32's 2^24 exact-integer range). Returns ``(components, nonzero)``.
 
     The separate nonzero and components fetches were the dominant share of
-    small-region wall-clock (VERDICT r4 weakness 1); the batched-transfer
+    small-region wall-clock; the batched-transfer
     pattern lives in ``parallel/mesh.py:packed_host_fetch``. ``mesh`` is
     the samples-sharded mesh for the sharded eigensolve path (the packed
     result is replicated so every process of a multi-controller run reads
@@ -782,9 +782,9 @@ class VariantsPcaDriver:
         device and transposed) and ``sync`` (the closing fetch). Inside
         ``enqueue``, the
         ``dispatch`` aggregate is the host time spent handing dispatches to
-        the runtime (where the host waits on a full device queue), ``stats``
-        the per-shard stats accounting and ``poke`` the early sync fetch;
-        its self time is the loop's own host work. ``ingest`` carries
+        the runtime (where the host waits on a full device queue) and
+        ``stats`` the per-shard stats accounting; its self time is the
+        loop's own host work. ``ingest`` carries
         ``sites_valid`` and ``sites_capacity``, the padding of the
         dispatched grid, ``pop_segments``, the population segments
         generated in one pass (0 where the thresholds are gathered),
@@ -832,7 +832,6 @@ class VariantsPcaDriver:
                 acc = self._device_gen_accumulator(
                     mesh, use_ring, blocks_per_dispatch, reduce_schedule
                 )
-            acc.spans = self.spans
 
             from spark_examples_tpu.obs.metrics import (
                 INGEST_PARTITIONS_PLANNED,

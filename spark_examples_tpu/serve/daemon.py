@@ -1089,7 +1089,7 @@ class PcaService:
             )
 
         # Admission-time cost prediction: the ONE estimator (check/
-        # plan.py:predict_job_cost, shared with the plan CLI and bench)
+        # plan.py:predict_job_cost, shared with the plan CLI)
         # over the geometry the validator above just computed — no second
         # validation — then calibrated against the fleet's measured
         # history. Prediction is telemetry plus a feasibility gate; a

@@ -1241,7 +1241,7 @@ class FileGenomicsSource(GenomicsSource):
             return view
 
     def _auto_stream_verified(self, set_id: str) -> bool:
-        """The ADVICE.md sharp-edge fix: AUTO-selected streaming verifies
+        """The unsorted-input fix: AUTO-selected streaming verifies
         coordinate-sortedness up front (a cached site-only pass — the same
         scan lazy contig discovery runs, O(chunk) memory, no genotype walk)
         instead of hard-erroring mid-ingest. An unsorted file warns and

@@ -1,8 +1,8 @@
 """Shared persistent XLA compile cache placement + warm-geometry ledger.
 
 First TPU compile of a shape costs seconds to tens of seconds. Every entry
-point (CLI, daemon, ``bench.py``, ``chip_smoke.py``) keeps its compile
-cache in the one directory :func:`compile_cache_dir` names:
+point (CLI, daemon, ``serve_load.py``, ``benchmark/run.py``) keeps its
+compile cache in the one directory :func:`compile_cache_dir` names:
 ``JAX_COMPILATION_CACHE_DIR`` when it is set, else ``<checkout>/.jax_cache``
 (gitignored). The path is part of the cache key, so it never derives from
 a run directory, a temp dir, a pid or the time.

@@ -3,7 +3,6 @@
 code), else ``<checkout>/.jax_cache``."""
 
 import os
-import sys
 
 import jax
 import pytest
@@ -57,8 +56,8 @@ def test_entries_count_the_resolved_dir(monkeypatch, tmp_path):
 
 
 @pytest.mark.parametrize("env_set", [True, False], ids=["env", "default"])
-def test_daemon_and_bench_use_the_same_dir(cache_updates, monkeypatch, tmp_path, env_set):
-    """The daemon and bench.py resolve the directory through the same
+def test_daemon_and_cli_use_the_same_dir(cache_updates, monkeypatch, tmp_path, env_set):
+    """The daemon and the CLI resolve the directory through the same
     function: never the daemon's run dir."""
     if env_set:
         monkeypatch.setenv(cache.CACHE_DIR_ENV, str(tmp_path / "shared"))
@@ -78,9 +77,8 @@ def test_daemon_and_bench_use_the_same_dir(cache_updates, monkeypatch, tmp_path,
         cache.reset_compile_cache_stats()  # detach the run dir's ledger
     assert daemon_dir == expected
 
-    import bench
+    from spark_examples_tpu import cli
 
-    monkeypatch.setattr(bench, "_run_config", lambda name, device: {"stub": name})
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--config", "brca1"])
-    bench.main()
+    monkeypatch.setitem(cli.COMMANDS, "variants-pca", lambda rest: None)
+    assert cli.main(["variants-pca"]) == 0
     assert cache_updates.get("jax_compilation_cache_dir") == expected

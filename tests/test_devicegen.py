@@ -326,51 +326,6 @@ def test_auto_blocks_per_dispatch_scales_with_cohort():
     assert 32 <= k <= 512 and k % 8 == 0
 
 
-def test_poke_gating_spans_grid_walks():
-    """The eager-mode poke fires exactly once, at the first dispatch with
-    more work following — including work in a LATER add_grid call: a
-    single-group first contig must not suppress the poke for the rest of a
-    multi-contig run, and a single-group-only run must never poke (it would
-    pay a pure round-trip for an overlap it cannot use)."""
-    source = SyntheticGenomicsSource(num_samples=8, seed=5)
-
-    def make():
-        return DeviceGenGramianAccumulator(
-            num_samples=8,
-            vs_keys=[source.genotype_stream_key("vs")],
-            pops=source.populations,
-            site_key=source.site_key,
-            spacing=source.variant_spacing,
-            ref_block_fraction=source.ref_block_fraction,
-            block_size=32,
-            blocks_per_dispatch=2,
-        )
-
-    group = 32 * 2
-    # Single-group run: no poke.
-    acc = make()
-    acc.add_grid(0, group)
-    assert acc.dispatches == 1 and not acc._poked
-    # Multi-group run: poked.
-    acc = make()
-    acc.add_grid(0, 3 * group)
-    assert acc.dispatches == 3 and acc._poked
-    # Single-group FIRST contig, then a multi-group contig: the poke fires
-    # during the second walk.
-    acc = make()
-    acc.add_grid(0, group)
-    assert not acc._poked
-    acc.add_grid(10 * group, 13 * group)
-    assert acc._poked
-    # Many contigs each fitting ONE group (decoy-heavy --all-references):
-    # the second dispatch — in a different add_grid — still pokes.
-    acc = make()
-    acc.add_grid(0, group)
-    assert not acc._poked
-    acc.add_grid(10 * group, 11 * group)
-    assert acc.dispatches == 2 and acc._poked
-
-
 def test_device_multiset_concatenates_per_set_genotypes():
     source = SyntheticGenomicsSource(num_samples=12, seed=3)
     contig = Contig("20", 100_000, 140_000)
@@ -614,7 +569,10 @@ def test_device_ingest_bitwise_matches_host_fuzz():
     check()
 
 
-@pytest.mark.parametrize("mesh_shape", [{"samples": 4}, {"data": 2, "samples": 4}])
+@pytest.mark.parametrize(
+    "mesh_shape",
+    [{"samples": 4}, {"data": 2, "samples": 4}, {"data": 2, "samples": 2}],
+)
 def test_ring_device_ingest_matches_host(mesh_shape):
     """Sharded large-N device ingest: per-slice column generation + ring
     exchange equals the host reference Gramian, at padded non-divisible N."""

@@ -646,7 +646,7 @@ def test_typecheck_gate_skips_or_passes():
 
 
 # --------------------------------------------------------------------------
-# The gz auto-streaming sortedness fallback (ADVICE.md sharp edge).
+# The gz auto-streaming sortedness fallback (a past sharp edge).
 # --------------------------------------------------------------------------
 
 _VCF_HEADER = (

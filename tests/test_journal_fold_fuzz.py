@@ -164,7 +164,17 @@ def test_compaction_rewrite_preserves_fold_semantics():
 
 def test_no_property_testing_dependency():
     # The differential fuzz must stay importable on the bare image: a
-    # hypothesis import would make this file collection-error there.
-    import sys
+    # hypothesis import would make this file collection-error there. The
+    # check reads this module's own imports, not ``sys.modules``, which
+    # holds whatever other tests in the same worker imported.
+    import ast
 
-    assert "hypothesis" not in sys.modules
+    with open(__file__, encoding="utf-8") as handle:
+        tree = ast.parse(handle.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported.update(alias.name.split(".")[0] for alias in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            imported.add(node.module.split(".")[0])
+    assert "hypothesis" not in imported

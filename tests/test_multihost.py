@@ -3,7 +3,7 @@ capability, ``/root/reference/README.md:64-104``; ``GenomicsConf.scala:50-57``).
 
 These tests spawn actual coordinator-connected subprocesses — no mocking, no
 single-process simulation — and assert the multi-controller code paths
-(``parallel/mesh.py:host_value``/``local_shard``, the replicated finalize in
+(``parallel/mesh.py:host_value``, the replicated finalize in
 ``ops/devicegen.py``) execute and agree with the host oracle in EVERY
 process.
 """

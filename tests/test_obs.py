@@ -259,16 +259,16 @@ def test_span_self_time_excludes_children():
     rec = SpanRecorder()
     with rec.span("enqueue"):
         rec.add("stats", 0.5)
-        with rec.span("poke"):
+        with rec.span("fetch"):
             pass
     enqueue = rec.find("enqueue")
-    poke = rec.find("enqueue/poke")
+    fetch = rec.find("enqueue/fetch")
     # The pre-measured aggregate may exceed the real elapsed time; self
     # time is duration minus every closed child, whatever they claim.
     assert enqueue.self_seconds == pytest.approx(
-        enqueue.seconds - 0.5 - poke.seconds
+        enqueue.seconds - 0.5 - fetch.seconds
     )
-    assert poke.self_seconds == poke.seconds
+    assert fetch.self_seconds == fetch.seconds
 
 
 def test_device_gen_spans_land_in_the_profiler_trace(tmp_path):

@@ -520,7 +520,7 @@ def test_sharded_device_ingest_run_matches_dense_run():
 
 
 def test_merged_sharded_run_stays_on_device_and_matches_wire(capsys):
-    """The VERDICT-r4 cliff, closed: a merged (asymmetric 2-set) config
+    """A past cliff, closed: a merged (asymmetric 2-set) config
     under the SHARDED strategy — the joint-cohort-past-the-dense-HBM-rule
     scenario (``VariantsPca.scala:155-168``) — now runs the multi-set ring
     device path instead of silently falling back to wire ingest, and its

@@ -461,7 +461,7 @@ def test_streamed_ingest_memory_is_bounded_by_chunk(tmp_path):
 
 
 def test_cli_streamed_run_memory_is_bounded(tmp_path):
-    """The VERDICT-r4 'Done' criterion, literally: a VCF ingested THROUGH
+    """The streamed-ingest criterion, literally: a VCF ingested THROUGH
     ``variants-pca --source file`` with streaming on keeps peak traced host
     memory far below the file size (tracemalloc sees every chunk buffer and
     parse array; device buffers are O(N²), not O(file)). The wire path on

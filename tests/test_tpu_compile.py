@@ -18,7 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 N_1KG = 2504  # 1000 Genomes phase 3 cohort
 N_LARGE = 25_000  # the large-cohort cell
-BLOCK = 16384  # bench.py's whole-genome block size
+BLOCK = 16384  # the wgs-batch traffic's block size (benchmark/traffic)
 
 
 @pytest.fixture(scope="module")
