@@ -234,6 +234,8 @@ class VariantsPcaDriver:
         self.indexes: Dict[str, int] = {
             cs["id"]: i for i, cs in enumerate(callsets)
         }
+        # The inverse of ``indexes``: callset ids in matrix-row order.
+        self.callset_ids: List[str] = list(self.indexes)
         self.names: Dict[str, str] = {cs["id"]: cs["name"] for cs in callsets}
         print(f"Matrix size: {len(self.indexes)}.")
         # After callset discovery: the static bound needs the REAL cohort
@@ -1146,10 +1148,7 @@ class VariantsPcaDriver:
                 )
             print(f"Non zero rows in matrix: {nonzero} / {n}.")
             components = fetched.astype(np.float64)
-        reverse = {i: cs_id for cs_id, i in self.indexes.items()}
-        return [
-            (reverse[i], [float(c) for c in components[i]]) for i in range(n)
-        ]
+        return list(zip(self.callset_ids, components.tolist()))
 
     @staticmethod
     def _host_center(similarity: np.ndarray) -> np.ndarray:

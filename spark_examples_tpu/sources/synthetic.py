@@ -289,25 +289,27 @@ class SyntheticGenomicsSource(GenomicsSource):
         (``VariantsPca.scala:275``)."""
         return f"{variant_set_id}-{i}"
 
+    def _name_tag(self, variant_set_id: str) -> int:
+        """The set's two-digit callset-name tag: one key hash per set, so
+        callers naming many callsets derive it once, not once per sample."""
+        return int(self._vs_key(variant_set_id) % _U64(90))
+
     def callset_name(self, variant_set_id: str, i: int) -> str:
-        tag = int(self._vs_key(variant_set_id) % _U64(90))
-        return f"S{tag:02d}N{i:05d}"
+        return f"S{self._name_tag(variant_set_id):02d}N{i:05d}"
 
     def search_callsets(self, variant_set_ids: Sequence[str]) -> List[Dict]:
         """Callsets across the requested variant sets. Duplicate variant-set
         ids contribute their callsets once, as the real SearchCallSets API
         (a search over a *set* of variant sets) would
-        (``VariantsPca.scala:97-105``)."""
+        (``VariantsPca.scala:97-105``). The ids and names are those of
+        :meth:`callset_id` and :meth:`callset_name`."""
         out = []
-        seen = set()
-        for vsid in variant_set_ids:
-            if vsid in seen:
-                continue
-            seen.add(vsid)
-            for i in range(self.num_samples_for(vsid)):
-                out.append(
-                    {"id": self.callset_id(vsid, i), "name": self.callset_name(vsid, i)}
-                )
+        for vsid in dict.fromkeys(variant_set_ids):
+            tag = self._name_tag(vsid)
+            out += [
+                {"id": f"{vsid}-{i}", "name": f"S{tag:02d}N{i:05d}"}
+                for i in range(self.num_samples_for(vsid))
+            ]
         return out
 
     def get_contigs(
@@ -522,12 +524,12 @@ class SyntheticGenomicsSource(GenomicsSource):
             genotypes = self._genotype_alleles(variant_set_id, positions)
         record["calls"] = [
             {
-                "callSetId": self.callset_id(variant_set_id, s),
-                "callSetName": self.callset_name(variant_set_id, s),
+                "callSetId": cs["id"],
+                "callSetName": cs["name"],
                 "genotype": [int(genotypes[0, s, 0]), int(genotypes[0, s, 1])],
                 "phaseset": "*",
             }
-            for s in range(self.num_samples_for(variant_set_id))
+            for s, cs in enumerate(self.search_callsets([variant_set_id]))
         ]
         return record
 

@@ -97,6 +97,35 @@ def test_pca_separates_populations():
     assert abs(means[0] - means[1]) > 3 * spread
 
 
+@pytest.mark.parametrize("backend", ["host", "tpu"])
+def test_compute_pca_rows_match_per_element_rows(monkeypatch, backend):
+    """The result rows are the callset ids in matrix order, each beside its
+    components as Python floats: what a reverse index map and a float()
+    per element build from the same fetched components."""
+    conf = _conf(variant_set_id=["vs-a", "vs-b"], pca_backend=backend)
+    source = SyntheticGenomicsSource(num_samples=30, seed=7, cohort_sizes={"vs-b": 7})
+    driver = VariantsPcaDriver(conf, source)
+    S = driver.get_similarity_matrix(list(driver.iter_calls(driver.get_data())))
+    fetched = []
+    name = "mllib_reference_pca" if backend == "host" else "_fetch_components_and_nonzero"
+    original = getattr(pca_driver, name)
+
+    def spy(*args, **kwargs):
+        out = original(*args, **kwargs)
+        fetched.append(out[0])
+        return out
+
+    monkeypatch.setattr(pca_driver, name, spy)
+    rows = driver.compute_pca(S)
+    n = len(driver.indexes)
+    assert n == 37
+    components = np.asarray(fetched[0]).astype(np.float64)
+    reverse = {i: cs_id for cs_id, i in driver.indexes.items()}
+    expected = [(reverse[i], [float(c) for c in components[i]]) for i in range(n)]
+    assert rows == expected
+    assert all(type(r) is tuple and type(r[1][0]) is float for r in rows)
+
+
 def test_min_allele_frequency_filters():
     conf = _conf(min_allele_frequency=0.2)
     driver = VariantsPcaDriver(conf, _source(conf))

@@ -16,6 +16,58 @@ def test_callsets_are_stable_and_sized(small_source):
     assert callsets == small_source.search_callsets(["vs-a"])
 
 
+@pytest.mark.parametrize(
+    "set_ids, cohort_sizes",
+    [
+        (["vs-a"], {}),
+        (["vs-a", "vs-b"], {"vs-b": 7}),
+        (["vs-a", "vs-b", "vs-a"], {"vs-a": 12}),
+    ],
+    ids=["one-set", "two-sets-sized", "repeated-set"],
+)
+def test_search_callsets_equals_per_sample_names(set_ids, cohort_sizes):
+    """Discovery builds exactly the per-sample ids and names, in order,
+    each set once, each at its own cohort size."""
+    source = SyntheticGenomicsSource(
+        num_samples=40, seed=7, cohort_sizes=cohort_sizes
+    )
+    expected = [
+        {"id": source.callset_id(v, i), "name": source.callset_name(v, i)}
+        for v in dict.fromkeys(set_ids)
+        for i in range(source.num_samples_for(v))
+    ]
+    assert source.search_callsets(set_ids) == expected
+
+
+def test_callset_names_are_pinned():
+    """The name rule ``S<tag>N<index>``, the tag the set key mod 90."""
+    source = SyntheticGenomicsSource(num_samples=40, seed=7, cohort_sizes={"vs-b": 7})
+    callsets = source.search_callsets(["vs-a", "vs-b"])
+    assert callsets[0] == {"id": "vs-a-0", "name": "S36N00000"}
+    assert callsets[39] == {"id": "vs-a-39", "name": "S36N00039"}
+    assert callsets[46] == {"id": "vs-b-6", "name": "S40N00006"}
+    assert source.callset_name("1000genomes", 12345) == "S03N12345"
+    kg = SyntheticGenomicsSource(num_samples=2504, seed=42)
+    assert kg.callset_name("10473108253681171589", 2503) == "S15N02503"
+
+
+def test_search_callsets_hashes_each_set_once(monkeypatch):
+    from spark_examples_tpu.sources import synthetic
+
+    calls = []
+    string_key = synthetic._string_key
+
+    def counting(s):
+        calls.append(s)
+        return string_key(s)
+
+    monkeypatch.setattr(synthetic, "_string_key", counting)
+    source = SyntheticGenomicsSource(num_samples=5000, seed=7)
+    callsets = source.search_callsets(["vs-a", "vs-b"])
+    assert len(callsets) == 10_000
+    assert calls == ["vs-a", "vs-b"]
+
+
 def test_contigs_exclude_xy(small_source):
     names = {c.reference_name for c in small_source.get_contigs("vs", SexChromosomeFilter.EXCLUDE_XY)}
     assert "X" not in names and "Y" not in names
