@@ -792,7 +792,9 @@ class VariantsPcaDriver:
         generated in one pass (0 where the thresholds are gathered),
         ``gramian_bytes_per_device``, one device's tile of the finished G,
         ``gramian_copies_max``, the most copies of the accumulator state the
-        loop can keep live (``ops/devicegen.py:gramian_copies_max``); on
+        loop can keep live (``ops/devicegen.py:gramian_copies_max``),
+        ``dispatch_depth``, the queued dispatches the memory rule allowed
+        (``ops/devicegen.py:dispatch_depth``; absent where no dispatch ran); on
         ring runs only ``ring_bytes``, the ring's ICI bytes,
         ``ring_dots_per_block``, the int8 dots per block and device (⌊D/2⌋+1
         on the half ring), and ``state_bytes_per_device``, one copy of the
@@ -943,6 +945,8 @@ class VariantsPcaDriver:
                 gramian_bytes_per_device=int(acc.gramian_bytes_per_device),
                 gramian_copies_max=int(acc.gramian_copies_max),
             )
+            if acc.depth is not None:
+                span.attrs["dispatch_depth"] = int(acc.depth)
             if use_ring:
                 span.attrs.update(
                     ring_bytes=int(acc.ring_bytes_total),

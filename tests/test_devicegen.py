@@ -780,32 +780,88 @@ def _waits(monkeypatch):
     return waited
 
 
-@pytest.mark.parametrize("copies", [3, 4])
-def test_ring_loop_keeps_at_most_its_copies(monkeypatch, copies):
-    """Ten ring dispatches on a 1x4 mesh: the loop waits for the dispatch
-    ``depth`` back after each one, so at most ``depth`` stay queued and the
-    live copies of its state, the half ring's three step tiles, stay
-    within ``gramian_copies_max`` (``depth + 2``)."""
-    acc, k0, k1 = _ring64()
-    steps = len(acc.G)
-    assert acc.state_bytes_per_device == steps * 16 * 16 * 4 == 3 * 1024
-    _budget(monkeypatch, copies, acc.state_bytes_per_device)
-    waited = _waits(monkeypatch)
-    tile = acc.G[0].shape
+def _dense64_job():
+    """The driver's dense device-generation job over 64 samples on one
+    device: ten dispatches of 16 sites. Returns the driver, its contig and
+    the Gramian, fetched."""
+    from spark_examples_tpu.config import PcaConf
+    from spark_examples_tpu.pipeline.pca_driver import VariantsPcaDriver
 
-    def tiles():
-        return sum(a.shape == tile for a in jax.live_arrays())
+    source = SyntheticGenomicsSource(num_samples=64, seed=42)
+    conf = PcaConf.parse([
+        "--ingest", "device", "--num-samples", "64", "--block-size", "16",
+        "--blocks-per-dispatch", "1",
+        "--references", f"17:0:{160 * source.variant_spacing}",
+    ])
+    driver = VariantsPcaDriver(conf, source, devices=jax.devices()[:1])
+    (contig,) = conf.get_contigs(source, conf.variant_set_id)
+    S = driver.get_similarity_device_gen([contig])
+    return driver, contig, np.asarray(jax.device_get(S))
 
-    others = tiles() - steps
+
+def _live_copies(monkeypatch, cls, method, shape, per_copy, present):
+    """Record, after each call of ``cls.method`` (one dispatch), the live
+    copies of the loop's state: the arrays of ``shape`` live then, less
+    those live now that are not the ``present`` state arrays, in copies of
+    ``per_copy`` arrays."""
+
+    def count():
+        return sum(a.shape == shape for a in jax.live_arrays())
+
+    others = count() - present
     live = []
-    dispatch = type(acc)._dispatch_ranges
+    dispatch = getattr(cls, method)
 
-    def counted(self, *args):
-        dispatch(self, *args)
-        live.append((tiles() - others) / steps)
+    def counted(self, *args, **kwargs):
+        dispatch(self, *args, **kwargs)
+        live.append((count() - others) / per_copy)
 
-    monkeypatch.setattr(type(acc), "_dispatch_ranges", counted)
-    acc.add_grid(k0, k1)
+    monkeypatch.setattr(cls, method, counted)
+    return live
+
+
+@pytest.mark.parametrize(
+    "kind, copies", [("ring", 3), ("ring", 4), ("dense", 3)], ids=["3", "4", "dense"]
+)
+def test_ring_loop_keeps_at_most_its_copies(monkeypatch, kind, copies):
+    """Ten dispatches: the loop waits for the dispatch ``depth`` back after
+    each one, so at most ``depth`` stay queued and the live copies of its
+    state stay within ``gramian_copies_max`` (``depth + 2``). On the ring
+    (a 1x4 mesh) the state is the half ring's three step tiles. The dense
+    loop runs on one device through the driver, at depth 1 as a
+    25,000-sample Gramian is on a v5e chip; its Gramian equals the
+    unbounded loop's and the host reference's, and its ``ingest`` span
+    carries the depth."""
+    if kind == "dense":
+        from spark_examples_tpu.obs.spans import recent_spans
+
+        free, contig, expected = _dense64_job()
+        assert free._device_gen_acc.depth > free._device_gen_acc.dispatches
+        _budget(monkeypatch, copies, 64 * 64 * 4)
+        waited = _waits(monkeypatch)
+        live = _live_copies(
+            monkeypatch, DeviceGenGramianAccumulator, "_dispatch_single", (64, 64), 1, 0
+        )
+        driver, _, got = _dense64_job()
+        acc = driver._device_gen_acc
+        host = _host_blocks(driver.source, driver.conf.variant_set_id[0], contig)
+        np.testing.assert_array_equal(got, expected)
+        np.testing.assert_array_equal(
+            got, gramian_reference(np.concatenate([b["has_variation"] for b in host]))
+        )
+        attrs = [s for s in recent_spans() if s["path"] == "ingest"][-1]["attrs"]
+        assert attrs["dispatch_depth"] == acc.depth
+        assert attrs["gramian_copies_max"] == acc.gramian_copies_max
+    else:
+        acc, k0, k1 = _ring64()
+        steps = len(acc.G)
+        assert acc.state_bytes_per_device == steps * 16 * 16 * 4 == 3 * 1024
+        _budget(monkeypatch, copies, acc.state_bytes_per_device)
+        waited = _waits(monkeypatch)
+        live = _live_copies(
+            monkeypatch, type(acc), "_dispatch_ranges", acc.G[0].shape, steps, steps
+        )
+        acc.add_grid(k0, k1)
     assert acc.dispatches >= 9
     assert acc.depth == copies - 2
     assert acc.gramian_copies_max == copies
@@ -889,8 +945,9 @@ def test_dense_loop_adds_no_wait_where_the_depth_is_not_reached(monkeypatch):
 @pytest.mark.parametrize(
     "gramian_bytes, device_bytes, depth",
     [(2_501_600_256, 15_750_000_000, 1), (2_501_600_256, 4_000_000_000, 1),
-     (625_250_000, 15_750_000_000, 8), (25_080_064, 15_750_000_000, 249)],
-    ids=["50k-ring", "50k-small-chip", "25k-ring", "kg1000"],
+     (625_250_000, 15_750_000_000, 8), (25_080_064, 15_750_000_000, 249),
+     (2_500_000_000, 15_750_000_000, 1)],
+    ids=["50k-ring", "50k-small-chip", "25k-ring", "kg1000", "25k-dense"],
 )
 def test_dispatch_depth(gramian_bytes, device_bytes, depth):
     from spark_examples_tpu.ops.devicegen import dispatch_depth
