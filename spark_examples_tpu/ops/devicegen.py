@@ -15,7 +15,9 @@ whole ingest onto the TPU:
   genotype thresholds, the ``--min-allele-frequency`` filter) bit-identically
   to the host source, generates the (block, samples) genotype matrix with
   the exact same splitmix64 draws, and accumulates ``G += XᵀX`` on the MXU —
-  one scanned XLA program per dispatch group;
+  one scanned XLA program per dispatch group; on one device a wide cohort
+  accumulates only the tiles on and above G's diagonal
+  (:func:`dense_tiles_per_side`), mirrored once at finalize;
 - there is no per-site host→device traffic at all, so throughput is pure
   device compute, independent of host→device bandwidth; fused scanning
   keeps dispatches in the hundreds for a whole-genome run (158 at the
@@ -358,6 +360,59 @@ def auto_blocks_per_dispatch(total_columns: int, block_size: int) -> int:
     return int(min(512, max(32, (k // 8) * 8)))
 
 
+#: ``(fewest columns, column tiles per side)`` of the one-slice dense
+#: state, widest first; narrower cohorts keep one G.
+_DENSE_TILES = ((8192, 8), (2048, 4))
+#: Tile boundaries sit on multiples of this many columns, the chip's lane
+#: width, where the cohort is wide enough for every tile to get one.
+_TILE_ALIGN = 128
+
+
+def dense_tiles_per_side(total_columns: int) -> int:
+    """Column tiles per side T of the one-slice dense accumulator's state:
+    it accumulates only the T(T+1)/2 tiles on and above the diagonal of
+    the symmetric Gramian, each into its own carry, ½ + Σwᵢ²/(2N²) of the
+    full square's dot work (0.625 at T = 4, 0.5625 at T = 8).
+
+    Measured on one v5e through the benchmark's jobs (PERF.md): at 2,504
+    columns (whole genome) a job took 2.079 s at T = 1, 1.702 at 2, 1.573
+    at 4, and 1.822 and 1.831 at 6 and 8, whose tiles are not multiples
+    of 128 columns there; at 25,000 columns (chr17) 4.595 s at T = 1,
+    3.544 at 2, 3.033 at 4, 2.916 at 6, 2.721 at 8, 2.625 at 10 and 2.742
+    at 12. T = 8 stands for the wide cohorts: T = 10 saved another 3.5%
+    of a job, but its program holds 55 tile dots against 36 and takes
+    half as long again to compile. The switch between 4 and 8 sits near
+    the measured points' geometric middle. Below 2,048 columns (four tiles
+    of 512) the tiles are unmeasured, and the state stays one N×N G (T =
+    1): the program of an untiled state, unchanged."""
+    for columns, tiles in _DENSE_TILES:
+        if int(total_columns) >= columns:
+            return tiles
+    return 1
+
+
+def dense_tile_bounds(total_columns: int, tiles: int) -> Tuple[int, ...]:
+    """The ``tiles + 1`` column boundaries of the dense state's tiles:
+    equal widths rounded up to a multiple of :data:`_TILE_ALIGN` where
+    every tile still gets columns (25,000 at T = 4: 6,272 × 3 and a last
+    tile of 6,184, nothing padded), else equal widths, the last tile the
+    remainder."""
+    n, t = int(total_columns), int(tiles)
+    step = -(-n // t)
+    aligned = -(-step // _TILE_ALIGN) * _TILE_ALIGN
+    if aligned * (t - 1) < n:
+        step = aligned
+    if step * (t - 1) >= n:
+        raise ValueError(f"{n} columns cannot make {t} column tiles")
+    return tuple(min(i * step, n) for i in range(t)) + (n,)
+
+
+def _upper_pairs(tiles: int) -> Tuple[Tuple[int, int], ...]:
+    """The (i, j) tiles on and above the diagonal, row by row: the order
+    of the dense tiled state."""
+    return tuple((i, j) for i in range(tiles) for j in range(i, tiles))
+
+
 #: Share of one device's memory the copies of G a dispatch loop keeps
 #: live may take, where one queued dispatch leaves room: half of the dense
 #: rule's 80% (``ops/gramian.py:DENSE_HBM_FRACTION``), the other half left
@@ -514,6 +569,7 @@ def _fused_update(
     accum_name: str,
     n_pops: int,
     set_sizes: Optional[Tuple[int, ...]] = None,
+    tile_bounds: Optional[Tuple[int, ...]] = None,
     tail: bool = False,
 ):
     """Build (and memoize) the scanned generate→accumulate program for one
@@ -532,6 +588,11 @@ def _fused_update(
     joint-cohort configurations (``pops_bytes`` is then the concatenation of
     each set's population vector); ``None`` means every set shares the one
     cohort ``pops_bytes`` describes.
+
+    ``tile_bounds`` (:func:`dense_tile_bounds`) makes the state G the
+    tuple of the tiles on and above the diagonal (:func:`_upper_pairs`),
+    each block's dot for tile (i, j) ``X[:, cols_i]ᵀ X[:, cols_j]`` added
+    into that tile's own carry; ``None`` keeps the one N×N G.
 
     The program is named ``devicegen_update`` (``devicegen_update_tail``
     with ``tail``), so its XLA module is ``jit_devicegen_update[_tail]``,
@@ -621,9 +682,25 @@ def _fused_update(
                     # path's hash fusion emits the count beside its rows).
                     rows_count = count_rows(rows_count, X)
                 with jax.named_scope("int8_dot"):
-                    G = G + jnp.einsum(
-                        "bn,bm->nm", X, X, preferred_element_type=accum_dtype
-                    )
+                    if tile_bounds is None:
+                        G = G + jnp.einsum(
+                            "bn,bm->nm", X, X, preferred_element_type=accum_dtype
+                        )
+                    else:
+                        # One carry per tile: each dot and its add compile
+                        # into one fusion that writes that tile; adds into
+                        # slices of one G ran the half ring's dots at half
+                        # rate on the chip (PERF.md).
+                        cols = [
+                            X[:, a:b] for a, b in zip(tile_bounds, tile_bounds[1:])
+                        ]
+                        G = tuple(
+                            g + jnp.einsum(
+                                "bn,bm->nm", cols[i], cols[j],
+                                preferred_element_type=accum_dtype,
+                            )
+                            for g, (i, j) in zip(G, _upper_pairs(len(cols)))
+                        )
                 return (G, rows_count, kept_count), None
 
             (G, rows_count, kept_count), _ = lax.scan(
@@ -644,6 +721,44 @@ def _fused_update(
             return scan_update(G, rows_count, kept_count, grid_offset, n_valid)
 
         return devicegen_update
+
+
+@functools.lru_cache(maxsize=8)
+def _dense_zeros(bounds: Tuple[int, ...], dtype_name: str):
+    """A program that writes the zero tiles of the dense tiled state in
+    one dispatch: made one by one, their dozens of small eager dispatches
+    left the device idle ~1% longer in each 25,000-sample job on a v5e
+    (PERF.md)."""
+    widths = np.diff(bounds)
+
+    def devicegen_dense_zeros():
+        return tuple(
+            jnp.zeros((int(widths[i]), int(widths[j])), dtype_name)
+            for i, j in _upper_pairs(len(widths))
+        )
+
+    return jax.jit(devicegen_dense_zeros)
+
+
+@functools.lru_cache(maxsize=8)
+def _dense_result(tiles: int):
+    """The dense tiled state (:func:`_upper_pairs` order) → the symmetric
+    N×N G, each tile below the diagonal the transpose of its mirror, in
+    one program named ``devicegen_dense_result``. The tiles are not
+    donated: no output has their shape."""
+    pairs = _upper_pairs(tiles)
+
+    @jax.jit
+    def devicegen_dense_result(state):  # graftcheck: disable=GC005 -- no output has a tile's shape, so a donated tile could not be reused
+        tile = dict(zip(pairs, state))
+        return jnp.block(
+            [
+                [tile[i, j] if i <= j else tile[j, i].T for j in range(tiles)]
+                for i in range(tiles)
+            ]
+        )
+
+    return devicegen_dense_result
 
 
 @functools.lru_cache(maxsize=32)
@@ -684,7 +799,7 @@ def _fused_update_mesh(
         accum_name,
         n_pops,
         set_sizes,
-        tail,
+        tail=tail,
     )
     g_spec = P(DATA_AXIS, None, None)
     r_spec = P(DATA_AXIS, None)
@@ -732,9 +847,13 @@ class _GridDispatchAccumulator:
     #: bytes of one device's shard of the finished G, set where G is made.
     gramian_bytes_per_device = 0
     #: bytes of one device's accumulator state, of which each queued
-    #: dispatch holds its own copy: G's shard, or the half ring's step
-    #: tiles (:class:`DeviceGenRingGramianAccumulator`).
+    #: dispatch holds its own copy: G's shard, the dense tiles on and
+    #: above the diagonal, or the half ring's step tiles
+    #: (:class:`DeviceGenRingGramianAccumulator`).
     state_bytes_per_device = 0
+    #: column tiles per side of the one-slice dense state
+    #: (:func:`dense_tiles_per_side`); 1 where the state is one G.
+    dense_tiles_per_side = 1
     #: :func:`dispatch_depth` of this loop, set at its first dispatch.
     depth = None
 
@@ -908,7 +1027,10 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
     positions (``index · spacing``), recomputes site metadata, generates
     genotypes, and accumulates. Carries the Gramian, a kept-site counter,
     and per-set variant-row counters through chained scanned dispatches;
-    nothing is fetched until finalize. ``exact_int`` accumulates
+    nothing is fetched until finalize. On one device a cohort of
+    :func:`dense_tiles_per_side` T > 1 carries the Gramian as its T(T+1)/2
+    column tiles on and above the diagonal (:func:`dense_tile_bounds`),
+    which :meth:`finalize_device` assembles. ``exact_int`` accumulates
     int8×int8→int32 on the MXU (always exact; whole-genome diagonal counts
     ~12M would sit uncomfortably close to f32's 2^24 integer limit — SURVEY
     §7 hard-part 3).
@@ -999,11 +1121,18 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
         )
 
         D = self.data_parallel
+        n = self.total_columns
         with jax.enable_x64(True):
             if D == 1:
-                self.G = jnp.zeros(
-                    (self.total_columns, self.total_columns), accum_dtype
-                )
+                # The data-parallel path below keeps one G per slice.
+                self.dense_tiles_per_side = dense_tiles_per_side(n)
+                bounds = None
+                if self.dense_tiles_per_side > 1:
+                    bounds = dense_tile_bounds(n, self.dense_tiles_per_side)
+                    self.G = _dense_zeros(bounds, np.dtype(accum_dtype).name)()
+                else:
+                    self.G = jnp.zeros((n, n), accum_dtype)
+                update_key += (bounds,)
                 # Per-set counts of rows with variation in that set's columns
                 # — matches the wire path's per-dataset record accounting.
                 self.variant_rows = jnp.zeros((self.n_sets,), jnp.int64)
@@ -1036,8 +1165,10 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
                     np.zeros((D,), np.int64), NamedSharding(mesh, s_spec)
                 )
                 self._update = _fused_update_mesh(*update_key, mesh)
-        self.gramian_bytes_per_device = _shard_bytes(self.G)
-        self.state_bytes_per_device = self.gramian_bytes_per_device
+        self.gramian_bytes_per_device = n * n * np.dtype(accum_dtype).itemsize
+        self.state_bytes_per_device = sum(
+            _shard_bytes(t) for t in jax.tree.leaves(self.G)
+        )
         # Tail program: a ~K/8-length variant of the same scanned update for
         # contig remainders. Large dispatch groups amortize per-dispatch
         # overhead, but a whole-genome run has 22 contig tails — padding
@@ -1127,9 +1258,14 @@ class DeviceGenGramianAccumulator(_GridDispatchAccumulator):
         """The accumulated Gramian, still on device; for data-parallel
         accumulators this is the one cross-slice reduce (the Spark
         ``reduceByKey`` shuffle become a single ``psum`` over ICI,
-        ``VariantsPca.scala:230``)."""
+        ``VariantsPca.scala:230``). A tiled state is assembled into the
+        symmetric N×N G once (:func:`_dense_result`), which then becomes the
+        state: the accumulator lets go of its tiles, and takes no further
+        dispatch."""
         from spark_examples_tpu.ops.gramian import data_axis_sum
 
+        if isinstance(self.G, tuple):
+            self.G = _dense_result(self.dense_tiles_per_side)(self.G)
         if self.data_parallel > 1:
             if not self.G.is_fully_addressable:
                 # Multi-controller: replicate so every process can fetch.

@@ -791,14 +791,17 @@ class VariantsPcaDriver:
         dispatched grid, ``pop_segments``, the population segments
         generated in one pass (0 where the thresholds are gathered),
         ``gramian_bytes_per_device``, one device's tile of the finished G,
-        ``gramian_copies_max``, the most copies of the accumulator state the
+        ``state_bytes_per_device``, one copy of the accumulator state (the
+        dense tiles on and above the diagonal, or the half ring's step
+        tiles), ``gramian_copies_max``, the most copies of that state the
         loop can keep live (``ops/devicegen.py:gramian_copies_max``),
         ``dispatch_depth``, the queued dispatches the memory rule allowed
-        (``ops/devicegen.py:dispatch_depth``; absent where no dispatch ran); on
-        ring runs only ``ring_bytes``, the ring's ICI bytes,
-        ``ring_dots_per_block``, the int8 dots per block and device (⌊D/2⌋+1
-        on the half ring), and ``state_bytes_per_device``, one copy of the
-        state (the half ring's step tiles); and ``device_peak_bytes``, the
+        (``ops/devicegen.py:dispatch_depth``; absent where no dispatch ran),
+        ``dense_tiles_per_side``, the column tiles per side of the dense
+        state (``ops/devicegen.py:dense_tiles_per_side``; 1 where it is one
+        G, and on the ring); on ring runs only ``ring_bytes``, the ring's
+        ICI bytes, and ``ring_dots_per_block``, the int8 dots per block and
+        device (⌊D/2⌋+1 on the half ring); and ``device_peak_bytes``, the
         largest
         device memory peak over the loop's local devices after the sync
         (absent where devices report no memory stats, as the CPU's do).
@@ -943,7 +946,9 @@ class VariantsPcaDriver:
                 sites_capacity=int(acc.sites_capacity),
                 pop_segments=int(acc.pop_segments),
                 gramian_bytes_per_device=int(acc.gramian_bytes_per_device),
+                state_bytes_per_device=int(acc.state_bytes_per_device),
                 gramian_copies_max=int(acc.gramian_copies_max),
+                dense_tiles_per_side=int(acc.dense_tiles_per_side),
             )
             if acc.depth is not None:
                 span.attrs["dispatch_depth"] = int(acc.depth)
@@ -951,7 +956,6 @@ class VariantsPcaDriver:
                 span.attrs.update(
                     ring_bytes=int(acc.ring_bytes_total),
                     ring_dots_per_block=int(acc.ring_dots_per_block),
-                    state_bytes_per_device=int(acc.state_bytes_per_device),
                 )
             peak = _device_peak_bytes(acc.kept_sites.sharding.addressable_devices)
             if peak is not None:

@@ -734,6 +734,133 @@ def test_ring_device_ingest_end_to_end_sharded_pca():
     np.testing.assert_allclose(c_dense, c_sharded * signs, atol=1e-3)
 
 
+# ----------------------------------------------------------------- the dense tiles
+
+
+def _tiles(monkeypatch, tiles):
+    """Make the dense state's rule give ``tiles`` tiles per side."""
+    from spark_examples_tpu.ops import devicegen
+
+    monkeypatch.setattr(devicegen, "dense_tiles_per_side", lambda columns: tiles)
+
+
+@pytest.mark.parametrize("tiles", [1, 2, 3, 4])
+@pytest.mark.parametrize("case", ["uneven", "joint", "mesh"])
+def test_dense_tiles_finalize_equals_reference_and_one_g(monkeypatch, case, tiles):
+    """The tiled dense state, main and tail programs both run, finalizes
+    to the host reference's Gramian and the one-G state's, bit for bit:
+    22 columns (tiles of 11; of 8 and 6; of 6 and 4), a joint
+    cohort of 13 + 6 columns, and a data-parallel mesh, which keeps one G
+    per slice whatever the rule says."""
+    from spark_examples_tpu.ops.devicegen import dense_tile_bounds
+    from spark_examples_tpu.parallel.mesh import DATA_AXIS, make_mesh
+
+    sets = ["setA", "setB"] if case == "joint" else ["vs"]
+    source = SyntheticGenomicsSource(
+        num_samples=13 if case == "joint" else 22, seed=5, cohort_sizes={"setB": 6}
+    )
+    contig = Contig("8", 7_000, 77_000)
+    sizes = [source.num_samples_for(s) for s in sets]
+    kw = dict(
+        num_samples=source.num_samples,
+        vs_keys=[source.genotype_stream_key(s) for s in sets],
+        pops=source.populations,
+        site_key=source.site_key,
+        spacing=source.variant_spacing,
+        ref_block_fraction=source.ref_block_fraction,
+        block_size=16,
+        blocks_per_dispatch=8,
+        n_pops=source.n_pops,
+        mesh=make_mesh({DATA_AXIS: 2}) if case == "mesh" else None,
+        set_sizes=sizes if case == "joint" else None,
+        pops_per_set=[source.populations_for(s) for s in sets] if case == "joint" else None,
+    )
+    k0, k1 = source.site_grid_range(contig)
+    assert (k1 - k0) % (16 * 8)
+
+    one = DeviceGenGramianAccumulator(**kw)
+    one.add_grid(k0, k1)
+    _tiles(monkeypatch, tiles)
+    acc = DeviceGenGramianAccumulator(**kw)
+    n = sum(sizes)
+    if case == "mesh" or tiles == 1:
+        assert acc.dense_tiles_per_side == 1
+        assert acc.state_bytes_per_device == acc.gramian_bytes_per_device
+    else:
+        bounds = dense_tile_bounds(n, tiles)
+        widths = np.diff(bounds)
+        assert acc.dense_tiles_per_side == tiles
+        assert [g.shape for g in acc.G] == [
+            (widths[i], widths[j]) for i in range(tiles) for j in range(i, tiles)
+        ]
+        assert acc.state_bytes_per_device == 4 * sum(g.size for g in acc.G)
+        assert acc.gramian_bytes_per_device == 4 * n * n
+    acc.add_grid(k0, k1)
+    assert acc._update_tail is not None
+    got = acc.finalize()
+    assert not isinstance(acc.G, tuple)
+
+    rows, pos = {}, {}
+    for s in sets:
+        blocks = _host_blocks(source, s, contig)
+        rows[s] = np.concatenate([b["has_variation"] for b in blocks])
+        pos[s] = np.concatenate([b["positions"] for b in blocks])
+    all_pos = np.union1d(*pos.values()) if len(sets) > 1 else pos[sets[0]]
+    joint = np.zeros((len(all_pos), n), dtype=np.int64)
+    start = 0
+    for s, size in zip(sets, sizes):
+        joint[np.searchsorted(all_pos, pos[s]), start : start + size] = rows[s]
+        start += size
+    np.testing.assert_array_equal(got, joint.T @ joint)
+    np.testing.assert_array_equal(got, one.finalize())
+    acc_rows, acc_kept = acc.ingest_counters()
+    one_rows, one_kept = one.ingest_counters()
+    np.testing.assert_array_equal(acc_rows, one_rows)
+    assert acc_kept == one_kept
+
+
+@pytest.mark.parametrize(
+    "columns, tiles, bounds",
+    [
+        (17, 1, None),
+        (2504, 4, (0, 640, 1280, 1920, 2504)),
+        (25_000, 8, (0, 3200, 6400, 9600, 12800, 16000, 19200, 22400, 25000)),
+    ],
+    ids=["platinum", "kg1000", "kg25k"],
+)
+def test_dense_tiles_rule_at_the_cells_widths(columns, tiles, bounds):
+    """The rule gives the one-chip cells' cohorts their measured tiles per
+    side; the boundaries sit on multiples of 128 columns, the last tile
+    the remainder, nothing padded."""
+    from spark_examples_tpu.ops.devicegen import dense_tile_bounds, dense_tiles_per_side
+
+    assert dense_tiles_per_side(columns) == tiles
+    if bounds is not None:
+        assert dense_tile_bounds(columns, tiles) == bounds
+
+
+@pytest.mark.parametrize(
+    "columns, tiles, bounds",
+    [
+        (25_000, 4, (0, 6272, 12544, 18816, 25000)),
+        (22, 3, (0, 8, 16, 22)),
+        (40, 4, (0, 10, 20, 30, 40)),
+    ],
+    ids=["kg25k-4", "narrow-uneven", "narrow-even"],
+)
+def test_dense_tile_bounds(columns, tiles, bounds):
+    from spark_examples_tpu.ops.devicegen import dense_tile_bounds
+
+    assert dense_tile_bounds(columns, tiles) == bounds
+
+
+def test_dense_tile_bounds_refuse_an_empty_tile():
+    from spark_examples_tpu.ops.devicegen import dense_tile_bounds
+
+    with pytest.raises(ValueError):
+        dense_tile_bounds(5, 4)
+
+
 # ----------------------------------------------------------------- the loop's memory
 
 
@@ -821,7 +948,9 @@ def _live_copies(monkeypatch, cls, method, shape, per_copy, present):
 
 
 @pytest.mark.parametrize(
-    "kind, copies", [("ring", 3), ("ring", 4), ("dense", 3)], ids=["3", "4", "dense"]
+    "kind, copies",
+    [("ring", 3), ("ring", 4), ("dense", 3), ("dense-tiled", 4)],
+    ids=["3", "4", "dense", "dense-tiled"],
 )
 def test_ring_loop_keeps_at_most_its_copies(monkeypatch, kind, copies):
     """Ten dispatches: the loop waits for the dispatch ``depth`` back after
@@ -829,18 +958,23 @@ def test_ring_loop_keeps_at_most_its_copies(monkeypatch, kind, copies):
     state stay within ``gramian_copies_max`` (``depth + 2``). On the ring
     (a 1x4 mesh) the state is the half ring's three step tiles. The dense
     loop runs on one device through the driver, at depth 1 as a
-    25,000-sample Gramian is on a v5e chip; its Gramian equals the
-    unbounded loop's and the host reference's, and its ``ingest`` span
-    carries the depth."""
-    if kind == "dense":
+    25,000-sample Gramian is on a v5e chip, or with its state in two tiles
+    per side (three 32² tiles) at depth 2, as the tiles are there; its
+    Gramian equals the unbounded loop's and the host reference's, and its
+    ``ingest`` span carries the depth, the tiles per side and the state's
+    bytes."""
+    if kind.startswith("dense"):
         from spark_examples_tpu.obs.spans import recent_spans
 
+        tiles = 2 if kind == "dense-tiled" else 1
+        shape, per_copy = ((32, 32), 3) if tiles > 1 else ((64, 64), 1)
+        _tiles(monkeypatch, tiles)
         free, contig, expected = _dense64_job()
         assert free._device_gen_acc.depth > free._device_gen_acc.dispatches
-        _budget(monkeypatch, copies, 64 * 64 * 4)
+        _budget(monkeypatch, copies, per_copy * shape[0] * shape[1] * 4)
         waited = _waits(monkeypatch)
         live = _live_copies(
-            monkeypatch, DeviceGenGramianAccumulator, "_dispatch_single", (64, 64), 1, 0
+            monkeypatch, DeviceGenGramianAccumulator, "_dispatch_single", shape, per_copy, 0
         )
         driver, _, got = _dense64_job()
         acc = driver._device_gen_acc
@@ -852,6 +986,9 @@ def test_ring_loop_keeps_at_most_its_copies(monkeypatch, kind, copies):
         attrs = [s for s in recent_spans() if s["path"] == "ingest"][-1]["attrs"]
         assert attrs["dispatch_depth"] == acc.depth
         assert attrs["gramian_copies_max"] == acc.gramian_copies_max
+        assert attrs["dense_tiles_per_side"] == tiles
+        assert attrs["state_bytes_per_device"] == per_copy * shape[0] * shape[1] * 4
+        assert attrs["gramian_bytes_per_device"] == 64 * 64 * 4
     else:
         acc, k0, k1 = _ring64()
         steps = len(acc.G)
@@ -946,8 +1083,10 @@ def test_dense_loop_adds_no_wait_where_the_depth_is_not_reached(monkeypatch):
     "gramian_bytes, device_bytes, depth",
     [(2_501_600_256, 15_750_000_000, 1), (2_501_600_256, 4_000_000_000, 1),
      (625_250_000, 15_750_000_000, 8), (25_080_064, 15_750_000_000, 249),
-     (2_500_000_000, 15_750_000_000, 1)],
-    ids=["50k-ring", "50k-small-chip", "25k-ring", "kg1000", "25k-dense"],
+     (2_500_000_000, 15_750_000_000, 1), (1_406_880_000, 15_750_000_000, 2),
+     (1_406_880_000, 16_909_000_000, 2)],
+    ids=["50k-ring", "50k-small-chip", "25k-ring", "kg1000", "25k-dense",
+         "25k-dense-tiles", "25k-dense-tiles-v5e-limit"],
 )
 def test_dispatch_depth(gramian_bytes, device_bytes, depth):
     from spark_examples_tpu.ops.devicegen import dispatch_depth

@@ -312,8 +312,12 @@ def test_device_gen_spans_land_in_the_profiler_trace(tmp_path):
     # A dense run on the CPU: no ring bytes, no device memory stats.
     assert set(ingest.attrs) == {
         "sites_valid", "sites_capacity", "pop_segments",
-        "gramian_bytes_per_device", "gramian_copies_max", "dispatch_depth",
+        "gramian_bytes_per_device", "state_bytes_per_device", "gramian_copies_max",
+        "dispatch_depth", "dense_tiles_per_side",
     }
+    # 8 columns keep one G: the state is the Gramian.
+    assert ingest.attrs["dense_tiles_per_side"] == 1
+    assert ingest.attrs["state_bytes_per_device"] == ingest.attrs["gramian_bytes_per_device"]
     assert 0 < ingest.attrs["sites_valid"] <= ingest.attrs["sites_capacity"]
     assert ingest.attrs["pop_segments"] == 0  # 8 samples gather their thresholds
 

@@ -72,15 +72,24 @@ def test_dense_update_compiles(one_chip, operand, accum):
     assert _about(mem.output_size_in_bytes, N_1KG * N_1KG * 4)
 
 
-def _whole_genome_update(one_chip):
-    """The fused generate→accumulate scan at the whole-genome dispatch
-    geometry (spacing 73, B=16384, the auto dispatch length) and its
-    arguments' shapes on one described chip."""
-    from spark_examples_tpu.ops.devicegen import _fused_update, auto_blocks_per_dispatch
+def _devicegen_update(one_chip, n):
+    """The fused generate→accumulate scan of ``n`` columns at the cells'
+    dispatch geometry (spacing 73, B=16384, the auto dispatch length, the
+    dense state's tiles by its rule) and its arguments' shapes on one
+    described chip."""
+    from spark_examples_tpu.ops.devicegen import (
+        _fused_update,
+        _upper_pairs,
+        auto_blocks_per_dispatch,
+        dense_tile_bounds,
+        dense_tiles_per_side,
+    )
     from spark_examples_tpu.sources.synthetic import SyntheticGenomicsSource
 
-    source = SyntheticGenomicsSource(num_samples=N_1KG, seed=42, variant_spacing=73)
-    K = auto_blocks_per_dispatch(N_1KG, BLOCK)
+    source = SyntheticGenomicsSource(num_samples=n, seed=42, variant_spacing=73)
+    K = auto_blocks_per_dispatch(n, BLOCK)
+    tiles = dense_tiles_per_side(n)
+    bounds = dense_tile_bounds(n, tiles) if tiles > 1 else None
     with jax.enable_x64(True):
         update = _fused_update(
             (source.genotype_stream_key("bench-1kg"),),
@@ -95,23 +104,36 @@ def _whole_genome_update(one_chip):
             "int32",
             int(source.n_pops),
             None,
+            bounds,
         )
+        if bounds is None:
+            state = _spec((n, n), jnp.int32, one_chip)
+        else:
+            widths = np.diff(bounds)
+            state = tuple(
+                _spec((int(widths[i]), int(widths[j])), jnp.int32, one_chip)
+                for i, j in _upper_pairs(tiles)
+            )
         scalar = _spec((), jnp.int64, one_chip)
-        shapes = (
-            _spec((N_1KG, N_1KG), jnp.int32, one_chip),
-            _spec((1,), jnp.int64, one_chip),
-            scalar,
-            scalar,
-            scalar,
-        )
+        shapes = (state, _spec((1,), jnp.int64, one_chip), scalar, scalar, scalar)
     return update, shapes
+
+
+def _whole_genome_update(one_chip):
+    """The whole-genome cell's update at 2,504 columns."""
+    return _devicegen_update(one_chip, N_1KG)
+
+
+def _state_bytes(shapes):
+    return sum(4 * int(np.prod(s.shape)) for s in jax.tree.leaves(shapes[0]))
 
 
 def test_devicegen_whole_genome_update_compiles(one_chip):
     update, shapes = _whole_genome_update(one_chip)
     with jax.enable_x64(True):
         compiled = update.lower(*shapes).compile()
-    assert compiled.memory_analysis().output_size_in_bytes >= N_1KG * N_1KG * 4
+    assert len(jax.tree.leaves(shapes[0])) == 10  # four tiles per side
+    assert compiled.memory_analysis().output_size_in_bytes >= _state_bytes(shapes)
 
 
 def _instructions(hlo_text):
@@ -122,6 +144,11 @@ def _instructions(hlo_text):
         if " = " in text:
             out[text.split(" = ", 1)[0].removeprefix("ROOT ").lstrip("%")] = text
     return out
+
+
+def _shape(line):
+    """The shape of an instruction's result, without its layout."""
+    return line.split(" = ", 1)[1].split("{", 1)[0]
 
 
 def _computations(hlo_text):
@@ -195,6 +222,57 @@ def test_devicegen_update_op_scopes_split_generation_from_the_dot(one_chip):
     ]
     assert hashes[0].split(" = ", 1)[0].lstrip("%") == operand
     assert scope[operand] == "generate"
+
+
+def test_devicegen_dense_tiles_compile_one_dot_per_tile(one_chip):
+    """The dense update at 25,000 columns as the chip compiles it: the
+    state is the T(T+1)/2 tiles on and above the diagonal (T = 8), and the
+    scan body runs one convolution fusion per tile, each adding into its
+    own tile's carry and reading the one ``generate`` fusion's s8 operand
+    whole (its column slices fold into the dots: no copy or slice fusion
+    of the operand), with no ``dynamic-update-slice``; every convolution
+    is scoped ``int8_dot``."""
+    from spark_examples_tpu.ops import devicegen
+
+    update, shapes = _devicegen_update(one_chip, N_LARGE)
+    tiles = devicegen.dense_tiles_per_side(N_LARGE)
+    n_tiles = tiles * (tiles + 1) // 2
+    assert (tiles, len(shapes[0])) == (8, n_tiles)
+    saved = dict(devicegen._DISPATCHED)
+    devicegen._DISPATCHED.clear()
+    try:
+        devicegen._note_dispatch(update, shapes)
+        scope = devicegen.update_op_scopes()["jit_devicegen_update"]
+        text = devicegen._compiled_text(update, shapes)
+    finally:
+        devicegen._DISPATCHED.clear()
+        devicegen._DISPATCHED.update(saved)
+    bodies = _computations(text)
+    ops = [op for body in bodies.values() for op in body]
+    assert not [op for op in ops if " dynamic-update-slice(" in op]
+
+    def called(line):
+        return line.split("calls=")[1].split(",")[0].lstrip("%") if "calls=" in line else None
+
+    def name(line):
+        return line.split(" = ", 1)[0].removeprefix("ROOT ").lstrip("%")
+
+    dots = {n for n, body in bodies.items() if any(" convolution(" in op for op in body)}
+    (body,) = [b for b in bodies.values() if any(called(op) in dots for op in b)]
+    fusions = [op for op in body if called(op) in dots]
+    assert len(fusions) == n_tiles
+    (operand,) = [op for op in body if op.split(" = ", 1)[1].startswith("s8[")]
+    assert _shape(operand) == f"s8[{BLOCK},{N_LARGE}]"
+    assert scope[name(operand)] == "generate"
+    carries = set()
+    for op in fusions:
+        args = op.split(" fusion(", 1)[1].split(")", 1)[0].replace("%", "").split(", ")
+        assert name(operand) in args and len(args) == 2
+        carries.update(a for a in args if a != name(operand))
+        assert scope[name(op)] == "int8_dot"
+    assert len(carries) == n_tiles
+    lines = _instructions(text)
+    assert {scope.get(n) for n, line in lines.items() if " convolution(" in line} == {"int8_dot"}
 
 
 def test_ring_update_op_scopes_hold_the_ring_exchange():
