@@ -1,10 +1,29 @@
-"""Closed-loop batch cells: whole PCoA jobs back to back through
-``VariantsPcaDriver``, one in flight, each from the driver call to the
-fetched (N, num_pc) components."""
+"""Closed-loop batch cells: whole jobs back to back, one in flight, each
+from the driver call to the fetched result.
+
+A cell's job kind is the module ``jobs/<kind>.py`` that its configuration
+names under ``"job"`` (``core.job_kind``; ``jobs/devicegen.py`` where it
+names none). The module gives:
+
+- ``Job(cell, devices, traced, data)``, with the cell's traffic as ``trf``
+  and ``traced``: called with one ``--references`` string, it runs one job
+  and returns its record: ``seconds``, ``S`` (the job's Gramian as the
+  program left it on the devices), ``pcs``, ``ranges`` (the job's site-grid
+  ranges) and, where the kind counts them, ``dispatches`` and
+  ``sites_scanned``. ``pcoa`` runs a PCoA job under the harness's
+  annotations.
+- ``warm_up(job)``: compiles every program of the window's jobs.
+- optionally ``prepare(cell, directory) -> data``: writes the kind's data
+  set into ``directory``, once per process, from the configuration alone
+  (never from ``--seed``); ``data`` goes to ``Job``. Its seconds are left
+  out of set-up.
+- optionally ``check(cell, kept) -> numbers``; ``check`` where it has none.
+"""
 
 from __future__ import annotations
 
 import contextlib
+import tempfile
 import time
 
 import numpy as np
@@ -20,72 +39,62 @@ def _span(name: str, on: bool):
     return jax.profiler.TraceAnnotation("bench:" + name)
 
 
-class Job:
-    """One whole-genome job as a user runs it."""
+def pcoa(job, references: str, similarity) -> tuple:
+    """One PCoA job as a user runs it: ``VariantsPcaDriver`` with
+    ``job.flags`` over ``job.source`` on ``job.devices``, under the
+    annotations that name the trace's idle gaps alike for every kind:
+    ``job`` around ``driver-setup`` (conf, driver, contigs), ``ingest``
+    (``similarity(driver, contigs)``, fenced when traced) and ``finalize``
+    (the components fetched). Returns the record's ``seconds``, ``S``,
+    ``pcs`` and ``ranges``, and the driver, whose counters the kind reads."""
+    from spark_examples_tpu.config import PcaConf
+    from spark_examples_tpu.pipeline.pca_driver import VariantsPcaDriver
 
-    def __init__(self, cell: dict, devices, traced: bool):
-        from spark_examples_tpu.sources.synthetic import SyntheticGenomicsSource
-
-        cfg, trf = cell["config"], cell["traffic"]
-        self.cfg, self.trf, self.devices, self.traced = cfg, trf, devices, traced
-        self.source = SyntheticGenomicsSource(
-            num_samples=int(cfg["num_samples"]),
-            seed=int(cfg["cohort_seed"]),
-            variant_spacing=int(trf["spacing"]),
-            ref_block_fraction=float(cfg["ref_block_fraction"]),
-            n_pops=int(cfg["n_pops"]),
-        )
-        self.flags = [
-            "--variant-set-id", cfg["variant_set_id"],
-            "--ingest", "device",
-            "--block-size", str(trf["block_size"]),
-            "--num-pc", str(cfg["num_pc"]),
-            "--num-samples", str(cfg["num_samples"]),
-            "--seed", str(cfg["cohort_seed"]),
-        ] + [str(flag) for flag in cfg.get("flags", [])]
-
-    def __call__(self, references: str) -> dict:
-        from spark_examples_tpu.config import PcaConf
-        from spark_examples_tpu.obs.metrics import (
-            DEVICEGEN_DISPATCHES,
-            INGEST_SITES_SCANNED,
-        )
-        from spark_examples_tpu.pipeline.pca_driver import VariantsPcaDriver
-
-        traced = self.traced
-        start = time.perf_counter()
-        with _span("job", traced):
-            with _span("driver-setup", traced):
-                conf = PcaConf.parse(self.flags + ["--references", references])
-                driver = VariantsPcaDriver(conf, self.source, devices=self.devices)
-                contigs = conf.get_contigs(self.source, conf.variant_set_id)
-            with _span("ingest", traced):
-                S = driver.get_similarity_device_gen(contigs)
-                if traced:
-                    S.block_until_ready()
-            with _span("finalize", traced):
-                result = driver.compute_pca(S)
-        return {
-            "seconds": time.perf_counter() - start,
-            "S": S,
-            "pcs": np.array([pcs for _, pcs in result], dtype=np.float64),
-            "ranges": [
-                reference.grid_range(c.start, c.end, int(self.trf["spacing"]))
-                for c in contigs
-            ],
-            "dispatches": int(driver.registry.value(DEVICEGEN_DISPATCHES)),
-            "sites_scanned": int(driver.registry.value(INGEST_SITES_SCANNED)),
-        }
+    traced = job.traced
+    start = time.perf_counter()
+    with _span("job", traced):
+        with _span("driver-setup", traced):
+            conf = PcaConf.parse(job.flags + ["--references", references])
+            driver = VariantsPcaDriver(conf, job.source, devices=job.devices)
+            contigs = conf.get_contigs(job.source, conf.variant_set_id)
+        with _span("ingest", traced):
+            S = similarity(driver, contigs)
+            if traced:
+                S.block_until_ready()
+        with _span("finalize", traced):
+            result = driver.compute_pca(S)
+    record = {
+        "seconds": time.perf_counter() - start,
+        "S": S,
+        "pcs": np.array([pcs for _, pcs in result], dtype=np.float64),
+        "ranges": [
+            reference.grid_range(c.start, c.end, int(job.trf["spacing"])) for c in contigs
+        ],
+    }
+    return record, driver
 
 
-def warm_up(job: Job) -> None:
-    from spark_examples_tpu.ops.devicegen import auto_blocks_per_dispatch
+@contextlib.contextmanager
+def set_up(cell: dict, devices, traced: bool):
+    """The cell's job kind set up: its data prepared into a temporary
+    directory that is removed on leaving, also on a failure; its ``Job``
+    built and warmed up. Yields the kind's module, the job and the seconds
+    ``prepare`` took (0 where the kind has none), which set-up leaves out."""
+    kind = core.load_job(cell["job"])
+    with contextlib.ExitStack() as stack:
+        data, prepare_s = None, 0.0
+        if hasattr(kind, "prepare"):
+            directory = stack.enter_context(tempfile.TemporaryDirectory(prefix="bench-data-"))
+            start = time.perf_counter()
+            data = kind.prepare(cell, directory)
+            prepare_s = time.perf_counter() - start
+            core.say(f"prepare: {prepare_s:.3f} s, data in {directory}")
+        job = kind.Job(cell, devices, traced, data)
+        kind.warm_up(job)
+        yield kind, job, prepare_s
 
-    k = auto_blocks_per_dispatch(int(job.cfg["num_samples"]), int(job.trf["block_size"]))
-    job(traffic.warmup_references(job.trf, k))
 
-
-def window(job: Job, seed: int, seconds: float) -> dict:
+def window(job, seed: int, seconds: float) -> dict:
     """Jobs back to back until ``seconds`` have passed; every started job
     completes, so the measured time spans whole jobs only. The jobs the
     check compares are drawn from the seed and kept on the device."""
@@ -108,6 +117,11 @@ def window(job: Job, seed: int, seconds: float) -> dict:
     if not kept:
         kept[len(jobs) - 1] = last
     return {"elapsed": elapsed, "jobs": jobs, "kept": kept}
+
+
+def compare(kind, cell: dict, kept: dict) -> dict:
+    """The kept jobs compared by the kind's own ``check``, or by ``check``."""
+    return getattr(kind, "check", check)(cell, kept)
 
 
 def check(cell: dict, kept: dict) -> dict:

@@ -4,8 +4,10 @@ compile clock, the device check, and the comparison arithmetic.
 Everything that belongs to one configuration, traffic mix, per-layer metric
 or cell lives in a file of its own that is found here by its name:
 ``configs/<file named in BENCHMARK.json>``, ``traffic/<traffic>.json``,
-``metrics/<metric>.py`` (a ``read(run)`` function) and
-``limits/<cell>.json``.
+``metrics/<metric>.py`` (a ``read(run)`` function),
+``limits/<cell>.json`` and ``jobs/<kind>.py``, the closed-loop job kind
+that a configuration names under ``"job"`` (``DEFAULT_JOB`` where it names
+none; ``batch.py`` says what a kind module gives).
 """
 
 from __future__ import annotations
@@ -14,6 +16,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 import sys
 import time
 from typing import Callable, Dict, List, Optional
@@ -22,6 +25,9 @@ import numpy as np
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
+#: The job kind of a configuration that names none: PCoA on genotypes the
+#: program generates on the device (``jobs/devicegen.py``).
+DEFAULT_JOB = "devicegen"
 
 
 class BenchFailure(Exception):
@@ -57,6 +63,27 @@ def load_reader(metric: str) -> Callable:
     return module.read
 
 
+def load_job(kind: str):
+    """The module ``jobs/<kind>.py``: a closed-loop cell's job kind."""
+    path = os.path.join(BENCH, "jobs", f"{kind}.py")
+    spec = importlib.util.spec_from_file_location(f"bench_job_{kind}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def job_kind(config: dict) -> str:
+    """The job kind a configuration names, checked to have its file."""
+    kind = config.get("job", DEFAULT_JOB)
+    if not (
+        isinstance(kind, str)
+        and re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}", kind)
+        and os.path.isfile(os.path.join(BENCH, "jobs", f"{kind}.py"))
+    ):
+        raise BenchFailure(f"no job kind {kind!r}: benchmark/jobs/<kind>.py is missing")
+    return kind
+
+
 def cell(name: str, doc: Optional[dict] = None) -> dict:
     """Everything one cell needs, found by the names in the manifest."""
     doc = doc if doc is not None else manifest()
@@ -65,10 +92,12 @@ def cell(name: str, doc: Optional[dict] = None) -> dict:
         raise BenchFailure(f"no workload {name!r} in BENCHMARK.json")
     work = workloads[name]
     entry = {c["name"]: c for c in doc["configs"]}[work["config"]]
+    config = load_json(os.path.join(ROOT, entry["file"]))
     return {
         "name": name,
         "chips": int(work["chips"]),
-        "config": load_json(os.path.join(ROOT, entry["file"])),
+        "job": job_kind(config),
+        "config": config,
         "traffic": load_traffic(work["traffic"]),
         "limits": load_limits(name),
         "end_to_end": [m for m in doc["end_to_end"] if name in m.get("workloads", [name])],
@@ -109,8 +138,8 @@ def peak_resident_bytes() -> int:
 
 
 class CompileClock:
-    """Tracing, lowering and compiling seconds, read from JAX's own
-    monitoring events (copied from ``chip_smoke.py``)."""
+    """Tracing, lowering and compiling seconds, read from the duration
+    events that JAX's monitoring records for each program (``EVENTS``)."""
 
     EVENTS = (
         "/jax/core/compile/jaxpr_trace_duration",

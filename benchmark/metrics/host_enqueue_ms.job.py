@@ -1,8 +1,8 @@
 """Host milliseconds per job of the ingest enqueue loop's own work: the
 self time of the program's ``ingest/enqueue`` span, that is its duration
-less the ``dispatch`` aggregate (the dispatch calls and operand uploads,
-where the host waits while the device's queue is full), the ``stats``
-aggregate and the ``poke`` fetch, averaged over the window's jobs."""
+less its two child aggregates, ``dispatch`` (the dispatch calls and operand
+uploads, where the host waits while the device's queue is full) and
+``stats``, averaged over the window's jobs."""
 
 from benchmark.program import window_runs
 

@@ -78,12 +78,11 @@ def main(argv=None) -> int:
     seeds = [int(s) for s in args.seeds.split(",") if s]
     out = {"workload": args.workload, "program": {}, "control": {}}
     if cell["traffic"]["loop"] == "closed":
-        job = batch.Job(cell, devices, traced=False)
-        batch.warm_up(job)
-        for seed in seeds:
-            result = batch.window(job, seed, args.seconds)
-            out["program"][seed] = batch.check(cell, result["kept"])
-            core.say(f"program seed {seed}: {out['program'][seed]}")
+        with batch.set_up(cell, devices, False) as (kind, job, _):
+            for seed in seeds:
+                result = batch.window(job, seed, args.seconds)
+                out["program"][seed] = batch.compare(kind, cell, result["kept"])
+                core.say(f"program seed {seed}: {out['program'][seed]}")
     else:
         svc = served.Service(cell)
         try:

@@ -2,14 +2,17 @@
 
     python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Loads and warms up the cell (set-up), measures for ``--seconds`` with the
-profiler off (``--trace 0``: the cell's end-to-end metrics) or on for a
-shorter traced window (``--trace 1``: its per-layer metrics), checks what
-the timed path produced against the plain reference (``reference.py``),
-and prints one JSON object as the last line of standard output. Everything
-else, the compared numbers last, goes to standard error. With no TPU, or
-fewer chips than the cell asks for, it exits 1 and prints no result.
-``--dry`` (tests only) runs the cell's ``dry`` sizes on any backend.
+Prepares the data set of the cell's job kind where the kind has one
+(``batch.py``; its seconds are printed and left out of set-up), loads and
+warms up the cell (set-up), measures for ``--seconds`` with the profiler
+off (``--trace 0``: the cell's end-to-end metrics) or on for a shorter
+traced window (``--trace 1``: its per-layer metrics), checks what the
+timed path produced (by the kind's check; by default against the plain
+reference, ``reference.py``), and prints one JSON object as the last line
+of standard output. Everything else, the compared numbers last, goes to
+standard error. With no TPU, or fewer chips than the cell asks for, it
+exits 1 and prints no result. ``--dry`` (tests only) runs the cell's
+``dry`` sizes on any backend.
 """
 
 from __future__ import annotations
@@ -68,26 +71,29 @@ def _stop_trace(directory: str):
 def run_batch(cell, args, devices, clock, trace_dir):
     from benchmark import batch
 
-    job = batch.Job(cell, devices, traced=bool(args.trace))
-    batch.warm_up(job)
-    setup_s = core.process_age_s()
-    before = clock.snapshot()
-    seconds = min(args.seconds, cell["traffic"]["trace_seconds"]) if args.trace else args.seconds
-    if args.trace:
-        _start_trace(trace_dir)
-    result = batch.window(job, args.seed, seconds)
-    trace = _stop_trace(trace_dir) if args.trace else None
-    compiles = clock.since(before)
-    device = core.device_block(devices)
-    jobs = result["jobs"]
-    e2e = {"job_s": result["elapsed"] / len(jobs), "setup_s": setup_s}
-    core.say(
-        f"window: {len(jobs)} jobs in {result['elapsed']:.3f} s, job seconds "
-        f"{[round(j['seconds'], 4) for j in jobs]}, dispatches {jobs[0]['dispatches']}, "
-        f"sites {jobs[0]['sites_scanned']}"
-    )
-    run = Run(cell, device["kind"], jobs, trace)
-    numbers = batch.check(cell, result["kept"])
+    with batch.set_up(cell, devices, bool(args.trace)) as (kind, job, prepare_s):
+        setup_s = core.process_age_s() - prepare_s
+        before = clock.snapshot()
+        seconds = min(args.seconds, cell["traffic"]["trace_seconds"]) if args.trace else args.seconds
+        if args.trace:
+            _start_trace(trace_dir)
+        result = batch.window(job, args.seed, seconds)
+        trace = _stop_trace(trace_dir) if args.trace else None
+        compiles = clock.since(before)
+        device = core.device_block(devices)
+        jobs = result["jobs"]
+        e2e = {"job_s": result["elapsed"] / len(jobs), "setup_s": setup_s}
+        counts = "".join(
+            f", {label} {jobs[0][key]}"
+            for key, label in (("dispatches", "dispatches"), ("sites_scanned", "sites"))
+            if key in jobs[0]
+        )
+        core.say(
+            f"window: {len(jobs)} jobs in {result['elapsed']:.3f} s, job seconds "
+            f"{[round(j['seconds'], 4) for j in jobs]}{counts}"
+        )
+        run = Run(cell, device["kind"], jobs, trace)
+        numbers = batch.compare(kind, cell, result["kept"])
     return run, e2e, numbers, device, compiles, len(jobs), 0
 
 

@@ -63,9 +63,9 @@ def closed_job(traffic: dict, seed: int, index: int) -> str:
 
 
 def warmup_references(traffic: dict, blocks_per_dispatch: int) -> str:
-    """The ``bench.py`` warm-up rule: one contig covering one dispatch group
-    of the resolved length plus the ~K/8 tail group, so every program of a
-    whole-genome job compiles before the window."""
+    """The warm-up of a device-generation job: one contig covering one
+    dispatch group of the resolved length plus the ~K/8 tail group, so
+    every program of a whole-genome job compiles before the window."""
     block = int(traffic["block_size"])
     bases = int(traffic["spacing"]) * (
         block * blocks_per_dispatch + block * max(1, blocks_per_dispatch // 8)

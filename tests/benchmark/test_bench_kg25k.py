@@ -132,11 +132,11 @@ def test_control_is_not_correct_at_dry_size():
 
 def _ingest_attrs(cell_name, devices):
     """The ``ingest`` span attributes and the record of one dry job."""
-    from benchmark import batch, traffic
+    from benchmark import traffic
     from spark_examples_tpu.obs.spans import recent_spans
 
     cell = core.dry_overrides(core.cell(cell_name))
-    job = batch.Job(cell, devices, traced=False)
+    job = core.load_job(cell["job"]).Job(cell, devices, False, None)
     record = job(traffic.closed_job(cell["traffic"], 5, 0))
     ingest = [s for s in recent_spans() if s["path"] == "ingest"][-1]
     return ingest["attrs"], record

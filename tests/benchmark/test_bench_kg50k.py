@@ -116,11 +116,11 @@ def _four_devices():
 
 def _ingest_attrs(cell_name):
     """The ``ingest`` span attributes of one dry job of ``cell_name``."""
-    from benchmark import batch, traffic
+    from benchmark import traffic
     from spark_examples_tpu.obs.spans import recent_spans
 
     cell = core.dry_overrides(core.cell(cell_name))
-    job = batch.Job(cell, _four_devices(), traced=False)
+    job = core.load_job(cell["job"]).Job(cell, _four_devices(), False, None)
     record = job(traffic.closed_job(cell["traffic"], 5, 0))
     ingest = [s for s in recent_spans() if s["path"] == "ingest"][-1]
     return ingest["attrs"], record

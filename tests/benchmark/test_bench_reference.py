@@ -12,7 +12,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark import batch, core, reference, traffic  # noqa: E402
+from benchmark import core, reference, traffic  # noqa: E402
 
 
 def _cohort_gramian(n: int, sites: int, seed: int) -> np.ndarray:
@@ -120,7 +120,7 @@ def test_tiled_comparison_equals_a_whole_fetch(name):
     cfg, trf = cell["config"], cell["traffic"]
     n, spacing = int(cfg["num_samples"]), int(trf["spacing"])
     devices = jax.devices()[: cell["chips"]]
-    job = batch.Job(cell, devices, traced=False)
+    job = core.load_job(cell["job"]).Job(cell, devices, False, None)
     record = job(traffic.closed_job(trf, 12345, 0))
     S = record["S"]
     assert len({shard.index for shard in S.addressable_shards}) == cell["chips"]
